@@ -18,7 +18,7 @@ checkpoint.
     python -m feddrift_tpu report runs/my-run --trace   # + trace.json
     python -m feddrift_tpu report runs/my-run --follow  # live tail + alerts
     python -m feddrift_tpu lineage runs/my-run  # cluster genealogy + oracle ARI
-    python -m feddrift_tpu regress bench_new.json --baseline BENCH_r05.json
+    python -m feddrift_tpu regress bench_new.json --baseline bench_old.json
     python -m feddrift_tpu critical_path runs/my-run  # round segment breakdown
     python -m feddrift_tpu fleet 127.0.0.1:7777  # live multi-process ops table
     python -m feddrift_tpu incident runs/my-run  # post-mortem incident triage
@@ -61,9 +61,9 @@ def _add_run_args(p: argparse.ArgumentParser) -> None:
                         "runs/<name>/metrics.jsonl; driver scripts pass this "
                         "so no post-hoc flattening is needed)")
     p.add_argument("--platform", type=str, default="",
-                   help="force a JAX platform (e.g. 'cpu'); must be applied "
-                        "before backend init, which env vars can't do when "
-                        "jax was pre-imported (tests/conftest.py note)")
+                   help="force a JAX platform (e.g. 'cpu') from inside the "
+                        "process, before its first backend use; same effect "
+                        "as JAX_PLATFORMS in the environment")
     p.add_argument("--auto_resume", action="store_true",
                    help="if the run dir already holds a checkpoint (ckpt/ or "
                         "ckpt.old/), resume from it instead of clobbering — "
@@ -146,6 +146,7 @@ def _serve_listen(args: argparse.Namespace, buckets: tuple) -> int:
     fe.start(port=args.listen)
     print(json.dumps({"listening": fe.url,
                       "replicas": fe.replicas.healthy_names()}))
+    stats = None
     try:
         if args.requests > 0:
             client = frontend_mod.FrontendClient(fe.url)
@@ -171,6 +172,19 @@ def _serve_listen(args: argparse.Namespace, buckets: tuple) -> int:
             broker.close()
         if ops is not None:
             ops.close()
+    return _serve_exit_code(stats, fe.replicas.engines)
+
+
+def _serve_exit_code(stats: dict | None, engines) -> int:
+    """0 only if every generated request was answered and no engine's
+    dispatcher died: a serve command whose requests failed has failed."""
+    errors = int((stats or {}).get("errors", 0))
+    dead = [e for e in engines if e.failed is not None]
+    if errors or dead:
+        print(f"serve: FAILED — {errors} request error(s), "
+              f"{len(dead)} dead engine(s)"
+              + "".join(f"; {e.failed!r}" for e in dead), file=sys.stderr)
+        return 1
     return 0
 
 
@@ -526,6 +540,14 @@ def main(argv: list[str] | None = None) -> int:
             engine.attach_ops(broker)
         engine.start()
         engine.warmup()
+        from feddrift_tpu import obs
+        from feddrift_tpu.obs import costmodel
+
+        def compiles() -> dict:
+            return {k: int(v) for k, v in obs.registry().snapshot().items()
+                    if k.startswith(("jit_compiles", "jit_recompiles"))}
+        warm = compiles()
+        stats = None
         try:
             gen = serving.TrafficGenerator(
                 engine, list(range(engine.population)), seed=args.seed,
@@ -537,14 +559,23 @@ def main(argv: list[str] | None = None) -> int:
                                 if args.deadline_ms > 0 else None))
             else:
                 stats = gen.run(args.requests)
-            print(json.dumps({**stats, **engine.stats()}, indent=2))
+            after = compiles()
+            print(json.dumps({
+                **stats, **engine.stats(), **costmodel.device_info(),
+                # programs compiled by warm-up, and what traffic added on
+                # top (any entry here is a steady-state recompile)
+                "warmup_compiles": warm,
+                "steady_compiles": {k: after[k] - warm.get(k, 0)
+                                    for k in after
+                                    if after[k] != warm.get(k, 0)},
+            }, indent=2))
         finally:
             engine.close()
             if broker is not None:
                 broker.close()
             if ops is not None:
                 ops.close()
-        return 0
+        return _serve_exit_code(stats, [engine])
 
     if args.cmd == "list":
         from feddrift_tpu.algorithms import available_algorithms
@@ -591,10 +622,15 @@ def main(argv: list[str] | None = None) -> int:
                                            faulthandler_file=fh_file)
 
     exp.run()
+    from feddrift_tpu.obs import costmodel
     print(json.dumps({"Test/Acc": exp.logger.last("Test/Acc"),
                       "Train/Acc": exp.logger.last("Train/Acc"),
                       "rounds": exp.global_round,
-                      "preempted": exp.preempted}))
+                      "preempted": exp.preempted,
+                      **costmodel.device_info(),
+                      "mesh": dict(exp.mesh.shape),
+                      "precision": exp.precision.name,
+                      "compute_dtype": exp.precision.compute_dtype}))
     return 0
 
 

@@ -20,15 +20,37 @@ import jax.numpy as jnp
 from feddrift_tpu.parallel.ring_attention import (blockwise_attention,
                                                   ring_attention)
 
+ATTENTION_IMPLS = ("auto", "pallas", "blockwise")
+
+
+def resolve_attention_impl(impl: str) -> str:
+    """The implementation ``impl`` runs as in THIS process.
+
+    ``auto`` is the Pallas flash kernel only on a TPU process with a single
+    device. GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a shard_map" —
+    v5e 2x2, PR 21), and the round programs shard the client axis over
+    every device of the default mesh, so on more than one chip ``auto`` is
+    the jnp blockwise path. The choice is never silent: the runner writes
+    the resolved name into ``run_start``. ``pallas`` / ``blockwise`` force
+    an implementation; forcing ``pallas`` on a sharded program raises at
+    compile time.
+    """
+    if impl not in ATTENTION_IMPLS:
+        raise ValueError(f"attention_impl must be auto|pallas|blockwise, "
+                         f"got {impl!r}")
+    if impl != "auto":
+        return impl
+    if jax.default_backend() == "tpu" and jax.device_count() == 1:
+        return "pallas"
+    return "blockwise"
+
 
 class MultiHeadAttention(nn.Module):
     num_heads: int
     seq_axis: Optional[str] = None      # mesh axis name for ring attention
     causal: bool = True
-    # 'auto': Pallas flash kernel on a TPU backend, jnp blockwise elsewhere;
-    # 'pallas' / 'blockwise' force an implementation (testability + fallback
-    # if Mosaic rejects a shape in production)
-    attention_impl: str = "auto"
+    attention_impl: str = "auto"        # see resolve_attention_impl
 
     @nn.compact
     def __call__(self, x):
@@ -40,18 +62,14 @@ class MultiHeadAttention(nn.Module):
         q = q.reshape(B, L, H, D).transpose(0, 2, 1, 3)
         k = k.reshape(B, L, H, D).transpose(0, 2, 1, 3)
         v = v.reshape(B, L, H, D).transpose(0, 2, 1, 3)
-        impl = self.attention_impl
-        if impl not in ("auto", "pallas", "blockwise"):
-            raise ValueError(f"attention_impl must be auto|pallas|blockwise, "
-                             f"got {impl!r}")
-        if impl == "auto":
-            impl = "pallas" if jax.default_backend() == "tpu" else "blockwise"
+        impl = resolve_attention_impl(self.attention_impl)
         if self.seq_axis is not None:
             out = ring_attention(q, k, v, axis_name=self.seq_axis,
                                  causal=self.causal)
         elif impl == "pallas":
-            # Mosaic flash kernel: ~6x the scan-based jnp path on-chip at
-            # O(L * block) memory (parallel/pallas_attention.py)
+            # Mosaic flash kernel at O(L * block) memory
+            # (parallel/pallas_attention.py); speed vs the jnp path on the
+            # chip: not measured
             from feddrift_tpu.parallel.pallas_attention import flash_attention
             out = flash_attention(q, k, v, self.causal)
         else:

@@ -20,6 +20,7 @@ log = logging.getLogger("feddrift_tpu.native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libdrift_gen.so")
+_SRC = os.path.join(_DIR, "drift_gen.cpp")
 _DATASET_IDS = {"sea": 0, "sine": 1, "circle": 2}
 
 _lock = threading.Lock()
@@ -32,7 +33,10 @@ def _load():
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_SO):
+        # (re)build when the library is missing OR older than its source:
+        # a copied tree can carry a stale artefact from another checkout
+        if (not os.path.exists(_SO)
+                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
             try:
                 subprocess.run(["make", "-C", _DIR], check=True,
                                capture_output=True, timeout=120)

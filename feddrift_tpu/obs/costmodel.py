@@ -29,20 +29,19 @@ emits an ``hbm_watermark`` event and folds the live peak into the
 returns ``None`` and emits nothing — graceful, never an error.
 
 **Peaks + roofline.** ``peak_flops()`` / ``peak_bytes_per_s()`` give the
-denominator MFU needs: a datasheet table for TPUs, and a *measured*
-matmul / memory-stream microbenchmark for CPU hosts (an invented CPU
-constant would make MFU meaningless; a measured one makes it "fraction
-of what this silicon demonstrably does"). ``roofline()`` combines
-achieved FLOP/s and bytes/s against those peaks and names the binding
-resource. bench.py and scripts/roofline_report.py both source their
-numbers here — one cost model, no per-script forks.
+denominator MFU needs from ONE table, ``DEVICE_PEAKS``, keyed by the
+``device_kind`` string the chip reports and carrying each entry's
+source. A kind that is not in the table raises; a CPU has no peak, so
+MFU and roofline utilization of a CPU run are ``None`` ("not measured"),
+never a measured stand-in under a device metric's name. ``roofline()``
+combines achieved FLOP/s and bytes/s against those peaks and names the
+binding resource.
 """
 
 from __future__ import annotations
 
 import logging
 import threading
-import time
 from dataclasses import asdict, dataclass
 from typing import Any
 
@@ -52,11 +51,26 @@ log = logging.getLogger("feddrift_tpu")
 
 CAPTURE_LEVELS = ("off", "lowered", "compiled")
 
-# Datasheet peaks per chip. TPU v5 lite (v5e): ~197 TFLOP/s bf16,
-# ~98 TFLOP/s f32, ~819 GB/s HBM BW per chip. (Moved here from bench.py so
-# bench and scripts/roofline_report.py read one table.)
-PEAK_FLOPS = {"tpu": {"bfloat16": 197e12, "float32": 98e12}}
-PEAK_BYTES_PER_S = {"tpu": 8.19e11}
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``. Only
+# what the source states: v5e has no published f32 matmul peak, so an f32
+# run has no MFU denominator (None), not an invented one.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "flops": {"bfloat16": 197e12, "int8": 393e12},
+        "bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, 'TPU v5e' "
+                  "(197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s HBM)",
+    },
+}
+
+
+def device_info() -> dict[str, Any]:
+    """What this process computes on, as JAX reports it. Every artifact
+    that carries a number also carries these three fields."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
 
 
 @dataclass
@@ -108,13 +122,12 @@ def clear() -> None:
 
 
 def _cost_dict(obj) -> dict | None:
-    """cost_analysis() returns a dict, or [dict] on older jax."""
+    """``cost_analysis()`` of a lowered or compiled program, or None where
+    the backend has none."""
     try:
         cost = obj.cost_analysis()
     except Exception:
         return None
-    if isinstance(cost, list):
-        cost = cost[0] if cost else None
     return cost if isinstance(cost, dict) else None
 
 
@@ -267,89 +280,62 @@ def hbm_peak_bytes() -> int | None:
 
 # ----------------------------------------------------------------------
 # Peaks: the MFU / roofline denominators
-_measured_peaks: dict[str, float] = {}
+def _peaks_for(device_kind: str) -> dict | None:
+    """The table row of ``device_kind``; None for a CPU; raises on any
+    other kind that is not in the table (an error, not a default)."""
+    if device_kind.lower() == "cpu":
+        return None
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"device_kind {device_kind!r} is not in costmodel.DEVICE_PEAKS "
+            f"({sorted(DEVICE_PEAKS)}); add its published peaks with their "
+            f"source before computing a utilization on it") from None
 
 
-def _measure_cpu_peak_flops() -> float:
-    """Achieved f32 matmul FLOP/s on this host — the honest MFU
-    denominator where no datasheet applies. One-time, ~100 ms."""
-    import jax
-    import jax.numpy as jnp
-
-    n = 512
-    f = jax.jit(lambda a, b: a @ b)
-    a = jnp.ones((n, n), jnp.float32)
-    jax.block_until_ready(f(a, a))               # compile
-    reps, best = 3, 0.0
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f(a, a))
-        dt = time.perf_counter() - t0
-        best = max(best, (2 * n ** 3) / max(dt, 1e-9))
-    return best
+def peak_flops(device_kind: str,
+               dtype: str = "bfloat16") -> tuple[float | None, str]:
+    """(peak FLOP/s, source) for MFU. ``None`` — not measured — on a CPU
+    and for a dtype whose peak the source does not state."""
+    row = _peaks_for(device_kind)
+    if row is None:
+        return None, "not measured"
+    return row["flops"].get(dtype), row["source"]
 
 
-def _measure_cpu_peak_bytes() -> float:
-    """Achieved memory-stream bytes/s (large-array copy) on this host."""
-    import jax
-    import jax.numpy as jnp
-
-    n = 4 * 1024 * 1024                          # 16 MiB f32
-    f = jax.jit(lambda a: a + 1.0)
-    a = jnp.ones((n,), jnp.float32)
-    jax.block_until_ready(f(a))
-    reps, best = 3, 0.0
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f(a))
-        dt = time.perf_counter() - t0
-        best = max(best, (2 * 4 * n) / max(dt, 1e-9))   # read + write
-    return best
-
-
-def peak_flops(backend: str, dtype: str = "float32") -> tuple[float, str]:
-    """(peak FLOP/s, source) for MFU. TPU backends use the datasheet
-    table; everything else gets a measured matmul microbenchmark
-    (memoized per process) so MFU is non-null on every backend."""
-    if backend.startswith("tpu"):
-        table = PEAK_FLOPS["tpu"]
-        return table.get(dtype, table["float32"]), "datasheet_tpu_v5e"
-    key = "cpu_flops"
-    if key not in _measured_peaks:
-        _measured_peaks[key] = _measure_cpu_peak_flops()
-    return _measured_peaks[key], "measured_matmul_f32"
-
-
-def peak_bytes_per_s(backend: str) -> tuple[float, str]:
-    """(peak bytes/s, source) for the bandwidth roofline axis."""
-    if backend.startswith("tpu"):
-        return PEAK_BYTES_PER_S["tpu"], "datasheet_tpu_v5e"
-    key = "cpu_bytes"
-    if key not in _measured_peaks:
-        _measured_peaks[key] = _measure_cpu_peak_bytes()
-    return _measured_peaks[key], "measured_stream"
+def peak_bytes_per_s(device_kind: str) -> tuple[float | None, str]:
+    """(peak HBM bytes/s, source) for the bandwidth roofline axis."""
+    row = _peaks_for(device_kind)
+    if row is None:
+        return None, "not measured"
+    return row["bytes_per_s"], row["source"]
 
 
 def roofline(flops: float | None, bytes_accessed: float | None,
-             seconds: float, backend: str,
-             dtype: str = "float32") -> dict | None:
+             seconds: float, device_kind: str,
+             dtype: str = "bfloat16") -> dict | None:
     """Achieved-vs-peak utilization on both roofline axes.
 
     Returns {"achieved_flops_per_s", "flops_utilization",
     "achieved_bytes_per_s", "bandwidth_utilization", "bound",
     "peak_flops", "peak_bytes_per_s", "peak_source"} — ``bound`` names
-    whichever axis is closer to its peak (the binding resource).
+    whichever axis is closer to its peak (the binding resource). None on
+    a CPU: a utilization needs a peak, and a CPU run has none.
     """
     if seconds <= 0 or (flops is None and bytes_accessed is None):
         return None
-    pf, src = peak_flops(backend, dtype)
-    pb, _ = peak_bytes_per_s(backend)
+    pf, src = peak_flops(device_kind, dtype)
+    pb, _ = peak_bytes_per_s(device_kind)
+    if pb is None:
+        return None
     out: dict[str, Any] = {"peak_flops": pf, "peak_bytes_per_s": pb,
                            "peak_source": src}
     fu = bu = None
     if flops is not None:
         out["achieved_flops_per_s"] = flops / seconds
-        fu = out["flops_utilization"] = round(flops / seconds / pf, 6)
+        if pf is not None:
+            fu = out["flops_utilization"] = round(flops / seconds / pf, 6)
     if bytes_accessed is not None:
         out["achieved_bytes_per_s"] = bytes_accessed / seconds
         bu = out["bandwidth_utilization"] = round(
@@ -359,8 +345,7 @@ def roofline(flops: float | None, bytes_accessed: float | None,
 
 
 # ----------------------------------------------------------------------
-# Model-level FLOP counting (shared by bench.py and
-# scripts/roofline_report.py — previously an island in each)
+# Model-level FLOP counting
 def forward_flops_per_example(exp) -> float:
     """Forward FLOPs per example of an Experiment's model, preferring
     XLA's cost analysis of the compiled single-model forward (exact for

@@ -1,13 +1,12 @@
 """Perf-regression gate over bench.py artifacts.
 
-Five BENCH_r0*.json snapshots existed with nothing that compared them;
-this module is the comparator, runnable in CI:
+The comparator for two ``bench.py`` snapshots, runnable in CI:
 
-    python -m feddrift_tpu regress <bench.json> --baseline BENCH_r05.json
+    python -m feddrift_tpu regress <bench.json> --baseline <bench_old.json>
 
 Accepts both raw ``bench.py`` stdout (a JSON object / last JSON line of a
-capture) and the committed ``BENCH_r0*.json`` wrapper format (driver
-snapshots with the bench object under ``"parsed"``). Compares the
+capture) and the driver-snapshot wrapper format (the bench object under
+``"parsed"``). Compares the
 metrics a throughput regression shows up in — rounds/s, wall seconds,
 steady-state XLA compile counts, final test accuracy — and exits nonzero
 iff any regresses past its threshold, printing a delta table either way.
@@ -55,8 +54,8 @@ DEFAULT_TOL = {
 
 def load_bench(path: str) -> dict:
     """Load a bench artifact: raw bench.py output, a mixed-output capture
-    (last parseable JSON line wins), or a BENCH_r0*.json driver wrapper
-    (bench object under "parsed")."""
+    (last parseable JSON line wins), or a driver wrapper (bench object
+    under "parsed", e.g. BENCH_r10.json)."""
     with open(path) as f:
         text = f.read()
     try:
@@ -74,7 +73,7 @@ def load_bench(path: str) -> dict:
     if not isinstance(d, dict):
         raise ValueError(f"{path}: expected a JSON object")
     if "parsed" in d and isinstance(d["parsed"], dict):
-        d = d["parsed"]                # committed BENCH_r0*.json wrapper
+        d = d["parsed"]                # driver wrapper
     return d
 
 
@@ -671,7 +670,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("candidate", help="bench JSON to gate")
     ap.add_argument("--baseline", required=True,
                     help="bench JSON to compare against (raw output or a "
-                         "committed BENCH_r0*.json)")
+                         "driver wrapper)")
     ap.add_argument("--tol-rounds", type=float, default=DEFAULT_TOL["rounds"],
                     help="relative rounds/s drop tolerated (default %(default)s)")
     ap.add_argument("--tol-wall", type=float, default=DEFAULT_TOL["wall"],

@@ -452,10 +452,10 @@ def summarize(run_dir: str) -> dict[str, Any]:
 def _roofline_from_events(events: list[dict], prog_costs: list[dict],
                           ends: list[dict]) -> dict[str, Any] | None:
     """Achieved FLOP/s and bytes/s of the run from the captured round
-    program's XLA cost + the iteration walls. Utilization against peak is
-    added only when the run's backend was a TPU: the datasheet lookup is
-    jax-free, whereas the CPU peak is a measured microbenchmark that the
-    (pure host-side) report CLI must not run."""
+    program's XLA cost + the iteration walls, and — for a run whose
+    ``run_start`` names a chip with published peaks — utilization against
+    them (costmodel.DEVICE_PEAKS; an unlisted kind raises there). A CPU
+    run has no peak, so it reports achieved rates only."""
     if not prog_costs or not ends:
         return None
     by_fn = {e.get("fn"): e for e in prog_costs}
@@ -476,19 +476,28 @@ def _roofline_from_events(events: list[dict], prog_costs: list[dict],
         "achieved_flops_per_s": round(flops_pr * rounds / wall, 1)}
     if bytes_pr:
         out["achieved_bytes_per_s"] = round(bytes_pr * rounds / wall, 1)
-    start = next((e for e in events if e["kind"] == "run_start"), None)
-    backend = (start or {}).get("backend", "") or ""
-    if backend.startswith("tpu"):
+    start = next((e for e in events if e["kind"] == "run_start"), None) or {}
+    out["backend"] = start.get("backend")
+    out["device_kind"] = start.get("device_kind")
+    out["device_count"] = start.get("device_count")
+    if start.get("device_kind"):
         from feddrift_tpu.obs import costmodel
-        dtype = (start or {}).get("compute_dtype", "float32")
-        pf, src = costmodel.peak_flops(backend, dtype)
-        out["flops_utilization"] = round(
-            out["achieved_flops_per_s"] / pf, 6)
-        if bytes_pr:
-            pb, _ = costmodel.peak_bytes_per_s(backend)
+        pf, src = costmodel.peak_flops(
+            start["device_kind"], start.get("compute_dtype", "float32"))
+        pb, _ = costmodel.peak_bytes_per_s(start["device_kind"])
+        # XLA counts a "lowered" program whole and a "compiled" one per
+        # device (after SPMD partitioning), so only the former is divided
+        # over the chips it ran on
+        chips = (start.get("device_count") or 1) \
+            if pc.get("level") == "lowered" else 1
+        if pf is not None:
+            out["flops_utilization"] = round(
+                out["achieved_flops_per_s"] / (pf * chips), 6)
+        if pb is not None and bytes_pr:
             out["bandwidth_utilization"] = round(
-                out["achieved_bytes_per_s"] / pb, 6)
-        out["peak_source"] = src
+                out["achieved_bytes_per_s"] / (pb * chips), 6)
+        if pf is not None or pb is not None:
+            out["peak_source"] = src
     return out
 
 

@@ -16,8 +16,9 @@ blockwise implementation (rematerialisation — the standard flash-attention
 trade of FLOPs for memory). Forward-only callers (inference, the M x C eval
 matrices) never pay that cost.
 
-Tests run the kernel with ``interpret=True`` on the CPU mesh; on a TPU
-backend the Mosaic compiler lowers it natively.
+Tests run the kernel in interpret mode on the CPU mesh; on a TPU backend
+the Mosaic compiler lowers it natively, and ``chip_smoke.py`` (step
+``kernel``) holds the compiled kernel against ``blockwise_attention`` there.
 """
 
 from __future__ import annotations
@@ -137,10 +138,11 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     block_k: int = 512, interpret: bool = False):
     """Fused causal attention: [B, H, L, D] -> [B, H, L, D].
 
-    Default 512-blocks: measured best on-chip (B=4, H=8, L=2048, D=64,
-    chained-dependency timing: 0.019 ms vs 0.121 ms for the scan-based jnp
-    blockwise path and 0.021 ms for naive full-matrix attention — i.e.
-    full-matrix speed at O(L * block) activation memory).
+    Default 512-blocks, O(L * block) activation memory. On TPU v5e the
+    kernel compiles and agrees with ``blockwise_attention`` (f32, highest
+    precision) to 0.012 max abs error at B=4, H=8, L=2048, D=64 and at the
+    transformer's L=80, D=32, in f32 and bf16 (chip_smoke.py, PR 21). Its
+    speed against the jnp paths on the chip: not measured.
     """
     return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
 

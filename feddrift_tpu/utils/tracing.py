@@ -30,7 +30,8 @@ put every phase on the unified trace timeline
 ``xla_trace`` is no-op-safe under nesting: ``jax.profiler.start_trace``
 raises when a trace is already active, so an inner ``xla_trace`` runs its
 body without starting (or stopping) anything; each completed capture
-emits a ``profile_captured`` event carrying the trace dir.
+emits a ``profile_captured`` event carrying the trace dir. A profiler that
+cannot start raises.
 """
 
 from __future__ import annotations
@@ -109,13 +110,13 @@ _trace_lock = threading.Lock()
 
 @contextlib.contextmanager
 def xla_trace(log_dir: str) -> Iterator[None]:
-    """jax.profiler trace (TensorBoard format). No-op-safe: if a trace is
-    already active (nested use) or the profiler cannot start, the body
-    still runs and the outer/foreign capture is left untouched. Each
+    """jax.profiler trace (TensorBoard format). Nesting is a no-op: if a
+    trace is already active the body runs and the outer capture owns the
+    trace. A profiler that cannot start raises — a capture that was asked
+    for and silently did not happen is worse than no capture. Each
     completed capture emits a ``profile_captured`` event with the dir."""
     global _trace_active
     import jax
-    started = False
     with _trace_lock:
         nested = _trace_active
         if not nested:
@@ -123,28 +124,19 @@ def xla_trace(log_dir: str) -> Iterator[None]:
     if nested:
         log.debug("xla_trace: trace already active; nested capture of %s "
                   "is a no-op", log_dir)
-    else:
-        try:
-            jax.profiler.start_trace(log_dir)
-            started = True
-        except Exception as e:                  # pragma: no cover
-            log.warning("xla_trace: profiler unavailable (%s)", e)
-            with _trace_lock:
-                _trace_active = False
-    try:
         yield
+        return
+    try:
+        jax.profiler.start_trace(log_dir)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
+        from feddrift_tpu import obs
+        obs.emit("profile_captured", trace_dir=log_dir)
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-                from feddrift_tpu import obs
-                obs.emit("profile_captured", trace_dir=log_dir)
-            finally:
-                with _trace_lock:
-                    _trace_active = False
-        elif not nested:
-            with _trace_lock:
-                _trace_active = False
+        with _trace_lock:
+            _trace_active = False
 
 
 @contextlib.contextmanager

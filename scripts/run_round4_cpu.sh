@@ -11,7 +11,7 @@
 #   4+5. FMoW with a CONV model (cnn): FedDrift vs win-1 on the hardened
 #      62-class task (round-3 verdict: fnn-64 was the one model-family
 #      downgrade in committed evidence).
-# Same sentinel semantics as run_tracked_tpu.sh: .done on zero exit only.
+# Sentinel semantics: .done on zero exit only.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 

@@ -12,7 +12,7 @@
 #      the 1-core host (b32, 5x8 rounds).
 #   5. femnist / cnn Ada at 50 clients on REAL digits (verdict item 4,
 #      half of config 4's defined scale) — fresh compile, queued last.
-# Same sentinel semantics as run_tracked_tpu.sh: .done on zero exit only.
+# Sentinel semantics: .done on zero exit only.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 

@@ -74,25 +74,47 @@ class TestCostModel:
         assert costmodel.record_hbm_watermark(iteration=0) is None
         assert fresh_bus.events("hbm_watermark") == []
 
-    def test_peak_flops_sources(self):
-        v, src = costmodel.peak_flops("tpu", "bfloat16")
-        assert v == costmodel.PEAK_FLOPS["tpu"]["bfloat16"]
-        assert src == "datasheet_tpu_v5e"
-        v, src = costmodel.peak_flops("cpu")
-        assert v > 0 and src == "measured_matmul_f32"
-        # memoized: the microbenchmark runs once per process
-        assert costmodel.peak_flops("cpu")[0] == v
+    def test_peaks_keyed_by_device_kind_with_source(self):
+        """One table, keyed by the device_kind string the chip reports;
+        every entry names where its numbers come from."""
+        v, src = costmodel.peak_flops("TPU v5 lite", "bfloat16")
+        assert v == costmodel.DEVICE_PEAKS["TPU v5 lite"]["flops"]["bfloat16"]
+        assert "TPU v5e" in src
+        assert costmodel.peak_bytes_per_s("TPU v5 lite")[0] == 819e9
+        for row in costmodel.DEVICE_PEAKS.values():
+            assert row["source"]
+        # no published f32 matmul peak: not measured, not invented
+        assert costmodel.peak_flops("TPU v5 lite", "float32")[0] is None
+
+    def test_unknown_device_kind_raises(self):
+        """A device that is not in the table is an error, not a default."""
+        with pytest.raises(KeyError, match="TPU v9 imaginary"):
+            costmodel.peak_flops("TPU v9 imaginary", "bfloat16")
+        with pytest.raises(KeyError, match="DEVICE_PEAKS"):
+            costmodel.peak_bytes_per_s("tpu")
+
+    def test_cpu_has_no_peak(self):
+        """A CPU run never gets an MFU/roofline denominator: null, not a
+        measured stand-in under a device metric's name."""
+        assert costmodel.peak_flops("cpu") == (None, "not measured")
+        assert costmodel.peak_bytes_per_s("cpu") == (None, "not measured")
+        assert costmodel.roofline(1e9, 1e9, 1.0, "cpu") is None
+        info = costmodel.device_info()
+        assert info["platform"] == "cpu" and info["device_count"] >= 1
+        assert costmodel.peak_flops(info["device_kind"])[0] is None
 
     def test_roofline_math(self):
         r = costmodel.roofline(flops=197e12, bytes_accessed=8.19e11,
-                               seconds=1.0, backend="tpu", dtype="bfloat16")
+                               seconds=1.0, device_kind="TPU v5 lite",
+                               dtype="bfloat16")
         assert r["flops_utilization"] == 1.0
         assert r["bandwidth_utilization"] == 1.0
         assert r["bound"] in ("compute", "memory")
         r = costmodel.roofline(flops=1e9, bytes_accessed=8.19e11,
-                               seconds=1.0, backend="tpu", dtype="bfloat16")
+                               seconds=1.0, device_kind="TPU v5 lite",
+                               dtype="bfloat16")
         assert r["bound"] == "memory"
-        assert costmodel.roofline(None, None, 1.0, "tpu") is None
+        assert costmodel.roofline(None, None, 1.0, "TPU v5 lite") is None
 
     def test_round_flops_prefers_captured_program(self, fresh_bus):
         """The fused round program's own cost wins over the analytic rule,
@@ -198,12 +220,21 @@ class TestSpans:
 
 # ----------------------------------------------------------------------
 class TestReportCostModel:
+    def _summary(self, tmp_path, rows):
+        with open(tmp_path / "events.jsonl", "w") as f:
+            for r in rows:
+                f.write(json.dumps(r) + "\n")
+        from feddrift_tpu.obs.report import summarize
+        return summarize(str(tmp_path))["cost_model"]
+
     def test_roofline_section_from_events(self, tmp_path, capsys):
         """The report CLI derives achieved-vs-peak roofline utilization
-        from program_cost + iteration_end events (datasheet peak for TPU
-        runs — jax-free), and renders the cost-model section."""
-        rows = [
+        from program_cost + iteration_end events against the published
+        peak of the device_kind run_start names, and carries the device
+        into the section."""
+        cm = self._summary(tmp_path, [
             {"_ts": 1.0, "kind": "run_start", "backend": "tpu",
+             "device_kind": "TPU v5 lite", "device_count": 1,
              "compute_dtype": "bfloat16"},
             {"_ts": 1.1, "kind": "program_cost", "fn": "train_iteration_eval",
              "level": "compiled", "flops": 4.6e10 * 20,
@@ -213,12 +244,7 @@ class TestReportCostModel:
             {"_ts": 3.0, "kind": "hbm_watermark", "bytes_in_use": 1e9,
              "peak_bytes": 2.1e9},
             {"_ts": 3.5, "kind": "profile_captured", "trace_dir": "/tmp/p"},
-        ]
-        with open(tmp_path / "events.jsonl", "w") as f:
-            for r in rows:
-                f.write(json.dumps(r) + "\n")
-        from feddrift_tpu.obs.report import main, summarize
-        cm = summarize(str(tmp_path))["cost_model"]
+        ])
         roof = cm["roofline"]
         # fused program: 920 GFLOP per 20-round dispatch → 46 G/round,
         # 20 rounds in 2 s → 460 GFLOP/s → 0.2335% of 197 TFLOP/s bf16
@@ -226,29 +252,55 @@ class TestReportCostModel:
         assert roof["achieved_flops_per_s"] == pytest.approx(4.6e11)
         assert roof["flops_utilization"] == pytest.approx(0.002335)
         assert roof["source"] == "cost_analysis"
+        assert (roof["backend"], roof["device_kind"],
+                roof["device_count"]) == ("tpu", "TPU v5 lite", 1)
         assert cm["hbm_peak_bytes"] == pytest.approx(2.1e9)  # live > static
+        from feddrift_tpu.obs.report import main
         assert main([str(tmp_path)]) == 0
         out = capsys.readouterr().out
         assert "cost model (XLA accounting):" in out
-        assert "% of datasheet_tpu_v5e" in out
+        assert "% of Google Cloud documentation" in out
+
+    def test_lowered_cost_is_divided_over_the_chips(self, tmp_path):
+        """XLA counts a lowered program whole and a compiled one per
+        device: only the former is spread over device_count chips."""
+        def util(level):
+            return self._summary(tmp_path, [
+                {"_ts": 1.0, "kind": "run_start", "backend": "tpu",
+                 "device_kind": "TPU v5 lite", "device_count": 4,
+                 "compute_dtype": "bfloat16"},
+                {"_ts": 1.1, "kind": "program_cost", "fn": "train_round",
+                 "level": level, "flops": 197e12},
+                {"_ts": 2.0, "kind": "iteration_end", "wall_s": 1.0,
+                 "rounds": 1},
+            ])["roofline"]["flops_utilization"]
+        assert util("compiled") == pytest.approx(1.0)
+        assert util("lowered") == pytest.approx(0.25)
 
     def test_no_utilization_for_cpu_runs(self, tmp_path):
-        """CPU runs report achieved rates only — the report CLI must not
-        run the measured-peak microbenchmark (it would init a backend)."""
-        rows = [
-            {"_ts": 1.0, "kind": "run_start", "backend": "cpu"},
+        """CPU runs report achieved rates only: there is no peak."""
+        roof = self._summary(tmp_path, [
+            {"_ts": 1.0, "kind": "run_start", "backend": "cpu",
+             "device_kind": "cpu", "device_count": 1},
             {"_ts": 1.1, "kind": "program_cost", "fn": "train_round",
              "level": "lowered", "flops": 1e6},
             {"_ts": 2.0, "kind": "iteration_end", "wall_s": 1.0,
              "rounds": 10},
-        ]
-        with open(tmp_path / "events.jsonl", "w") as f:
-            for r in rows:
-                f.write(json.dumps(r) + "\n")
-        from feddrift_tpu.obs.report import summarize
-        roof = summarize(str(tmp_path))["cost_model"]["roofline"]
+        ])["roofline"]
         assert roof["achieved_flops_per_s"] == pytest.approx(1e7)
         assert "flops_utilization" not in roof
+        assert "peak_source" not in roof
+
+    def test_unknown_device_kind_is_an_error(self, tmp_path):
+        with pytest.raises(KeyError, match="DEVICE_PEAKS"):
+            self._summary(tmp_path, [
+                {"_ts": 1.0, "kind": "run_start", "backend": "tpu",
+                 "device_kind": "TPU v9 imaginary", "device_count": 1},
+                {"_ts": 1.1, "kind": "program_cost", "fn": "train_round",
+                 "level": "lowered", "flops": 1e6},
+                {"_ts": 2.0, "kind": "iteration_end", "wall_s": 1.0,
+                 "rounds": 10},
+            ])
 
 
 # ----------------------------------------------------------------------
@@ -307,7 +359,7 @@ class TestRegress:
         assert "rounds differ" in capsys.readouterr().out
 
     def test_wrapper_format_and_missing_instruments(self, tmp_path, capsys):
-        """Committed BENCH_r0*.json wrappers load; artifacts that predate
+        """Driver-wrapper artifacts load; artifacts that predate
         the instruments snapshot skip compile gating instead of failing."""
         base = _bench_fixture(wrap=True)
         del base["parsed"]["instruments"]
@@ -418,7 +470,7 @@ class TestEndToEnd:
 
     def test_perf_gate(self):
         """scripts/perf_gate.sh: two warm smoke benches, cost-model field
-        assertions, regress self-comparison + committed-baseline check."""
+        assertions, regress self-comparison."""
         out = subprocess.run(
             ["bash", os.path.join(ROOT, "scripts", "perf_gate.sh")],
             capture_output=True, text=True, timeout=1500)

@@ -43,3 +43,43 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(naive_attention(q, k, v, True)),
             atol=1e-5)
+
+
+class TestAutoSelection:
+    """`attention_impl="auto"` picks the Mosaic kernel only where the
+    program is not partitioned: GSPMD refuses to split a Mosaic kernel over
+    a mesh (v5e 2x2, PR 21), so on more than one device auto is blockwise."""
+
+    @pytest.mark.parametrize("backend,devices,want", [
+        ("cpu", 1, "blockwise"), ("cpu", 8, "blockwise"),
+        ("tpu", 1, "pallas"), ("tpu", 4, "blockwise")])
+    def test_auto_follows_backend_and_device_count(self, monkeypatch,
+                                                   backend, devices, want):
+        from feddrift_tpu.models import transformer
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(jax, "device_count", lambda: devices)
+        assert transformer.resolve_attention_impl("auto") == want
+        # a forced implementation is never second-guessed
+        assert transformer.resolve_attention_impl("pallas") == "pallas"
+        assert transformer.resolve_attention_impl("blockwise") == "blockwise"
+
+    def test_unknown_impl_rejected(self):
+        from feddrift_tpu.models.transformer import resolve_attention_impl
+        with pytest.raises(ValueError, match="attention_impl"):
+            resolve_attention_impl("flashiest")
+
+    def test_run_start_names_the_resolved_impl(self):
+        from feddrift_tpu.config import ExperimentConfig
+        from feddrift_tpu.simulation.runner import Experiment
+        kw = dict(train_iterations=1, comm_round=1, epochs=1, sample_num=8,
+                  batch_size=8, client_num_in_total=8, client_num_per_round=8)
+        for model, dataset, want in (("transformer", "shakespeare",
+                                      "blockwise"), ("fnn", "sea", None)):
+            exp = Experiment(ExperimentConfig(dataset=dataset, model=model,
+                                              concept_drift_algo="win-1",
+                                              **kw))
+            (start,) = [e for e in exp.events.ring
+                        if e["kind"] == "run_start"]
+            assert start["attention_impl"] == want
+            assert start["device_kind"] and start["device_count"] == 8
+            assert start["mesh"] == {"clients": 8}
