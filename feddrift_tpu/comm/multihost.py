@@ -91,8 +91,15 @@ def fetch(tree):
     identically everywhere, keeping the SPMD programs in lockstep).
     """
     obs.registry().counter("multihost_fetches").inc()
-    if jax.process_count() == 1:
-        return jax.device_get(tree)
+    # the host blocks here until the device has produced the value, then
+    # copies it: wait plus copy is the round's device_compute segment
+    with obs.spans.span("device_compute", cat="round"):
+        if jax.process_count() == 1:
+            return jax.device_get(tree)
+        return _allgather_tiled(tree)
+
+
+def _allgather_tiled(tree):
     from jax.experimental import multihost_utils
 
     def _require_jax_array(leaf):

@@ -285,14 +285,6 @@ class ExperimentConfig:
     alerts: bool = True
     alert_window: int = 3           # churn window (iterations)
     alert_churn_threshold: int = 4  # structural cluster events per window
-    # Causal tracing / round critical path (obs/spans.py,
-    # simulation/runner.py; docs/OBSERVABILITY.md "Causal tracing").
-    # profile_rounds: every Nth global round the runner additionally
-    # blocks to the device (dispatch -> block_until_ready sampling) to
-    # split host dispatch from device compute — the round_breakdown
-    # event + host_overhead_frac gauge. 1 = every round (bench sets
-    # trace_sync anyway); large N keeps async dispatch mostly untouched.
-    profile_rounds: int = 10
     # Size cap (MiB) on events.jsonl / spans.jsonl before rotation to
     # <file>.1 with a loud obs_rotated event; 0 = unbounded (default).
     obs_max_file_mb: float = 0.0
@@ -416,8 +408,6 @@ class ExperimentConfig:
             raise ValueError("alert_window must be >= 1")
         if self.alert_churn_threshold < 1:
             raise ValueError("alert_churn_threshold must be >= 1")
-        if self.profile_rounds < 1:
-            raise ValueError("profile_rounds must be >= 1")
         if self.obs_max_file_mb < 0:
             raise ValueError("obs_max_file_mb must be >= 0")
         if self.ops_port < -1 or self.ops_port > 65535:

@@ -48,6 +48,7 @@ the POPSCALE regress axis gate.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from functools import partial
@@ -209,6 +210,32 @@ class TrainStep:
             return
         obs.costmodel.capture(fn, jit_fn, (self,) + args, kwargs,
                               level=self.cost_capture)
+
+    @contextlib.contextmanager
+    def _tracked(self, fn: str, jit_fn, args: tuple,
+                 kwargs: dict | None = None, *, sig: tuple, static=()):
+        """Around the one call into a jitted program: a ``dispatch`` span
+        from before the signature check to the return of the (asynchronous)
+        jitted call. ``sig`` are the argument trees the signature is taken
+        from. ``track_us`` on the span is the time spent in the signature
+        check and the cost capture; the first dispatch of a signature traces
+        and compiles synchronously, so a span that carries ``event`` is that
+        compile's cost.
+
+        A context manager and not a helper that makes the call: the jitted
+        call stays in its wrapper's own frame. One more Python frame under
+        it cost 20 s of tracing and lowering in the first time step on the
+        chip's host (PERF.md section 6, PR 25)."""
+        with obs.spans.span("dispatch", cat="round", fn=fn) as sp:
+            p0 = time.perf_counter()
+            kind = self._note_signature(fn, *sig, static=static)
+            self._capture_cost(kind, fn, jit_fn, args, kwargs)
+            track_us = round((time.perf_counter() - p0) * 1e6, 1)
+            yield
+            if kind is None:
+                sp.set(track_us=track_us)
+            else:
+                sp.set(track_us=track_us, event=kind)
 
     # ------------------------------------------------------------------
     def init_opt_states(self, params, num_models: int, num_clients: int):
@@ -416,31 +443,17 @@ class TrainStep:
         buffer is M x C full model copies of HBM the weighted-mean reduction
         can otherwise stream through.
         """
-        kind = self._note_signature(
-            "train_round", params, opt_states, x, y, time_w, sample_w,
-            feat_mask, client_mask, byz_modes, stale_params, edge_ids,
-            edge_mask, edge_modes, codec_prev,
-            static=(keep_client_params,))
-        self._capture_cost(
-            kind, "train_round", type(self)._train_round_jit,
-            (params, opt_states, key, x, y, time_w, sample_w, feat_mask,
-             lr_scale, client_mask, byz_modes, stale_params, edge_ids,
-             edge_mask, edge_modes, codec_prev),
-            {"keep_client_params": keep_client_params})
-        # lint: hot-path-begin (tracked dispatch wrapper)
-        # lint: r4-ok (telemetry wall stamp; never a replay input)
-        t0w, p0 = time.time(), time.perf_counter()
-        out = self._train_round_jit(
-            params, opt_states, key, x, y, time_w, sample_w, feat_mask,
-            lr_scale, client_mask, byz_modes, stale_params, edge_ids,
-            edge_mask, edge_modes, codec_prev,
-            keep_client_params=keep_client_params)
-        if kind is not None:
-            # first dispatch of a signature traces+compiles synchronously:
-            # its duration is the compile cost, worth its own trace slice
-            obs.spans.record("jit_compile", t0w, time.perf_counter() - p0,
-                             cat="round", fn="train_round", event=kind)
-        # lint: hot-path-end
+        args = (params, opt_states, key, x, y, time_w, sample_w, feat_mask,
+                lr_scale, client_mask, byz_modes, stale_params, edge_ids,
+                edge_mask, edge_modes, codec_prev)
+        kwargs = {"keep_client_params": keep_client_params}
+        with self._tracked(
+                "train_round", type(self)._train_round_jit, args, kwargs,
+                sig=(params, opt_states, x, y, time_w, sample_w, feat_mask,
+                     client_mask, byz_modes, stale_params, edge_ids,
+                     edge_mask, edge_modes, codec_prev),
+                static=(keep_client_params,)):
+            out = self._train_round_jit(*args, **kwargs)
         return out if with_agg_stats else out[:5]
 
     @partial(jax.jit, static_argnums=0,
@@ -495,30 +508,18 @@ class TrainStep:
         ``with_agg_stats`` additionally returns the per-round stats
         ([R, M, 3] flat, [R, 1 + E, M, 3] hierarchical).
         """
-        kind = self._note_signature(
-            "train_iteration_eval", params, opt_states, x, y, time_w,
-            sample_w, feat_mask, client_masks, byz_modes, edge_ids,
-            edge_masks, edge_byz,
-            static=(R, freq, byz_stale))
-        self._capture_cost(
-            kind, "train_iteration_eval",
-            type(self)._train_iteration_eval_jit,
-            (params, opt_states, iter_key, x, y, time_w, sample_w,
-             feat_mask, lr_scale, R, freq, t, client_masks, byz_modes,
-             edge_ids, edge_masks, edge_byz),
-            {"byz_stale": byz_stale})
-        # lint: hot-path-begin (tracked dispatch wrapper)
-        # lint: r4-ok (telemetry wall stamp; never a replay input)
-        t0w, p0 = time.time(), time.perf_counter()
-        out = self._train_iteration_eval_jit(
-            params, opt_states, iter_key, x, y, time_w, sample_w, feat_mask,
-            lr_scale, R, freq, t, client_masks, byz_modes, edge_ids,
-            edge_masks, edge_byz, byz_stale=byz_stale)
-        if kind is not None:
-            obs.spans.record("jit_compile", t0w, time.perf_counter() - p0,
-                             cat="round", fn="train_iteration_eval",
-                             event=kind)
-        # lint: hot-path-end
+        args = (params, opt_states, iter_key, x, y, time_w, sample_w,
+                feat_mask, lr_scale, R, freq, t, client_masks, byz_modes,
+                edge_ids, edge_masks, edge_byz)
+        kwargs = {"byz_stale": byz_stale}
+        with self._tracked(
+                "train_iteration_eval",
+                type(self)._train_iteration_eval_jit, args, kwargs,
+                sig=(params, opt_states, x, y, time_w, sample_w, feat_mask,
+                     client_masks, byz_modes, edge_ids, edge_masks,
+                     edge_byz),
+                static=(R, freq, byz_stale)):
+            out = self._train_iteration_eval_jit(*args, **kwargs)
         return out if with_agg_stats else out[:6]
 
     @partial(jax.jit, static_argnums=(0, 10, 11), donate_argnums=(1, 2),
@@ -669,27 +670,18 @@ class TrainStep:
         (and the stale-replay / delta-codec carries) from the same
         value-independent seeds.
         """
-        kind = self._note_signature(
-            "train_megastep", params, x, y, time_ws, sample_w, feat_mask,
-            client_masks, byz_modes, edge_ids, edge_masks, edge_byz,
-            x_steps, y_steps, static=(R, freq, K, byz_stale))
-        self._capture_cost(
-            kind, "train_megastep", type(self)._train_megastep_jit,
-            (params, base_key, x, y, time_ws, sample_w, feat_mask, lr_scale,
-             t0, R, freq, K, client_masks, byz_modes, edge_ids, edge_masks,
-             edge_byz, x_steps, y_steps), {"byz_stale": byz_stale})
-        # lint: hot-path-begin (tracked dispatch wrapper)
-        # lint: r4-ok (telemetry wall stamp; never a replay input)
-        t0w, p0 = time.time(), time.perf_counter()
-        out = self._train_megastep_jit(
-            params, base_key, x, y, time_ws, sample_w, feat_mask, lr_scale,
-            t0, R, freq, K, client_masks, byz_modes, edge_ids, edge_masks,
-            edge_byz, x_steps, y_steps, byz_stale=byz_stale)
-        if kind is not None:
-            obs.spans.record("jit_compile", t0w, time.perf_counter() - p0,
-                             cat="round", fn="train_megastep", event=kind)
-        # lint: hot-path-end
-        return out
+        args = (params, base_key, x, y, time_ws, sample_w, feat_mask,
+                lr_scale, t0, R, freq, K, client_masks, byz_modes, edge_ids,
+                edge_masks, edge_byz, x_steps, y_steps)
+        kwargs = {"byz_stale": byz_stale}
+        with self._tracked(
+                "train_megastep", type(self)._train_megastep_jit, args,
+                kwargs,
+                sig=(params, x, y, time_ws, sample_w, feat_mask,
+                     client_masks, byz_modes, edge_ids, edge_masks, edge_byz,
+                     x_steps, y_steps),
+                static=(R, freq, K, byz_stale)):
+            return self._train_megastep_jit(*args, **kwargs)
 
     # NOTE: no buffer donation here — every output is K-stacked, so the
     # [M, ...] params input can never alias an output buffer (XLA would
@@ -765,10 +757,10 @@ class TrainStep:
         FedAvgEnsDataLoader.py:1074-1085) — with one [M, C, N] forward.
         x: [C, N, ...]; returns (correct [M, C], loss_sum [M, C], total [C]).
         """
-        kind = self._note_signature("acc_matrix", params, x, y, feat_mask)
-        self._capture_cost(kind, "acc_matrix", type(self)._acc_matrix_jit,
-                           (params, x, y, feat_mask))
-        return self._acc_matrix_jit(params, x, y, feat_mask)
+        args = (params, x, y, feat_mask)
+        with self._tracked("acc_matrix", type(self)._acc_matrix_jit, args,
+                           sig=args):
+            return self._acc_matrix_jit(*args)
 
     @partial(jax.jit, static_argnums=0)
     def _acc_matrix_jit(self, params, x, y, feat_mask):
@@ -836,10 +828,10 @@ class TrainStep:
     # ------------------------------------------------------------------
     def acc_cells(self, params, x, y, feat_mask):
         """Tracked dispatch of ``_acc_cells_jit`` (see there)."""
-        kind = self._note_signature("acc_cells", params, x, y, feat_mask)
-        self._capture_cost(kind, "acc_cells", type(self)._acc_cells_jit,
-                           (params, x, y, feat_mask))
-        return self._acc_cells_jit(params, x, y, feat_mask)
+        args = (params, x, y, feat_mask)
+        with self._tracked("acc_cells", type(self)._acc_cells_jit, args,
+                           sig=args):
+            return self._acc_cells_jit(*args)
 
     @partial(jax.jit, static_argnums=0)
     def _acc_cells_jit(self, params, x, y, feat_mask):
@@ -931,6 +923,7 @@ class ForwardStep:
     # implementation, one event vocabulary (jit_compile/jit_recompile)
     _note_signature = TrainStep._note_signature
     _capture_cost = TrainStep._capture_cost
+    _tracked = TrainStep._tracked
 
     def forward(self, params, x, model_idx):
         """Tracked dispatch: logits [B, K] for x [B, ...] routed by
@@ -943,10 +936,9 @@ class ForwardStep:
         dtype/sharding/committed-ness), not bucket-ladder noise.
         """
         fn = f"serve_forward_b{x.shape[0]}"
-        kind = self._note_signature(fn, params, x, model_idx)
-        self._capture_cost(kind, fn, type(self)._forward_jit,
-                           (params, x, model_idx))
-        return self._forward_jit(params, x, model_idx)
+        args = (params, x, model_idx)
+        with self._tracked(fn, type(self)._forward_jit, args, sig=args):
+            return self._forward_jit(*args)
 
     @partial(jax.jit, static_argnums=0)
     def _forward_jit(self, params, x, model_idx):

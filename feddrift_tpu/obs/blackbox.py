@@ -138,7 +138,9 @@ class FlightRecorder:
         out["events"] = [dict(e) for e in events]
         if include_spans:
             from feddrift_tpu.obs import spans as _spans
-            out["spans"] = _spans.get_recorder().spans()
+            recorder = _spans.get_recorder()
+            recorder.flush()             # the file keeps up with a dump
+            out["spans"] = recorder.spans()
         if include_instruments:
             from feddrift_tpu.obs.instruments import registry
             out["instruments"] = registry().snapshot()

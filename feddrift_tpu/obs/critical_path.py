@@ -5,8 +5,9 @@
 Replays ``spans.jsonl`` + ``events.jsonl`` (rotated ``.1`` generations
 included) into a per-iteration segment table: for every ``iteration``
 span the matching ``round_breakdown`` event contributes the measured
-segments (cohort_prep / h2d / dispatch / device_compute / writeback /
-drift_decision / eval and the residual dispatch_gap), the dominant
+segments (cohort_prep / h2d / opt_init / round_prep / dispatch /
+device_compute / guard / writeback / drift_decision / eval and the
+residual dispatch_gap), the dominant
 segment is named per iteration and overall, and iterations whose wall
 time stretches past the run median are attributed to the concrete cause
 recorded in the event stream — the straggler clients that missed the
@@ -28,8 +29,9 @@ import os
 import sys
 from typing import Any
 
-SEGMENT_ORDER = ("cohort_prep", "h2d", "dispatch", "device_compute",
-                 "writeback", "drift_decision", "eval", "dispatch_gap")
+SEGMENT_ORDER = ("cohort_prep", "h2d", "opt_init", "round_prep", "dispatch",
+                 "device_compute", "guard", "writeback", "drift_decision",
+                 "eval", "dispatch_gap")
 
 
 def _load_jsonl(path: str) -> list[dict]:

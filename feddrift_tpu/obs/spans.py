@@ -16,9 +16,22 @@ reserved ``events`` lane where every ``events.jsonl`` record appears as
 an instant. Span ``ts`` is unix epoch microseconds — the same clock
 events carry in ``_ts`` — so the two sources interleave correctly.
 
-Recording is O(1) per span (one lock, one append, one optional file
-write) and the recorder is disabled until ``configure()`` arms it, so
-un-instrumented processes pay one attribute check on the hot path.
+Recording is O(1) per span (one lock, one append, one buffered line for
+the optional file sink) and the recorder is disabled until ``configure()``
+arms it, so un-instrumented processes pay one attribute check on the hot
+path. The ring is the record: the JSONL sink is flushed when an
+``iteration`` span is recorded, on rotation, on ``flush()`` (the flight
+recorder's dump) and on ``close()``, never per span.
+
+``span()`` keeps a per-thread stack of open spans, so every closed span
+has its **self time** next to its duration: the duration less what its
+child spans on that thread covered. The thread's completion hook
+(``set_hook``) receives both; an accumulator that sums self times
+partitions the wall, and nothing nested is counted twice. A span also
+enters the ``annotate`` factory the owner handed the recorder (the runner
+passes ``jax.profiler.TraceAnnotation``), which puts the program's spans
+into the host plane of any profiler capture; this module itself never
+imports JAX.
 """
 
 from __future__ import annotations
@@ -29,11 +42,12 @@ import os
 import threading
 import time
 import uuid
-from typing import Any, Callable, Iterator
-
-import contextlib
+from typing import Any, Callable
 
 RING_SIZE = 8192
+
+# the clock of span(): perf_counter seconds (tests put their own here)
+_now = time.perf_counter
 
 # tid of the reserved per-process instant-event lane in trace.json
 EVENTS_LANE_TID = 0
@@ -67,6 +81,60 @@ def child_of(ctx: dict | None) -> dict:
     return out
 
 
+class _NullSpan:
+    """What a disabled recorder's ``span()`` hands out."""
+
+    __slots__ = ()
+    dur = 0.0
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, **args: Any) -> None:
+        """Arguments known only at the end of the interval; dropped here."""
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """One open interval on its thread's stack (``SpanRecorder.span``);
+    ``dur`` is its duration in seconds once it has closed."""
+
+    __slots__ = ("_rec", "_name", "_cat", "_args", "_frame", "_ann", "dur")
+
+    def __init__(self, rec, name, cat, args) -> None:
+        self._rec, self._name, self._cat, self._args = rec, name, cat, args
+        self._frame = self._ann = None
+        self.dur = 0.0
+
+    def set(self, **args: Any) -> None:
+        """Arguments known only at the end of the interval."""
+        self._args.update(args)
+
+    def __enter__(self) -> "_Span":
+        rec = self._rec
+        if rec.annotate is not None:
+            self._ann = rec.annotate(self._name)
+            self._ann.__enter__()
+        self._frame = rec._begin(_now())
+        return self
+
+    def __exit__(self, *exc) -> None:
+        rec = self._rec
+        self.dur, self_s = rec._end(self._frame, _now())
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        rec.record(self._name, rec.wall(self._frame[0]), self.dur,
+                   self._cat, **self._args)
+        hook = getattr(rec._local, "hook", None)
+        if hook is not None:
+            hook(self._name, self._cat, self.dur, self_s)
+
+
 class SpanRecorder:
     """Thread-safe span sink: in-memory ring + optional JSONL file.
 
@@ -74,21 +142,79 @@ class SpanRecorder:
     when a write pushes the file past the cap it is rotated to
     ``<path>.1`` (one generation kept) and a loud ``obs_rotated`` event
     marks the boundary, so 10^5-round runs cannot fill the disk.
+
+    ``annotate`` is a factory ``name -> context manager`` entered with
+    every ``span()`` of an enabled recorder. ``set_hook`` gives the calling
+    thread a completion hook ``(name, cat, dur_s, self_s)``, called after
+    each of its ``span()``s closes (the runner's segment accumulator).
+    ``set_context`` fields (the runner's ``iteration`` and ``round``) are
+    stamped into the ``args`` of every ``span()``; explicit args win.
+    ``dropped`` counts the spans the full ring has pushed out.
     """
 
     def __init__(self, path: str | None = None, pid: int = 0,
-                 enabled: bool = True, max_bytes: int = 0) -> None:
+                 enabled: bool = True, max_bytes: int = 0,
+                 annotate: Callable[[str], Any] | None = None) -> None:
         self._lock = threading.Lock()
         self.ring: collections.deque = collections.deque(maxlen=RING_SIZE)
+        self.dropped = 0
         self.pid = pid
         self.enabled = enabled
         self.path = path
         self.max_bytes = int(max_bytes)
         self.rotations = 0
+        self.annotate = annotate
+        self._context: dict[str, Any] = {}
+        self._local = threading.local()     # .stack of open spans, .hook
+        # spans are timed on perf_counter alone; this puts them on the
+        # unix clock the events carry in _ts
+        self._epoch = time.time() - time.perf_counter()
         self._fh = None
+        self._size = 0
         if path:
             os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
             self._fh = open(path, "a")
+            self._size = self._fh.tell()
+
+    def wall(self, perf: float) -> float:
+        """Unix seconds of a ``time.perf_counter()`` reading."""
+        return self._epoch + perf
+
+    def set_context(self, **ctx: Any) -> None:
+        """Ambient ``args`` of every later ``span()``; None removes a key.
+        The dict is replaced, never mutated: readers take no lock."""
+        new = {**self._context, **ctx}
+        self._context = {k: v for k, v in new.items() if v is not None}
+
+    def set_hook(self, hook: Callable[[str, str, float, float], None]
+                 | None) -> None:
+        """The calling thread's completion hook (None takes it off). One
+        per thread: two experiments driven from two threads each see their
+        own spans, whichever layer recorded them."""
+        self._local.hook = hook
+
+    # -- the self-time stack -------------------------------------------
+    def _begin(self, now: float) -> list:
+        """Open an interval at ``now`` (perf_counter seconds) on this
+        thread's stack; the frame is [start, seconds covered by children]."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        frame = [now, 0.0]
+        stack.append(frame)
+        return frame
+
+    def _end(self, frame: list, now: float) -> tuple[float, float]:
+        """Close ``frame`` at ``now``: (duration, self time). The duration
+        is charged to the enclosing open interval as child time."""
+        stack = self._local.stack
+        # a frame closed out of order takes the frames above it along
+        while stack and stack.pop() is not frame:
+            pass
+        dur = now - frame[0]
+        if stack:
+            stack[-1][1] += dur
+        return dur, max(dur - frame[1], 0.0)
 
     def record(self, name: str, ts: float, dur: float, cat: str = "phase",
                **args: Any) -> dict | None:
@@ -103,11 +229,16 @@ class SpanRecorder:
             rec["args"] = args
         rotated_bytes = 0
         with self._lock:
+            if len(self.ring) == self.ring.maxlen:
+                self.dropped += 1
             self.ring.append(rec)
             if self._fh is not None:
-                self._fh.write(json.dumps(rec) + "\n")
-                self._fh.flush()
-                if self.max_bytes and self._fh.tell() >= self.max_bytes:
+                line = json.dumps(rec) + "\n"
+                self._fh.write(line)
+                self._size += len(line)        # json.dumps emits ASCII
+                if name == "iteration":
+                    self._fh.flush()
+                if self.max_bytes and self._size >= self.max_bytes:
                     rotated_bytes = self._rotate_locked()
         if rotated_bytes:
             # the bus lock is unrelated to ours, but emit outside our own
@@ -124,45 +255,40 @@ class SpanRecorder:
     def _rotate_locked(self) -> int:
         """Swap the sink to a fresh file (caller holds the lock); returns
         the size of the rotated-out generation."""
-        size = self._fh.tell()
-        self._fh.close()
+        size = self._size
+        self._fh.close()               # flushes the generation it ends
         try:
             os.replace(self.path, self.path + ".1")
         except OSError:
             pass
         self._fh = open(self.path, "a")
+        self._size = 0
         self.rotations += 1
         return size
 
-    @contextlib.contextmanager
     def span(self, name: str, cat: str = "phase",
-             on_close: Callable[[float, float], None] | None = None,
-             **args: Any) -> Iterator[None]:
-        """Context manager recording the enclosed interval.
-
-        ``on_close(wall_start_s, duration_s)`` fires after the span is
-        recorded — the single timing code path PhaseTracer and other
-        accumulators hang their accounting on. The interval is measured
-        whenever an ``on_close`` is given, even on a disabled recorder
-        (the caller's accounting must not depend on sink state).
-        """
-        if not self.enabled and on_close is None:
-            yield
-            return
-        t0 = time.time()
-        p0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - p0
-            self.record(name, t0, dt, cat, **args)
-            if on_close is not None:
-                on_close(t0, dt)
+             **args: Any) -> "_Span | _NullSpan":
+        """Context manager recording the enclosed interval; a disabled
+        recorder measures nothing. ``with ... as sp`` gives
+        ``sp.set(**args)`` for what is known only at the end and, once
+        closed, ``sp.dur`` (seconds). The thread's hook (``set_hook``)
+        fires after the span is recorded: the one completion path every
+        accumulator hangs on."""
+        if not self.enabled:
+            return _NULL_SPAN
+        ctx = self._context
+        return _Span(self, name, cat, {**ctx, **args} if ctx else args)
 
     def spans(self, name: str | None = None) -> list[dict]:
         with self._lock:
             out = list(self.ring)
         return out if name is None else [s for s in out if s["name"] == name]
+
+    def flush(self) -> None:
+        """Push the buffered tail of the JSONL sink to the file."""
+        with self._lock:
+            if self._fh is not None:
+                self._fh.flush()
 
     def close(self) -> None:
         with self._lock:
@@ -189,14 +315,14 @@ def get_recorder() -> SpanRecorder:
     return _recorder
 
 
-def configure(path: str | None, pid: int = 0,
-              max_bytes: int = 0) -> SpanRecorder:
+def configure(path: str | None, pid: int = 0, max_bytes: int = 0,
+              annotate: Callable[[str], Any] | None = None) -> SpanRecorder:
     """Install a fresh default recorder writing to ``path`` (None =
     memory-only, still enabled). Closes the previous recorder's sink."""
     global _recorder
     with _rec_lock:
-        old, _recorder = _recorder, SpanRecorder(path, pid=pid,
-                                                 max_bytes=max_bytes)
+        old, _recorder = _recorder, SpanRecorder(
+            path, pid=pid, max_bytes=max_bytes, annotate=annotate)
         old.close()
     return _recorder
 

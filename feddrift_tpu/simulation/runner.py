@@ -20,6 +20,7 @@ Round structure parity:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from functools import partial
@@ -207,10 +208,14 @@ class Experiment:
         # folds both into one Perfetto-loadable trace.json. Every process
         # records (pid = its lane in the merged timeline); only the
         # coordinator gets a file sink, like the event bus.
+        # Every span also enters a TraceAnnotation of its name, so a
+        # profiler capture (xla_trace, the benchmark's) shows the runner's
+        # segments in its host plane; obs/spans.py itself stays JAX-free.
         self.spans = obs.spans.configure(
             os.path.join(out_dir, "spans.jsonl")
             if (out_dir and self.is_coordinator) else None,
-            pid=jax.process_index(), max_bytes=obs_cap)
+            pid=jax.process_index(), max_bytes=obs_cap,
+            annotate=jax.profiler.TraceAnnotation)
         # Host-plane observatory (obs/hostprof.py): the per-subsystem
         # host-seconds/bytes ledger finalized at each iteration tail, and
         # the optional sampling stack profiler (cfg.hostprof_hz > 0) whose
@@ -406,13 +411,14 @@ class Experiment:
                             warmup=cfg.divergence_warmup_rounds)
             if cfg.divergence_guard else None)
         self.tracer = PhaseTracer(registry=obs.registry(), spans=self.spans)
-        # Round-breakdown accounting: per-iteration segment accumulator
-        # (cohort_prep / h2d / dispatch / device_compute / writeback /
-        # drift_decision / eval); whatever the segments do not cover is the
-        # dispatch gap — host time the device spent idle. Finalized into one
-        # round_breakdown event + host_overhead_frac gauge per iteration.
+        # Round-breakdown accounting: a segment is the summed SELF time of
+        # the cat="round" spans of that name, recorded where the work
+        # happens (here, core/step.py's dispatch wrapper, multihost.fetch);
+        # whatever no span claims is the dispatch gap — unclaimed host
+        # time. Finalized into one round_breakdown event +
+        # host_overhead_frac gauge per iteration.
         self._segs: dict[str, float] = {}
-        self._profiled_rounds = 0
+        self._seg_owner: "str | None" = None
         self.last_round_breakdown: "dict | None" = None
         # The ground-truth concept matrix rides along in run_start for
         # synthetic datasets: obs/lineage.py scores the recorded
@@ -776,46 +782,86 @@ class Experiment:
     # ------------------------------------------------------------------
     # round_breakdown segments that are HOST control-plane work double-
     # book into the hostprof ledger (device_compute/h2d/dispatch do not);
-    # _seg_add is the single accumulation point for both the iteration
+    # _on_span is the single accumulation point for both the iteration
     # and the megastep path, so this map covers both.
     _LEDGER_SEGS = {"cohort_prep": "cohort_plan",
                     "writeback": "registry_writeback",
                     "drift_decision": "drift_decision"}
 
-    def _seg_add(self, name: str, dt: float) -> None:
-        self._segs[name] = self._segs.get(name, 0.0) + dt
+    # ... and the cat="round" spans that cover a whole PhaseTracer phase
+    # feed its totals: one span per interval, two accountings of it
+    _PHASE_OF = {"cohort_prep": "cohort", "drift_decision": "cluster",
+                 "eval": "eval"}
+
+    def _on_span(self, name: str, cat: str, dur: float,
+                 self_s: float) -> None:
+        """This thread's completion hook on the recorder: a closed
+        cat="round" span adds its self time to the segment of its name,
+        whichever layer recorded it. Inside the drift decision it goes to
+        that segment instead: drift_decision stays everything the algorithm
+        does at the time-step boundary, the dispatches and device waits it
+        asks for included (their spans are recorded and counted all the
+        same)."""
+        if cat != "round":
+            return
+        phase = self._PHASE_OF.get(name)
+        if phase is not None:
+            self.tracer.add(phase, dur)
+        name = self._seg_owner or name
+        self._segs[name] = self._segs.get(name, 0.0) + self_s
         sub = self._LEDGER_SEGS.get(name)
         if sub is not None:
-            self._ledger.add_seconds(sub, dt)
+            self._ledger.add_seconds(sub, self_s)
+
+    def _claim_spans(self) -> None:
+        """A time step starts: core/step.py and multihost.fetch record on
+        the process-wide recorder, so the experiment that runs the step
+        records there too (a later Experiment may have installed a new one)
+        and takes this thread's completion hook."""
+        self.spans = self.tracer.spans = obs.spans.get_recorder()
+        self.spans.set_hook(self._on_span)
+        self._segs = {}
 
     def _seg(self, name: str, **args):
-        """Sub-span of the iteration (cat="round") that also accumulates
-        into the per-iteration round_breakdown segments."""
-        return self.spans.span(
-            name, cat="round",
-            on_close=lambda _w, dt, _n=name: self._seg_add(_n, dt), **args)
+        """Sub-span of the iteration (cat="round"); its self time lands in
+        the round_breakdown segment of the same name (_on_span)."""
+        return self.spans.span(name, cat="round", **args)
+
+    @contextlib.contextmanager
+    def _drift_decision(self):
+        """begin_iteration / end_iteration: the algorithm's layer (the
+        tracer's "cluster" phase)."""
+        with self._seg("drift_decision"):
+            self._seg_owner = "drift_decision"
+            try:
+                yield
+            finally:
+                self._seg_owner = None
+
+    def _set_context(self, **ctx) -> None:
+        """The ambient iteration/round of the events and of the spans,
+        which core/step.py and comm/multihost.py record without knowing
+        the time step."""
+        self.events.set_context(**ctx)
+        self.spans.set_context(**ctx)
+
+    def _block(self, tree) -> None:
+        """Wait for a dispatched program where no fetch would: the wait is
+        a device_compute span."""
+        with self._seg("device_compute"):
+            # lint: r2-ok (the measured wait itself)
+            jax.block_until_ready(tree)
 
     # ------------------------------------------------------------------
     def run_iteration(self, t: int) -> None:
         cfg = self.cfg
-        t0 = time.time()
-        self._segs = {}
-        self._profiled_rounds = 0
-        self.events.set_context(iteration=t, round=self.global_round)
+        p0 = time.perf_counter()
+        self._claim_spans()
+        self._set_context(iteration=t, round=self.global_round)
         self.events.emit("iteration_start")
         if self.population_mode:
-            # cohort_prep accumulates EXCLUSIVE of the nested h2d staging
-            # span (_prepare_cohort) so the breakdown segments partition
-            # the wall time; the recorded span still covers the whole prep.
-            prep_w, prep_p = time.time(), time.perf_counter()
-            h2d_before = self._segs.get("h2d", 0.0)
-            with self.tracer.phase("cohort"):
+            with self._seg("cohort_prep"):
                 self._prepare_cohort(t)
-            prep_dt = time.perf_counter() - prep_p
-            self.spans.record("cohort_prep", prep_w, prep_dt, cat="round",
-                              iteration=t)
-            self._seg_add("cohort_prep", prep_dt
-                          - (self._segs.get("h2d", 0.0) - h2d_before))
         if self.divergence_guard is not None:
             # the time step changes the training window/concept: losses
             # legitimately re-spike, so the spike baseline starts fresh
@@ -832,8 +878,7 @@ class Experiment:
             self.algo.set_client_staleness(
                 self.failure_detector.absent_streak,
                 self.failure_detector.suspected)
-        with self.tracer.phase("cluster"), \
-                self._seg("drift_decision", iteration=t):
+        with self._drift_decision():
             # drift detection / clustering
             self.algo.begin_iteration(t)
         if cfg.debug_checks:
@@ -843,8 +888,9 @@ class Experiment:
                 tw, sw, fm, num_models=self.pool.num_models,
                 num_clients=self.C_, num_steps_p1=self.ds.num_steps + 1,
                 sample_num=self.ds.samples_per_step)
-        opt_states = self.step.init_opt_states(
-            self.pool.params, self.pool.num_models, self.C_pad)
+        with self._seg("opt_init"):
+            opt_states = self.step.init_opt_states(
+                self.pool.params, self.pool.num_models, self.C_pad)
 
         if cfg.stream_data:
             if not (self.algo.chunkable(t)
@@ -861,14 +907,13 @@ class Experiment:
             # per-round client stack on host, so rounds cannot fuse
             self._run_rounds(t, opt_states)
 
-        with self.tracer.phase("cluster"), \
-                self._seg("drift_decision", iteration=t):
+        with self._drift_decision():
             self.algo.end_iteration(t)
         if self.population_mode:
-            with self._seg("writeback", iteration=t):
+            with self._seg("writeback"):
                 self._cohort_writeback(t)
         if self.cfg.checkpoint_every_iteration and self.out_dir:
-            with self._seg("writeback", iteration=t):
+            with self._seg("writeback"):
                 self.save_checkpoint(t)
             self.events.emit("checkpoint_save", path=self.ckpt_path())
         if self.population_mode:
@@ -876,7 +921,7 @@ class Experiment:
             # AFTER this iteration's checkpoint so the churned registry the
             # draw commits is never ahead of the state a resume reloads
             self._stage_cohort(t + 1)
-        wall = time.time() - t0
+        wall = time.perf_counter() - p0
         log.info("iteration %d done in %.1fs (Test/Acc=%.4f)", t,
                  wall, self.logger.last("Test/Acc", -1))
         self.tracer.log_summary(prefix=f"iter {t}: ")
@@ -903,13 +948,16 @@ class Experiment:
         # One trace lane entry spanning the whole time step, and a live
         # HBM watermark per iteration (silently a no-op on backends
         # without memory_stats — CPU).
-        self.spans.record("iteration", t0, wall, cat="runner", iteration=t)
-        # Critical-path breakdown: the measured segments partition the
-        # iteration wall; the residual is the dispatch gap (host time in
-        # which no segment — and in particular no device wait — was
-        # running). host_overhead_frac = 1 - device_compute/wall is the
-        # fraction the accelerator sat idle; `critical_path <run_dir>` and
-        # the regress host-overhead ceiling both consume this event.
+        self.spans.record("iteration", self.spans.wall(p0), wall,
+                          cat="runner", iteration=t)
+        # Critical-path breakdown: the segments (self times of the
+        # cat="round" spans) partition the iteration wall; the residual is
+        # the dispatch gap: host time no span claimed. device_compute is
+        # the time the host was blocked on the device (wait plus copy), so
+        # host_overhead_frac = 1 - device_compute/wall is the share of the
+        # wall in which the host was NOT waiting for the device;
+        # `critical_path <run_dir>` and the regress host-overhead ceiling
+        # both consume this event.
         gap = max(wall - sum(self._segs.values()), 0.0)
         dev = self._segs.get("device_compute", 0.0)
         host_frac = min(max(1.0 - dev / max(wall, 1e-9), 0.0), 1.0)
@@ -918,7 +966,8 @@ class Experiment:
         self.last_round_breakdown = {
             "iteration": t, "wall_s": round(wall, 6),
             "rounds": cfg.comm_round,
-            "profiled_rounds": self._profiled_rounds,
+            # every round's device wait is measured (kept for consumers)
+            "profiled_rounds": cfg.comm_round,
             "segments": segments, "dispatch_gap_s": round(gap, 6),
             "host_overhead_frac": round(host_frac, 6)}
         self.events.emit("round_breakdown", **self.last_round_breakdown)
@@ -1011,15 +1060,19 @@ class Experiment:
                                     self.failure_detector.suspected.tolist())
         return masks
 
-    def _check_divergence(self, losses, n) -> bool:
-        """Guard one round's fetched losses; True = diverged (caller rolls
-        back). Fetch goes through multihost so every process of a
-        multi-controller run sees identical arrays and stays in lockstep."""
+    def _check_divergence(self, losses, n, fetched: bool = False) -> bool:
+        """Guard one round's losses; True = diverged (caller rolls back).
+        The fetch goes through multihost so every process of a
+        multi-controller run sees identical arrays and stays in lockstep;
+        ``fetched`` says the caller hands in host arrays it fetched that
+        way already (the megastep replay)."""
         if self.divergence_guard is None:
             return False
-        l_host, n_host = multihost.fetch((losses, n))
-        diverged, reason, observed = self.divergence_guard.check(
-            np.asarray(l_host), np.asarray(n_host))
+        with self._seg("guard"):
+            if not fetched:
+                losses, n = multihost.fetch((losses, n))
+            diverged, reason, observed = self.divergence_guard.check(
+                np.asarray(losses), np.asarray(n))
         if not diverged:
             return False
         g = self.divergence_guard
@@ -1143,49 +1196,37 @@ class Experiment:
         # lint: hot-path-begin (per-round dispatch loop — every host sync
         # here serializes all comm_round dispatches)
         for r in range(cfg.comm_round):
-            self.events.set_context(round=self.global_round)
-            tw, sw, fm, lr_scale = self.algo.round_inputs(t, r)
-            tw = self._pad_clients(tw)                  # phantom clients: w=0
-            sw = self._pad_clients(sw, value=1.0)
-            cm = self._client_masks(t, [r])
-            bm = self._byz_modes([r], t)
-            eids = emasks = ebyz = None
-            if self.hierarchy:
-                eids, emasks, ebyz = self._edge_state(t, [r])
+            self._set_context(round=self.global_round)
+            with self._seg("round_prep"):
+                tw, sw, fm, lr_scale = self.algo.round_inputs(t, r)
+                tw = self._pad_clients(tw)              # phantom clients: w=0
+                sw = self._pad_clients(sw, value=1.0)
+                cm = self._client_masks(t, [r])
+                bm = self._byz_modes([r], t)
+                eids = emasks = ebyz = None
+                if self.hierarchy:
+                    eids, emasks, ebyz = self._edge_state(t, [r])
+                # the round's key and device copies of its host-made rows
+                # (eager dispatches, each tens of microseconds)
+                rkey = round_key(self.key, t, r)
+                cm, bm, eids, emasks, ebyz = (
+                    None if a is None else jnp.asarray(a[0])
+                    for a in (cm, bm, eids, emasks, ebyz))
             prev_params = self.pool.params
-            profiled = (cfg.trace_sync
-                        or self.global_round % cfg.profile_rounds == 0)
             with self.tracer.phase("train_round"):
-                disp0 = time.perf_counter()
                 (new_params, opt_states, client_params, n, losses, agg_stats,
                  codec_prev) = self.step.train_round(
-                    prev_params, opt_states, round_key(self.key, t, r),
-                    self.x, self.y, tw, sw, fm, lr_scale,
-                    None if cm is None else jnp.asarray(cm[0]),
-                    None if bm is None else jnp.asarray(bm[0]),
+                    prev_params, opt_states, rkey, self.x, self.y, tw, sw,
+                    fm, lr_scale, cm, bm,
                     self._byz_stale if (byz is not None and byz.has_stale)
                     else None,
-                    None if eids is None else jnp.asarray(eids[0]),
-                    None if emasks is None else jnp.asarray(emasks[0]),
-                    None if ebyz is None else jnp.asarray(ebyz[0]),
-                    self._codec_prev,
+                    eids, emasks, ebyz, self._codec_prev,
                     keep_client_params=keep_cp, with_agg_stats=True)
-                self._seg_add("dispatch", time.perf_counter() - disp0)
-                if profiled:
-                    # dispatch-to-ready sample (every cfg.profile_rounds-th
-                    # global round; trace_sync profiles every round): the
-                    # blocked wait IS the device-compute segment, and it
-                    # attributes device time to this phase instead of letting
-                    # async dispatch spill it into whichever phase blocks next
-                    blk_w, blk0 = time.time(), time.perf_counter()
-                    # lint: r2-ok (attribution sample, rate-gated)
-                    jax.block_until_ready(new_params)
-                    blk_dt = time.perf_counter() - blk0
-                    self.spans.record("device_compute", blk_w, blk_dt,
-                                      cat="round", iteration=t,
-                                      round=self.global_round)
-                    self._seg_add("device_compute", blk_dt)
-                    self._profiled_rounds += 1
+                if cfg.trace_sync:
+                    # attribute the device time to this phase instead of
+                    # letting async dispatch spill it into whichever call
+                    # blocks next (the guard's fetch, without trace_sync)
+                    self._block(new_params)
                 if byz is not None and byz.has_stale:
                     self._byz_stale = client_params
                 if self.step.codec == "delta":
@@ -1199,23 +1240,22 @@ class Experiment:
                     # diverged step contaminated both); skip after_round and
                     # this round's eval — its numbers would be garbage
                     self.pool.params = prev_params
-                    opt_states = self.step.init_opt_states(
-                        self.pool.params, self.pool.num_models, self.C_pad)
+                    with self._seg("opt_init"):
+                        opt_states = self.step.init_opt_states(
+                            self.pool.params, self.pool.num_models,
+                            self.C_pad)
                     self.divergence_guard.record_rollback()
                     self.global_round += 1
                     continue
-                wb0 = time.perf_counter()
-                if self.secure_driver is not None:
-                    new_params = self._secure_substitute(
-                        prev_params, new_params, client_params, n)
-                self.pool.params = self.algo.after_round(
-                    t, r, prev_params, new_params, client_params, n)
-                self._seg_add("writeback", time.perf_counter() - wb0)
+                with self._seg("writeback"):
+                    if self.secure_driver is not None:
+                        new_params = self._secure_substitute(
+                            prev_params, new_params, client_params, n)
+                    self.pool.params = self.algo.after_round(
+                        t, r, prev_params, new_params, client_params, n)
             if r % cfg.frequency_of_the_test == 0 or r == cfg.comm_round - 1:
-                ev0 = time.perf_counter()
-                with self.tracer.phase("eval"):
+                with self._seg("eval"):
                     self.evaluate(t, r)
-                self._seg_add("eval", time.perf_counter() - ev0)
             self.global_round += 1
         # lint: hot-path-end
 
@@ -1288,40 +1328,46 @@ class Experiment:
         cfg = self.cfg
         R, freq = cfg.comm_round, cfg.frequency_of_the_test
         it_key = iteration_key(self.key, t)
-        tw, sw, fm, lr_scale = self.algo.round_inputs(t, 0)
-        tw = self._pad_clients(tw)
-        sw = self._pad_clients(sw, value=1.0)
-        if stream:
-            tw_np = np.asarray(tw)
-            if np.delete(tw_np, t, axis=2).any():
-                raise ValueError("stream_data: algorithm weights reference "
-                                 "steps other than the current one")
-            tw2 = np.zeros((*tw_np.shape[:2], 2), dtype=tw_np.dtype)
-            tw2[:, :, 0] = tw_np[:, :, t]
-            tw = jnp.asarray(tw2)
-            x, y = self._stream_view(t)
-            t_idx = 0
-        else:
-            x, y = self.x, self.y
-            t_idx = t
         g0 = self.global_round
-        cms = self._client_masks(t, range(R))
-        bms = self._byz_modes(range(R), t)
-        eids = emasks = ebyz = None
-        if self.hierarchy:
-            # whole-step edge plan up front: kills/re-homes land between
-            # scanned rounds exactly as they would on the per-round path
-            eids, emasks, ebyz = self._edge_state(t, range(R))
-        byz_stale = self.byzantine is not None and self.byzantine.has_stale
-        # The fused program DONATES its params input (HBM economy), so the
-        # divergence rollback target must live on host: a numpy snapshot of
-        # the iteration-start pool — the same D2H the default per-iteration
-        # checkpoint already pays, taken only when the guard is armed.
-        host_prev = (jax.tree_util.tree_map(np.asarray, self.pool.params)
-                     if self.divergence_guard is not None else None)
+        with self._seg("round_prep"):
+            tw, sw, fm, lr_scale = self.algo.round_inputs(t, 0)
+            tw = self._pad_clients(tw)
+            sw = self._pad_clients(sw, value=1.0)
+            if stream:
+                tw_np = np.asarray(tw)
+                if np.delete(tw_np, t, axis=2).any():
+                    raise ValueError("stream_data: algorithm weights "
+                                     "reference steps other than the "
+                                     "current one")
+                tw2 = np.zeros((*tw_np.shape[:2], 2), dtype=tw_np.dtype)
+                tw2[:, :, 0] = tw_np[:, :, t]
+                tw = jnp.asarray(tw2)
+                x, y = self._stream_view(t)
+                t_idx = 0
+            else:
+                x, y = self.x, self.y
+                t_idx = t
+            cms = self._client_masks(t, range(R))
+            bms = self._byz_modes(range(R), t)
+            eids = emasks = ebyz = None
+            if self.hierarchy:
+                # whole-step edge plan up front: kills/re-homes land between
+                # scanned rounds exactly as they would on the per-round path
+                eids, emasks, ebyz = self._edge_state(t, range(R))
+            byz_stale = (self.byzantine is not None
+                         and self.byzantine.has_stale)
+            # The fused program DONATES its params input (HBM economy), so
+            # the divergence rollback target must live on host: a numpy
+            # snapshot of the iteration-start pool — the same D2H the
+            # default per-iteration checkpoint already pays, taken only when
+            # the guard is armed. It blocks on the device like a fetch.
+            host_prev = None
+            if self.divergence_guard is not None:
+                with self._seg("device_compute"):
+                    host_prev = jax.tree_util.tree_map(np.asarray,
+                                                       self.pool.params)
         # lint: hot-path-begin (fused dispatch: one program per time step)
         with self.tracer.phase("train_round"):
-            disp0 = time.perf_counter()
             new_params, opt_states, n, losses, bufs, total, agg_stats = \
                 self.step.train_iteration_eval(
                     self.pool.params, opt_states, it_key, x, y,
@@ -1332,18 +1378,10 @@ class Experiment:
                     None if emasks is None else jnp.asarray(emasks),
                     None if ebyz is None else jnp.asarray(ebyz),
                     byz_stale=byz_stale, with_agg_stats=True)
-            self._seg_add("dispatch", time.perf_counter() - disp0)
             # One dispatch covers all R rounds, so one dispatch-to-ready
-            # sample covers them too (the stats/eval fetches below would
+            # wait covers them too (the stats/eval fetches below would
             # block here anyway — this only attributes the wait).
-            blk_w, blk0 = time.time(), time.perf_counter()
-            # lint: r2-ok (one dispatch-to-ready sample per fused step)
-            jax.block_until_ready(new_params)
-            blk_dt = time.perf_counter() - blk0
-            self.spans.record("device_compute", blk_w, blk_dt, cat="round",
-                              iteration=t, round=g0)
-            self._seg_add("device_compute", blk_dt)
-            self._profiled_rounds += R
+            self._block(new_params)
             if self._robust_active or self.hierarchy:
                 # one bulk [R, M, 3] (hierarchy: [R, 1+E, M, 3]) fetch
                 # -> one event per fused round
@@ -1361,12 +1399,10 @@ class Experiment:
                 self.divergence_guard.record_rollback()
                 self.global_round = g0 + R
                 return
-            wb0 = time.perf_counter()
-            self.pool.params = self.algo.after_round(
-                t, R - 1, None, new_params, None, n)
-            self._seg_add("writeback", time.perf_counter() - wb0)
-        ev0 = time.perf_counter()
-        with self.tracer.phase("eval"):
+            with self._seg("writeback"):
+                self.pool.params = self.algo.after_round(
+                    t, R - 1, None, new_params, None, n)
+        with self._seg("eval"):
             C = self.C_
             # lint: r2-ok (the design point: ONE bulk D2H per time step)
             bufs, total, n = multihost.fetch((bufs, total, n))
@@ -1376,7 +1412,6 @@ class Experiment:
                 self._log_eval(t, corr_tr[slot][:, :C], loss_tr[slot][:, :C],
                                corr_te[slot][:, :C], loss_te[slot][:, :C],
                                total[:C])
-        self._seg_add("eval", time.perf_counter() - ev0)
         self.global_round = g0 + R
         # lint: hot-path-end
         # The final eval slot holds acc(final params, step t) and
@@ -1499,9 +1534,8 @@ class Experiment:
         non-idempotent population/edge-fault bookkeeping)."""
         cfg = self.cfg
         R, freq = cfg.comm_round, cfg.frequency_of_the_test
-        block_t0 = time.time()
-        self._segs = {}
-        self._profiled_rounds = 0
+        block_p0 = time.perf_counter()
+        self._claim_spans()
         g0 = self.global_round
         # -- plan ------------------------------------------------------
         # lint: hot-path-begin (megastep plan: K-step cohort/fault stacking)
@@ -1512,28 +1546,18 @@ class Experiment:
         sw = fm = lr_scale = None
         for j in range(K):
             t = t0 + j
-            self.events.set_context(iteration=t, round=g0 + j * R)
+            self._set_context(iteration=t, round=g0 + j * R)
             self.events.emit("iteration_start", megastep_k=K)
             if self.population_mode:
-                # identical accounting to run_iteration: cohort_prep
-                # exclusive of the nested h2d span
-                prep_w, prep_p = time.time(), time.perf_counter()
-                h2d_before = self._segs.get("h2d", 0.0)
-                with self.tracer.phase("cohort"):
+                with self._seg("cohort_prep"):
                     self._prepare_cohort(t)
-                prep_dt = time.perf_counter() - prep_p
-                self.spans.record("cohort_prep", prep_w, prep_dt,
-                                  cat="round", iteration=t)
-                self._seg_add("cohort_prep", prep_dt
-                              - (self._segs.get("h2d", 0.0) - h2d_before))
             self._byz_stale = None
             self._codec_prev = None
             if self.failure_detector is not None:
                 self.algo.set_client_staleness(
                     self.failure_detector.absent_streak,
                     self.failure_detector.suspected)
-            with self.tracer.phase("cluster"), \
-                    self._seg("drift_decision", iteration=t):
+            with self._drift_decision():
                 self.algo.begin_iteration(t)
             if cfg.debug_checks:
                 from feddrift_tpu.utils.invariants import check_round_inputs
@@ -1542,22 +1566,23 @@ class Experiment:
                     tw_d, sw_d, fm_d, num_models=self.pool.num_models,
                     num_clients=self.C_, num_steps_p1=self.ds.num_steps + 1,
                     sample_num=self.ds.samples_per_step)
-            tw, sw, fm, lr_scale = self.algo.round_inputs(t, 0)
-            if fm is not getattr(self.algo, "_ones_feat_mask", None):
-                raise RuntimeError(
-                    "megastep requires the algorithm's plain all-ones "
-                    "feature mask (megastep_horizon contract violated)")
-            tws.append(self._pad_clients(tw))
-            cms_list.append(self._client_masks(t, range(R)))
-            if bms_list is not None:
-                bms_list.append(self._byz_modes(range(R), t))
-            if self.hierarchy:
-                # sequential per-step planning: edge kills/re-homes land
-                # between steps exactly as on the per-iteration path
-                eid_j, em_j, eb_j = self._edge_state(t, range(R))
-                eids_list.append(eid_j)
-                emasks_list.append(em_j)
-                ebyz_list.append(eb_j)
+            with self._seg("round_prep"):
+                tw, sw, fm, lr_scale = self.algo.round_inputs(t, 0)
+                if fm is not getattr(self.algo, "_ones_feat_mask", None):
+                    raise RuntimeError(
+                        "megastep requires the algorithm's plain all-ones "
+                        "feature mask (megastep_horizon contract violated)")
+                tws.append(self._pad_clients(tw))
+                cms_list.append(self._client_masks(t, range(R)))
+                if bms_list is not None:
+                    bms_list.append(self._byz_modes(range(R), t))
+                if self.hierarchy:
+                    # sequential per-step planning: edge kills/re-homes land
+                    # between steps exactly as on the per-iteration path
+                    eid_j, em_j, eb_j = self._edge_state(t, range(R))
+                    eids_list.append(eid_j)
+                    emasks_list.append(em_j)
+                    ebyz_list.append(eb_j)
             if self.population_mode:
                 xs_list.append(self.x)
                 ys_list.append(self.y)
@@ -1570,43 +1595,46 @@ class Experiment:
                 # block-boundary registry commit (see docstring); must
                 # precede the next step's draw, whose staleness view and
                 # assignment history read these columns
-                with self._seg("writeback", iteration=t):
+                with self._seg("writeback"):
                     self._cohort_writeback(t)
                 if j < K - 1:
                     # pipeline the NEXT plan step's gather; the block-exit
                     # stage (t0+K) waits for the block checkpoint below so
                     # a resume never re-applies its churn
                     self._stage_cohort(t + 1)
-        sw = self._pad_clients(sw, value=1.0)
-        time_ws = jnp.stack(tws)                      # [K, M, C_pad, T1]
-        cms = None
-        if cms_list[0] is not None:
-            cms = jnp.asarray(np.stack(cms_list))     # [K, R, C_pad]
-        bms = None
-        if bms_list:
-            bms = jnp.asarray(np.stack(bms_list))     # [K, R, C_pad]
-        eids = emasks = ebyz = None
-        if self.hierarchy:
-            eids = jnp.asarray(np.stack(eids_list))   # [K, R, C_pad]
-            if emasks_list[0] is not None:
-                emasks = jnp.asarray(np.stack(emasks_list))   # [K, R, E]
-            if any(b is not None for b in ebyz_list):
-                zeros = np.zeros((R, cfg.hierarchy_edges), dtype=np.int32)
-                ebyz = jnp.asarray(np.stack(
-                    [b if b is not None else zeros for b in ebyz_list]))
-        x_steps = y_steps = None
-        if self.population_mode:
-            # [K, C_pad, T1, N, ...] stacked per-step cohort shards — the
-            # scan's data input; built identically every block so the jit
-            # signature (and therefore the compile cache) is stable
-            x_steps = jnp.stack(xs_list)
-            y_steps = jnp.stack(ys_list)
-        byz_stale = self.byzantine is not None and self.byzantine.has_stale
+        # what follows belongs to the block: its spans carry the block's
+        # first time step (the events keep the last plan step's context)
+        self.spans.set_context(iteration=t0, round=g0)
+        with self._seg("round_prep"):
+            sw = self._pad_clients(sw, value=1.0)
+            time_ws = jnp.stack(tws)                      # [K, M, C_pad, T1]
+            cms = None
+            if cms_list[0] is not None:
+                cms = jnp.asarray(np.stack(cms_list))     # [K, R, C_pad]
+            bms = None
+            if bms_list:
+                bms = jnp.asarray(np.stack(bms_list))     # [K, R, C_pad]
+            eids = emasks = ebyz = None
+            if self.hierarchy:
+                eids = jnp.asarray(np.stack(eids_list))   # [K, R, C_pad]
+                if emasks_list[0] is not None:
+                    emasks = jnp.asarray(np.stack(emasks_list))   # [K, R, E]
+                if any(b is not None for b in ebyz_list):
+                    zeros = np.zeros((R, cfg.hierarchy_edges), dtype=np.int32)
+                    ebyz = jnp.asarray(np.stack(
+                        [b if b is not None else zeros for b in ebyz_list]))
+            x_steps = y_steps = None
+            if self.population_mode:
+                # [K, C_pad, T1, N, ...] stacked per-step cohort shards — the
+                # scan's data input; built identically every block so the jit
+                # signature (and therefore the compile cache) is stable
+                x_steps = jnp.stack(xs_list)
+                y_steps = jnp.stack(ys_list)
+            byz_stale = self.byzantine is not None and self.byzantine.has_stale
         # lint: hot-path-end
         # -- dispatch --------------------------------------------------
         # lint: hot-path-begin (megastep: one program per K-step block)
         with self.tracer.phase("train_round"):
-            disp0 = time.perf_counter()
             ps, ns, ls, bufs, total, agg_stats = self.step.train_megastep(
                 self.pool.params, self.key,
                 None if self.population_mode else self.x,
@@ -1614,15 +1642,8 @@ class Experiment:
                 time_ws, sw, fm,
                 lr_scale, jnp.int32(t0), R, freq, K, cms, bms, eids,
                 emasks, ebyz, x_steps, y_steps, byz_stale=byz_stale)
-            self._seg_add("dispatch", time.perf_counter() - disp0)
-            blk_w, blk0 = time.time(), time.perf_counter()
-            # lint: r2-ok (one dispatch-to-ready sample per K-step block)
-            jax.block_until_ready(ps)
-            blk_dt = time.perf_counter() - blk0
-            self.spans.record("device_compute", blk_w, blk_dt, cat="round",
-                              iteration=t0, round=g0)
-            self._seg_add("device_compute", blk_dt)
-            self._profiled_rounds += K * R
+            # one dispatch-to-ready wait per K-step block
+            self._block(ps)
         # lint: hot-path-end
         # -- replay ----------------------------------------------------
         C = self.C_
@@ -1651,7 +1672,7 @@ class Experiment:
         for j in range(K):
             t = t0 + j
             gj = g0 + j * R
-            self.events.set_context(iteration=t, round=gj)
+            self._set_context(iteration=t, round=gj)
             if self.population_mode:
                 # metrics masking + eval logging must see THIS step's
                 # cohort, not the last plan step's
@@ -1665,13 +1686,12 @@ class Experiment:
                 # eval logging (the buffers hold diverged numbers); the
                 # round cadence and iteration lifecycle still advance
                 self.global_round = gj + R
-                with self.tracer.phase("cluster"), \
-                        self._seg("drift_decision", iteration=t):
+                with self._drift_decision():
                     self.algo.end_iteration(t)
                 continue
             if self.divergence_guard is not None:
                 self.divergence_guard.new_window()
-            if self._check_divergence(ls_h[j], ns_h[j]):
+            if self._check_divergence(ls_h[j], ns_h[j], fetched=True):
                 # roll back to the end of block step j-1: the fused
                 # program trained later steps on the diverged trajectory.
                 # For j=0 the pool still holds the pre-block params (the
@@ -1685,27 +1705,22 @@ class Experiment:
                     committed = j + 1
                     break
                 skipping = True
-                with self.tracer.phase("cluster"), \
-                        self._seg("drift_decision", iteration=t):
+                with self._drift_decision():
                     self.algo.end_iteration(t)
                 continue
             step_p = steps_p[j]
-            wb0 = time.perf_counter()
-            self.pool.params = self.algo.after_round(
-                t, R - 1, None, step_p, None, ns_h[j])
-            self._seg_add("writeback", time.perf_counter() - wb0)
-            ev0 = time.perf_counter()
-            with self.tracer.phase("eval"):
+            with self._seg("writeback"):
+                self.pool.params = self.algo.after_round(
+                    t, R - 1, None, step_p, None, ns_h[j])
+            with self._seg("eval"):
                 for slot, r in enumerate(evs):
                     self.global_round = gj + r
                     self._log_eval(
                         t, corr_tr[j, slot][:, :C], loss_tr[j, slot][:, :C],
                         corr_te[j, slot][:, :C], loss_te[j, slot][:, :C],
                         total_h[:C])
-            self._seg_add("eval", time.perf_counter() - ev0)
             self.global_round = gj + R
-            with self.tracer.phase("cluster"), \
-                    self._seg("drift_decision", iteration=t):
+            with self._drift_decision():
                 self.algo.end_iteration(t)
             final_p = step_p
         # Final-slot accuracy offer, exactly like the K=1 fused path —
@@ -1720,7 +1735,7 @@ class Experiment:
             # one checkpoint per BLOCK (the per-iteration generations
             # between block boundaries are skipped — each would overwrite
             # the same path anyway; resume granularity becomes the block)
-            with self._seg("writeback", iteration=last_t):
+            with self._seg("writeback"):
                 self.save_checkpoint(last_t)
             self.events.emit("checkpoint_save", path=self.ckpt_path())
         if self.population_mode:
@@ -1731,7 +1746,7 @@ class Experiment:
             # on the live active mask, so double-application diverges)
             self._stage_cohort(t0 + committed)
         # -- per-iteration telemetry records ---------------------------
-        wall = time.time() - block_t0
+        wall = time.perf_counter() - block_p0
         log.info("megastep %d..%d (K=%d) done in %.1fs (Test/Acc=%.4f)",
                  t0, last_t, K, wall, self.logger.last("Test/Acc", -1))
         self.tracer.log_summary(prefix=f"iters {t0}..{last_t}: ")
@@ -1754,7 +1769,7 @@ class Experiment:
         segments["dispatch_gap"] = round(gap / committed, 6)
         for j in range(committed):
             t = t0 + j
-            self.events.set_context(iteration=t, round=g0 + j * R + R - 1)
+            self._set_context(iteration=t, round=g0 + j * R + R - 1)
             self.events.emit(
                 "iteration_end", wall_s=round(wall_j, 4), rounds=R,
                 examples=examples,
@@ -1762,7 +1777,8 @@ class Experiment:
                 rounds_per_s=round(R / max(wall_j, 1e-9), 3),
                 test_acc=self.logger.last("Test/Acc"),
                 megastep_k=K, phases=phases)
-            self.spans.record("iteration", block_t0 + j * wall_j, wall_j,
+            self.spans.record("iteration",
+                              self.spans.wall(block_p0) + j * wall_j, wall_j,
                               cat="runner", iteration=t)
             self.last_round_breakdown = {
                 "iteration": t, "wall_s": round(wall_j, 6), "rounds": R,

@@ -39,7 +39,6 @@ from __future__ import annotations
 import contextlib
 import logging
 import threading
-import time
 from collections import defaultdict
 from typing import Iterator
 
@@ -53,12 +52,14 @@ class PhaseTracer:
     thread-safe.
 
     The interval measurement itself lives in ``obs.spans.SpanRecorder``
-    (one timing code path for the whole repo): ``phase()`` is a thin shim
-    over ``SpanRecorder.span(..., on_close=...)`` that hangs the
-    total/count accounting and the ``phase_seconds`` histogram off the
-    span's completion hook. Without an explicit ``spans=`` recorder a
-    private memory-only recorder measures (accounting never depends on
-    whether a run sink is armed).
+    (one timing code path for the whole repo): ``phase()`` is a
+    ``cat="phase"`` span whose duration goes through ``add``, the
+    total/count accounting and the ``phase_seconds`` histogram. The runner
+    feeds ``add`` from its ``cat="round"`` spans where one of those covers
+    a phase (eval, the drift decision, the cohort preparation), so no
+    interval is recorded twice. Without an explicit ``spans=`` recorder a
+    private memory-only one measures; a disabled recorder counts the
+    phases and measures nothing.
     """
 
     def __init__(self, registry=None, spans=None) -> None:
@@ -66,21 +67,25 @@ class PhaseTracer:
         self.counts: dict[str, int] = defaultdict(int)
         self._lock = threading.Lock()
         self._registry = registry
-        self._spans = spans if spans is not None \
-            else SpanRecorder(None, enabled=False)
+        self.spans = spans if spans is not None else SpanRecorder(None)
+
+    def add(self, name: str, dt: float) -> None:
+        """Account one completed interval of ``dt`` seconds to ``name``."""
+        with self._lock:
+            self.totals[name] += dt
+            self.counts[name] += 1
+        if self._registry is not None:
+            self._registry.histogram("phase_seconds",
+                                     phase=name).observe(dt)
 
     @contextlib.contextmanager
     def phase(self, name: str) -> Iterator[None]:
-        def account(_wall0: float, dt: float) -> None:
-            with self._lock:
-                self.totals[name] += dt
-                self.counts[name] += 1
-            if self._registry is not None:
-                self._registry.histogram("phase_seconds",
-                                         phase=name).observe(dt)
-
-        with self._spans.span(name, cat="phase", on_close=account):
-            yield
+        sp = self.spans.span(name, cat="phase")
+        try:
+            with sp:
+                yield
+        finally:
+            self.add(name, sp.dur)
 
     def summary(self) -> dict[str, dict[str, float]]:
         with self._lock:
@@ -137,11 +142,3 @@ def xla_trace(log_dir: str) -> Iterator[None]:
     finally:
         with _trace_lock:
             _trace_active = False
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region inside a trace (shows up on the TraceMe timeline)."""
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield

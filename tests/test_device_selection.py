@@ -76,9 +76,12 @@ class TestNoTpuFailsLoudly:
 
 class TestServeExitCode:
     def _serve(self, monkeypatch, capsys, engine):
-        from feddrift_tpu import cli
+        from feddrift_tpu import cli, obs
         from feddrift_tpu.platform import serving
         monkeypatch.setattr(serving, "load_engine", lambda *a, **k: engine)
+        # serve reports the process-wide jit_compiles* counters: start them
+        # from nought, whatever ran before this test on its worker
+        obs.registry().reset()
         rc = cli.main(["serve", "unused-run-dir", "--requests", "8",
                        "--concurrency", "2", "--buckets", "1,2,4"])
         return rc, json.loads(capsys.readouterr().out)

@@ -65,9 +65,31 @@ class TestPhaseTracer:
 
 
 class TestAnnotate:
-    def test_annotation_context(self):
-        import jax.numpy as jnp
-        from feddrift_tpu.utils.tracing import annotate
-        with annotate("region"):
-            x = jnp.ones((4,)) * 2
-        assert float(x.sum()) == 8.0
+    def test_program_spans_in_the_profilers_host_plane(self, tmp_path):
+        """Under xla_trace the runner's spans are TraceAnnotations of the
+        same name: a capture shows the runner next to the device ops."""
+        import glob
+        import os
+
+        import jax
+        from feddrift_tpu.config import ExperimentConfig
+        from feddrift_tpu.simulation.runner import Experiment
+        from feddrift_tpu.utils.tracing import xla_trace
+        cfg = ExperimentConfig(dataset="sea", model="fnn",
+                               concept_drift_algo="win-1",
+                               train_iterations=2, comm_round=2, epochs=1,
+                               sample_num=16, batch_size=8,
+                               client_num_in_total=4, client_num_per_round=4,
+                               concept_num=2, frequency_of_the_test=1,
+                               report_client=0, chunk_rounds=False)
+        exp = Experiment(cfg)
+        exp.run_iteration(0)
+        with xla_trace(str(tmp_path)):
+            exp.run_iteration(1)
+        path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        host, = [p for p in jax.profiler.ProfileData.from_file(path).planes
+                 if p.name == "/host:CPU"]
+        names = {ev.name for line in host.lines for ev in line.events}
+        assert {"dispatch", "device_compute", "guard", "writeback",
+                "round_prep", "eval"} <= names
