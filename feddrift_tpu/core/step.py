@@ -239,14 +239,41 @@ class TrainStep:
 
     # ------------------------------------------------------------------
     def init_opt_states(self, params, num_models: int, num_clients: int):
-        """[M, C, ...] optimizer states, fresh at each time-step boundary."""
+        """[M, C, ...] optimizer states, fresh at each time-step boundary.
+
+        The traceable body: the megastep scan and ``eval_shape`` callers use
+        it as it is. Called eagerly it is one dispatch per op and leaf; the
+        runner dispatches it as one program, ``fresh_opt_states``."""
         def init_one(p):
             return self.optimizer.init(p)
         per_model = jax.vmap(init_one)(params)          # [M, ...]
         return jax.tree_util.tree_map(
             lambda s: jnp.broadcast_to(
-                s[:, None], (s.shape[0], num_clients, *s.shape[1:])).copy(),
+                s[:, None], (s.shape[0], num_clients, *s.shape[1:])),
             per_model)
+
+    def fresh_opt_states(self, params, num_clients: int):
+        """``init_opt_states`` as ONE tracked program, its outputs committed
+        in the placement ``train_round`` returns its optimizer states in, so
+        that the round program meets one signature however often the states
+        are made anew."""
+        args = (params, num_clients)
+        with self._tracked("fresh_opt_states",
+                           type(self)._fresh_opt_states_jit, args,
+                           sig=(params,), static=(num_clients,)):
+            return self._fresh_opt_states_jit(*args)
+
+    # keep_unused: only the shapes of params are read, and a pruned argument
+    # brings neither its devices nor its commitment to the outputs
+    @partial(jax.jit, static_argnums=(0, 2), keep_unused=True)
+    def _fresh_opt_states_jit(self, params, num_clients: int):
+        num_models = jax.tree_util.tree_leaves(params)[0].shape[0]
+        # the clients axis alone, as GSPMD lays out train_round's states:
+        # split over "models" too they come back so, the pool with them,
+        # and every program that takes the pool meets a second signature
+        return constrain_pool(
+            self.mesh, self.init_opt_states(params, num_models, num_clients),
+            model_axis=None, client_axis=1)
 
     # ------------------------------------------------------------------
     def _local_sgd(self, params, opt_state, key, x_ct, y_ct, w_t, s_n,

@@ -15,6 +15,10 @@ deadline (``straggler_masked``) or the edge that failed
 (``edge_failed``) during that iteration. Pure host-side: no jax, no
 backend, safe to run while the run is still writing.
 
+opt_init holds one tracked dispatch, the program that makes the time
+step's optimizer states; that dispatch's self time is in the dispatch
+segment, so opt_init itself reads microseconds.
+
 The segment sums are checked against the iteration span's wall clock
 (``coverage`` column); by construction the residual dispatch_gap closes
 the budget, so a coverage far from 1.0 means the two streams disagree

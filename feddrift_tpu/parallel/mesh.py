@@ -83,7 +83,8 @@ def _axis_size(mesh: Mesh, name: str) -> int:
     return mesh.shape[name] if name in mesh.axis_names else 0
 
 
-def pool_spec(mesh: Mesh, shape: tuple[int, ...], model_axis: int = 0,
+def pool_spec(mesh: Mesh, shape: tuple[int, ...],
+              model_axis: int | None = 0,
               client_axis: int | None = None) -> P:
     """PartitionSpec for one [M, C, ...]-style leaf on ``mesh``.
 
@@ -104,7 +105,7 @@ def pool_spec(mesh: Mesh, shape: tuple[int, ...], model_axis: int = 0,
     return P(*spec)
 
 
-def constrain_pool(mesh: Mesh | None, tree, model_axis: int = 0,
+def constrain_pool(mesh: Mesh | None, tree, model_axis: int | None = 0,
                    client_axis: int | None = None):
     """``with_sharding_constraint`` every leaf of a model-pool stack.
 

@@ -889,8 +889,8 @@ class Experiment:
                 num_clients=self.C_, num_steps_p1=self.ds.num_steps + 1,
                 sample_num=self.ds.samples_per_step)
         with self._seg("opt_init"):
-            opt_states = self.step.init_opt_states(
-                self.pool.params, self.pool.num_models, self.C_pad)
+            opt_states = self.step.fresh_opt_states(
+                self.pool.params, self.C_pad)
 
         if cfg.stream_data:
             if not (self.algo.chunkable(t)
@@ -1241,9 +1241,8 @@ class Experiment:
                     # this round's eval — its numbers would be garbage
                     self.pool.params = prev_params
                     with self._seg("opt_init"):
-                        opt_states = self.step.init_opt_states(
-                            self.pool.params, self.pool.num_models,
-                            self.C_pad)
+                        opt_states = self.step.fresh_opt_states(
+                            self.pool.params, self.C_pad)
                     self.divergence_guard.record_rollback()
                     self.global_round += 1
                     continue
