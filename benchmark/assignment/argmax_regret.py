@@ -19,6 +19,6 @@ def choose(ref, t: int) -> np.ndarray:
 
 def reading(ref, t: int, prog_assign: np.ndarray) -> float:
     """Read once the reference has followed time step ``t``'s rounds."""
-    acc = ref.eval_matrix(t)[0] / ref.N
+    acc = ref.eval_matrix(t)[0] / ref.labels
     clients = np.arange(acc.shape[1])
     return float((acc.max(axis=0) - acc[prog_assign, clients]).max())

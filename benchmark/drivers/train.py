@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import gc
 import glob
-import importlib
 import inspect
 import json
 import os
@@ -23,7 +22,7 @@ import time
 
 import numpy as np
 
-from benchmark import flops, reference, weights, xplane
+from benchmark import family_of, flops, named, reference, weights, xplane
 from benchmark.run import ROOT, load_json, log, read_per_layer
 
 
@@ -55,8 +54,45 @@ def experiment_config(config, traffic, sizes, seed, clients):
 def assignment_rule(traffic: dict):
     """The job's assignment rule, ``assignment/<name>.py``, found by the
     name the traffic file gives under ``check``."""
-    return importlib.import_module(
-        f"benchmark.assignment.{traffic['check']['assignment']}")
+    return named("assignment", traffic["check"]["assignment"])
+
+
+def optimizer_of(config: dict):
+    """The comparison's side of the client optimizer,
+    ``optimizers/<kind>.py``, found by the ``kind`` of the configuration's
+    ``optimizer`` group."""
+    return named("optimizers", config["optimizer"]["kind"])
+
+
+def numbers_of(config: dict, traffic: dict) -> list[str]:
+    """The numbers ``check`` works out for a cell, in the order of the
+    ``numbers`` line."""
+    return _numbers(optimizer_of(config), assignment_rule(traffic))
+
+
+def _numbers(opt, rule) -> list[str]:
+    """Those of the optimizer's moments only where it has them."""
+    return (["train_loss_gap", "test_loss_gap", rule.NUMBER]
+            + (["moment_gap", "moment_gap_median"] if opt.RECENT else [])
+            + (["first_grad_gap", "first_grad_gap_median"]
+               if opt.FIRST_GRAD else [])
+            + (["moment_store_gap"] if opt.RECENT else [])
+            + ["change_gap", "change_gap_median", "param_store_gap"])
+
+
+def check_cell(config: dict, traffic: dict, sizes: dict) -> None:
+    """A cell's files are refused as they are loaded where the family or
+    the optimizer has no file, or a limit is set on a number that the
+    cell's optimizer cannot give."""
+    family_of(config["arch"])
+    there = numbers_of(config, traffic)
+    stray = sorted(set(sizes["limits"]) - set(there))
+    if stray:
+        raise ValueError(
+            f"cells/{sizes.get('name', '?')}.json sets a limit on {stray}, "
+            f"which optimizer {config['optimizer']['kind']!r} under "
+            f"assignment {traffic['check']['assignment']!r} cannot give; "
+            f"the numbers there are: {there}")
 
 
 # ----------------------------------------------------------------------
@@ -66,28 +102,32 @@ class Recorder:
     the program's ``TrainStep`` that the job dispatches) while the warm-up
     time steps run: notes each dispatch's time weights, as the program
     masks them by the round's participants, and reduces the optimizer state
-    it returns to per-parameter norms of AMSGrad's first moment and of its
-    running maximum of the second. It changes no argument and no result,
-    and is taken off before the window."""
+    it returns to per-parameter norms of the moments the optimizer has (its
+    file names them and says where they sit). It changes no argument and no
+    result, and is taken off before the window."""
 
-    def __init__(self, exp, arch, round_program):
+    def __init__(self, exp, arch, optimizer, round_program):
         import jax
         import jax.numpy as jnp
         self.exp, self.arch, self.name = exp, arch, round_program
+        self.optimizer = optimizer
         self.time_w: list[np.ndarray] = []
-        self.moments = self.nu_max = self.store_share = None
+        self.moments = self.store_share = None
 
         def norms(tree):
             return jax.tree_util.tree_map(
                 lambda l: jnp.sqrt((l.astype(jnp.float32) ** 2).sum()), tree)
 
-        def reduce(mu, nu_max):
-            f32 = jax.tree_util.tree_leaves(jax.tree_util.tree_map(
-                lambda l: l.astype(jnp.float32), mu))
-            res = sum((reference.bf16_residue(l) ** 2).sum() for l in f32)
-            tot = sum((l * l).sum() for l in f32)
-            return (norms(mu), norms(nu_max),
-                    jnp.sqrt(res / jnp.maximum(tot, 1e-30)))
+        def reduce(moments):
+            share = None
+            if optimizer.RECENT:
+                f32 = jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+                    lambda l: l.astype(jnp.float32),
+                    moments[optimizer.RECENT]))
+                res = sum((reference.bf16_residue(l) ** 2).sum() for l in f32)
+                tot = sum((l * l).sum() for l in f32)
+                share = jnp.sqrt(res / jnp.maximum(tot, 1e-30))
+            return {k: norms(v) for k, v in moments.items()}, share
         self._norms = jax.jit(reduce)
         fn = getattr(type(exp.step), round_program)
         self._signature = inspect.signature(fn)
@@ -100,22 +140,23 @@ class Recorder:
             self.time_w.extend(masked_time_weights(
                 np.asarray(args["time_w"]),
                 args.get("client_mask", args.get("client_masks"))))
-            state = out[1][1][0]
-            self.moments, self.nu_max, self.store_share = self._norms(
-                state.mu, state.nu_max)
+            if self.optimizer.MOMENTS:
+                self.moments, self.store_share = self._norms(
+                    self.optimizer.moments_of(out[1]))
             return out
         return call
 
     def take(self):
         """(time weights of the rounds dispatched since the last take, or
-        one for all the rounds of a fused dispatch; norms of the first
-        moment and of the second's maximum after the last of them; the first
-        moment's store share)."""
+        one for all the rounds of a fused dispatch; per moment of the
+        optimizer its norms after the last of them; the store share of the
+        recent-gradient moment, or None where the optimizer has none)."""
         tw, self.time_w = self.time_w, []
-        flat = [{k: float(v) for k, v in
-                 weights.from_program_tree(self.arch, tree).items()}
-                for tree in (self.moments, self.nu_max)]
-        return tw, flat[0], flat[1], float(self.store_share)
+        flat = {name: {k: float(v) for k, v in
+                       weights.from_program_tree(self.arch, tree).items()}
+                for name, tree in (self.moments or {}).items()}
+        share = self.store_share
+        return tw, flat, None if share is None else float(share)
 
     def remove(self):
         delattr(self.exp.step, self.name)
@@ -225,6 +266,7 @@ def run(*, manifest, cell, config, traffic, sizes, seed, seconds, trace,
     from feddrift_tpu.simulation.runner import Experiment
 
     arch = config["arch"]
+    optimizer = optimizer_of(config)
     chips = int(cell["chips"])
     clients = int(sizes["clients_per_chip"]) * chips
     cfg = experiment_config(config, traffic, sizes, seed, clients)
@@ -242,14 +284,14 @@ def run(*, manifest, cell, config, traffic, sizes, seed, seconds, trace,
     warm = int(traffic["warmup_time_steps"])
     if follow > warm:
         raise ValueError("the comparison follows warm-up time steps only")
-    rec = Recorder(exp, arch, traffic["round_program"])
+    rec = Recorder(exp, arch, optimizer, traffic["round_program"])
     seen = []
     for t in range(warm):
         exp.run_iteration(t)
         if t < follow:
-            tw, moments, nu_max, store_share = rec.take()
+            tw, moments, store_share = rec.take()
             seen.append({
-                "t": t, "time_w": tw, "moments": moments, "nu_max": nu_max,
+                "t": t, "time_w": tw, "moments": moments,
                 "moment_store_share": store_share,
                 "c_pad": exp.C_pad,
                 "assign": np.asarray(exp.algo.weights[t]).copy(),
@@ -327,6 +369,12 @@ def run(*, manifest, cell, config, traffic, sizes, seed, seconds, trace,
     x_host, y_host = exp.ds.x[:, : follow + 1], exp.ds.y[:, : follow + 1]
     hyper = dict(config["optimizer"], lr=cfg.lr, wd=cfg.wd)
     job = {"seed": cfg.seed, "batch": cfg.batch_size, "local_steps": cfg.epochs}
+    if steps:
+        longest = max(steps, key=lambda s: s["wall_s"])
+        log(f"longest time step of the window: t={longest['t']}, "
+            f"{longest['wall_s']:.3f} s (median "
+            f"{float(np.median([s['wall_s'] for s in steps])):.3f} s), "
+            f"segments " + json.dumps(longest["segments"]))
     _free(exp)
     del exp, rec
     gc.collect()
@@ -437,7 +485,7 @@ def check(arch, hyper, init, x, y, job, traffic, seen, *, lower=False,
                               batch=job["batch"],
                               local_steps=job["local_steps"], lower=lower,
                               fault=fault)
-    rule = assignment_rule(traffic)
+    rule, opt = assignment_rule(traffic), ref.optimizer
     C = x.shape[0]
     numbers = {"train_loss_gap": 0.0, "test_loss_gap": 0.0, rule.NUMBER: 0.0}
     used = set()
@@ -458,20 +506,20 @@ def check(arch, hyper, init, x, y, job, traffic, seen, *, lower=False,
                                    ("test_loss_gap", te, s["test_loss"])):
             numbers[name] = max(numbers[name], abs(theirs - mine) / abs(mine))
         if t == seen[0]["t"]:
-            numbers["moment_gap"], at = reference.worst_norm_gap(
-                s["moments"], ref.moment_norms())
-            numbers["moment_gap_median"] = reference.median_norm_gap(
-                s["moments"], ref.moment_norms())
-            log(f"check detail: moment_gap worst at {at}")
-            mine = ref.moment_norms("nu_max")
-            numbers["first_grad_gap"], at = reference.worst_norm_gap(
-                s["nu_max"], mine)
-            numbers["first_grad_gap_median"] = reference.median_norm_gap(
-                s["nu_max"], mine)
-            log(f"check detail: first_grad_gap worst at {at}")
-            mine = ref.moment_store_share()
-            numbers["moment_store_gap"] = \
-                abs(s["moment_store_share"] - mine) / max(mine, 1e-30)
+            for number, which in (("moment_gap", opt.RECENT),
+                                  ("first_grad_gap", opt.FIRST_GRAD)):
+                if which is None:
+                    continue
+                mine = ref.moment_norms(which)
+                numbers[number], at = reference.worst_norm_gap(
+                    s["moments"][which], mine)
+                numbers[f"{number}_median"] = reference.median_norm_gap(
+                    s["moments"][which], mine)
+                log(f"check detail: {number} worst at {at}")
+            if opt.RECENT:
+                mine = ref.moment_store_share(opt.RECENT)
+                numbers["moment_store_gap"] = \
+                    abs(s["moment_store_share"] - mine) / max(mine, 1e-30)
     flat = reference.flat_gradient_leaves(ref.first_grad_norms)
     if flat:
         log(f"check detail: left out of the change (gradient nought to "
@@ -491,7 +539,7 @@ def check(arch, hyper, init, x, y, job, traffic, seen, *, lower=False,
     numbers["param_store_gap"] = abs(theirs - mine) / max(mine, 1e-30)
     log(f"check detail: share of the norm stored below bfloat16's last bit: "
         f"parameters {theirs:.3e} (reference {mine:.3e})")
-    return numbers
+    return {k: numbers[k] for k in _numbers(opt, rule)}
 
 
 def _store_share(arrays) -> float:
@@ -543,11 +591,13 @@ def reference_as_program(arch, hyper, init, x, y, job, traffic, *,
         hist[t, assign, np.arange(C)] = 1.0
         tr, te = ref.losses(t, assign, assign)
         nought = {k: 0.0 for k in init[0]}
+        recent = ref.optimizer.RECENT
         seen.append({
             "t": t, "time_w": tws if rule.EVERY_ROUND else tws[:1],
-            "moments": ref.moment_norms() or nought,
-            "nu_max": ref.moment_norms("nu_max") or nought,
-            "moment_store_share": ref.moment_store_share(),
+            "moments": {which: ref.moment_norms(which) or nought
+                        for which in ref.optimizer.MOMENTS},
+            "moment_store_share":
+                ref.moment_store_share(recent) if recent else None,
             "c_pad": C, "assign": hist[t].copy(), "train_idx": assign,
             "test_idx": assign, "train_loss": tr, "test_loss": te,
             "params": {k: np.stack([np.asarray(p[k], np.float32)

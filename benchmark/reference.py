@@ -7,11 +7,14 @@ trajectory at a time. It imports nothing of the program and takes nothing
 the program made: weights come from ``benchmark/weights.py``, data and the
 round's keys from the seed. What it shares with the program is the
 definition of the job: how a round's key becomes a batch (``batch_indices``),
-AMSGrad with decayed weights, the sample-weighted mean over a model's
-clients.
+the local loop, the sample-weighted mean over a model's clients.
 
-A parameter set is a flat dict ``name -> array``; ``param_spec`` lists the
-names in forward order.
+What is the model's comes from the family's file, ``families/<family>.py``
+(parameters, forward pass, loss and hits), found by ``arch["family"]``; the
+client optimizer's plain update comes from ``optimizers/<kind>.py``, found
+by the ``kind`` of the configuration's ``optimizer`` group. A parameter set
+is a flat dict ``name -> array``; ``param_spec`` lists the names in forward
+order.
 """
 
 from __future__ import annotations
@@ -23,90 +26,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
+from benchmark import family_of, named
 
 
 # ----------------------------------------------------------------------
-# the model
-def _blocks(arch: dict):
-    """(name, cin, cout, stride, has_projection) per basic block."""
-    cin = arch["stem_filters"]
-    for s, stage in enumerate(arch["stages"]):
-        cout = stage["filters"]
-        for b in range(stage["blocks"]):
-            stride = stage["stride"] if b == 0 else 1
-            yield f"s{s}b{b}", cin, cout, stride, (stride != 1 or cin != cout)
-            cin = cout
-
-
+# the model: its family's file
 def param_spec(arch: dict) -> list[tuple[str, tuple[int, ...], str]]:
-    """(name, shape, role) of every parameter; role is conv | dense | scale
-    | bias."""
-    if arch["family"] != "resnet_basic":
-        raise KeyError(f"no reference for family {arch['family']!r}")
-    spec = []
-
-    def norm(prefix, c):
-        spec.append((f"{prefix}/scale", (c,), "scale"))
-        spec.append((f"{prefix}/bias", (c,), "bias"))
-
-    c0 = arch["stem_filters"]
-    spec.append(("stem/conv", (3, 3, arch["input"][2], c0), "conv"))
-    norm("stem/norm", c0)
-    cout = c0
-    for name, cin, cout, _stride, proj in _blocks(arch):
-        spec.append((f"{name}/conv1", (3, 3, cin, cout), "conv"))
-        norm(f"{name}/norm1", cout)
-        spec.append((f"{name}/conv2", (3, 3, cout, cout), "conv"))
-        norm(f"{name}/norm2", cout)
-        if proj:
-            spec.append((f"{name}/proj", (1, 1, cin, cout), "conv"))
-            norm(f"{name}/projnorm", cout)
-    spec.append(("head/kernel", (cout, arch["num_classes"]), "dense"))
-    spec.append(("head/bias", (arch["num_classes"],), "bias"))
-    return spec
-
-
-def _conv(x, k, stride, dtype=None):
-    if dtype is not None:
-        x, k = x.astype(dtype), k.astype(dtype)
-    return jax.lax.conv_general_dilated(
-        x, k, (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST)
-
-
-def _norm(x, scale, bias):
-    """Per-batch normalisation over (N, H, W), no running statistics."""
-    x = x.astype(jnp.float32)
-    mean = x.mean(axis=(0, 1, 2), keepdims=True)
-    var = ((x - mean) ** 2).mean(axis=(0, 1, 2), keepdims=True)
-    return (x - mean) / jnp.sqrt(var + 1e-5) * scale + bias
+    """(name, shape, role) of every parameter, in forward order."""
+    return family_of(arch).param_spec(arch)
 
 
 def forward(arch: dict, p: dict, x, dtype=None):
-    """Logits [N, classes] of images [N, H, W, 3]. ``dtype`` lowers the
-    convolutions' operands (the control), nothing else."""
-    x = x.reshape((x.shape[0], *arch["input"]))
-    h = jax.nn.relu(_norm(_conv(x, p["stem/conv"], 1, dtype),
-                          p["stem/norm/scale"], p["stem/norm/bias"]))
-    for name, _cin, _cout, stride, proj in _blocks(arch):
-        y = _conv(h, p[f"{name}/conv1"], stride, dtype)
-        y = jax.nn.relu(_norm(y, p[f"{name}/norm1/scale"],
-                              p[f"{name}/norm1/bias"]))
-        y = _conv(y, p[f"{name}/conv2"], 1, dtype)
-        y = _norm(y, p[f"{name}/norm2/scale"], p[f"{name}/norm2/bias"])
-        if proj:
-            h = _norm(_conv(h, p[f"{name}/proj"], stride, dtype),
-                      p[f"{name}/projnorm/scale"], p[f"{name}/projnorm/bias"])
-        h = jax.nn.relu(y + h)
-    feats = h.mean(axis=(1, 2))
-    return jnp.matmul(feats, p["head/kernel"], precision=HIGHEST) \
-        + p["head/bias"]
-
-
-def _nll(logits, y):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
+    """Logits of a batch. ``dtype`` lowers the operands of the family's
+    matrix products (the control), nothing else."""
+    return family_of(arch).forward(arch, p, x, dtype)
 
 
 # ----------------------------------------------------------------------
@@ -137,46 +70,26 @@ def batch_indices(key, time_w, n_per_step: int, batch: int, num_steps: int):
     return jnp.stack(out)
 
 
-def new_opt_state(p: dict) -> dict:
-    z = {k: jnp.zeros_like(v) for k, v in p.items()}
-    return {"count": jnp.zeros((), jnp.int32), "mu": z, "nu": dict(z),
-            "nu_max": dict(z)}
-
-
-@partial(jax.jit, static_argnames=("arch_key", "fault", "dtype"))
-def _local_sgd(p, opt, xb, yb, hyper, *, arch_key, fault=None, dtype=None):
-    """``xb`` [steps, B, ...]: AMSGrad with decayed weights over the steps.
+@partial(jax.jit, static_argnames=("arch_key", "optimizer", "fault", "dtype"))
+def _local_sgd(p, opt, xb, yb, hyper, *, arch_key, optimizer, fault=None,
+               dtype=None):
+    """``xb`` [steps, B, ...]: the optimizer's update over the steps.
     Returns params, optimizer state, the steps' mean loss and the norms of
     the first step's gradient per parameter."""
     arch = _ARCHS[arch_key]
-    lr, wd, b1, b2, eps = (hyper[k] for k in ("lr", "wd", "b1", "b2", "eps"))
+    family, update = family_of(arch), named("optimizers", optimizer).update
 
     def loss_fn(p, x, y):
         if fault == "half_batch":
             x, y = x[: x.shape[0] // 2], y[: y.shape[0] // 2]
-        return _nll(forward(arch, p, x, dtype), y).mean()
+        return family.nll(family.forward(arch, p, x, dtype), y).mean()
 
     def step(carry, xy):
         p, o = carry
         loss, g = jax.value_and_grad(loss_fn)(p, *xy)
         gnorm = {k: jnp.sqrt((v.astype(jnp.float32) ** 2).sum())
                  for k, v in g.items()}
-        count = o["count"] + 1
-        new_p, mu, nu, nu_max = {}, {}, {}, {}
-        for k in p:
-            gk = (g[k] + wd * p[k]).astype(p[k].dtype)
-            mu[k] = b1 * o["mu"][k] + (1 - b1) * gk
-            nu[k] = b2 * o["nu"][k] + (1 - b2) * gk * gk
-            mu_hat = mu[k] / (1 - b1 ** count)
-            nu_hat = nu[k] / (1 - b2 ** count)
-            nu_max[k] = jnp.maximum(o["nu_max"][k], nu_hat)
-            upd = mu_hat / (jnp.sqrt(nu_max[k]) + eps)
-            new_p[k] = (p[k] - lr * upd).astype(p[k].dtype)
-            mu[k] = mu[k].astype(p[k].dtype)
-            nu[k] = nu[k].astype(p[k].dtype)
-            nu_max[k] = nu_max[k].astype(p[k].dtype)
-        return (new_p, {"count": count, "mu": mu, "nu": nu,
-                        "nu_max": nu_max}), (loss, gnorm)
+        return update(p, g, o, hyper), (loss, gnorm)
 
     (p, opt), (losses, gnorms) = jax.lax.scan(step, (p, opt), (xb, yb))
     return p, opt, losses.mean(), {k: v[0] for k, v in gnorms.items()}
@@ -184,10 +97,12 @@ def _local_sgd(p, opt, xb, yb, hyper, *, arch_key, fault=None, dtype=None):
 
 @partial(jax.jit, static_argnames=("arch_key", "dtype"))
 def _eval(p, x, y, *, arch_key, dtype=None):
-    """(correct count, summed loss) of one client's whole time step as one
-    batch, as the program evaluates it."""
-    logits = forward(_ARCHS[arch_key], p, x, dtype)
-    return (logits.argmax(-1) == y).sum(), _nll(logits, y).sum()
+    """(correct count, summed loss) over the labels of one client's whole
+    time step as one batch, as the program evaluates it."""
+    arch = _ARCHS[arch_key]
+    family = family_of(arch)
+    logits = family.forward(arch, p, x, dtype)
+    return family.hits(logits, y).sum(), family.nll(logits, y).sum()
 
 
 def bf16_residue(a):
@@ -227,22 +142,26 @@ def _arch_key(arch: dict) -> str:
 class Reference:
     """Follows a federated job from benchmark-made weights.
 
-    ``x`` [C, T1, N, ...] and ``y`` [C, T1, N] are host arrays of the time
-    steps it will need; ``init`` is a list of M flat parameter dicts.
-    ``lower`` runs the whole of it one precision step down (bfloat16
-    parameters, moments, aggregation and convolution operands): the control
-    where the program has no such path of its own. ``fault`` plants
-    ``half_batch`` (half of each batch left out, the mean over the rest).
-    ``compute_dtype`` lowers the convolutions' operands alone, as the
-    configurations state their compute: the look at what bfloat16 compute
-    by itself does to the numbers compared (PERF.md section 2).
+    ``x`` [C, T1, N, ...] and ``y`` [C, T1, N, ...] (a label per sample, or
+    whatever trailing axes the family's labels have) are host arrays of the
+    time steps it will need; ``init`` is a list of M flat parameter dicts;
+    ``hyper`` is the configuration's ``optimizer`` group with the program's
+    ``lr`` and ``wd``. ``lower`` runs the whole of it one precision step
+    down (bfloat16 parameters, moments, aggregation and convolution
+    operands): the control where the program has no such path of its own.
+    ``fault`` plants ``half_batch`` (half of each batch left out, the mean
+    over the rest). ``compute_dtype`` lowers the convolutions' operands
+    alone, as the configurations state their compute: the look at what
+    bfloat16 compute by itself does to the numbers compared (PERF.md
+    section 2).
     """
 
     def __init__(self, arch, hyper, init, x, y, seed, *, batch, local_steps,
                  lower=False, fault=None, compute_dtype=None):
         self.arch, self.akey = arch, _arch_key(arch)
-        self.hyper = {k: float(hyper[k]) for k in ("lr", "wd", "b1", "b2",
-                                                   "eps")}
+        self.kind = hyper["kind"]
+        self.optimizer = named("optimizers", self.kind)
+        self.hyper = {k: float(hyper[k]) for k in self.optimizer.HYPER}
         self.dtype = "bfloat16" if lower else compute_dtype
         pd = jnp.bfloat16 if lower else jnp.float32
         self.params = [{k: jnp.asarray(v, pd) for k, v in p.items()}
@@ -254,9 +173,9 @@ class Reference:
         self.fault = fault
         self.M, self.C = len(init), x.shape[0]
         self.N = x.shape[2]
+        self.labels = int(np.prod(y.shape[2:]))     # of a client's time step
         self.opt: dict[tuple[int, int], dict] = {}
         self.first_grad_norms: dict[str, float] | None = None
-        self.mu_sq: dict[str, float] = {}
 
     def begin_time_step(self) -> None:
         """Optimizer states are fresh at every time-step boundary."""
@@ -277,12 +196,14 @@ class Reference:
                     pair_key(rkey, m, c, self.M, c_pad), w, self.N, B,
                     self.local_steps))
                 xf = self.x[c].reshape((-1,) + self.x.shape[3:])
-                yf = self.y[c].reshape(-1)
-                opt = self.opt.get((m, c)) or new_opt_state(self.params[m])
+                yf = self.y[c].reshape((-1,) + self.y.shape[3:])
+                opt = self.opt.get((m, c))
+                if opt is None:
+                    opt = self.optimizer.new_state(self.params[m])
                 p, opt, _loss, gn = _local_sgd(
                     self.params[m], opt, jnp.asarray(xf[idx]),
                     jnp.asarray(yf[idx]), self.hyper, arch_key=self.akey,
-                    fault=self.fault,
+                    optimizer=self.kind, fault=self.fault,
                     dtype=self.dtype)
                 self.opt[(m, c)] = opt
                 if self.first_grad_norms is None:
@@ -296,16 +217,13 @@ class Reference:
             if acc is None:
                 new.append(self.params[m])
             else:
-                dt = self.params[m]["head/bias"].dtype
+                dt = next(iter(self.params[m].values())).dtype
                 new.append({k: (v / wsum).astype(dt) for k, v in acc.items()})
         self.params = new
 
-    def moment_norms(self, which: str = "mu") -> dict[str, float]:
-        """Per parameter, the norm of an AMSGrad moment over every pair that
-        trained in this time step. ``mu`` is the recent gradients as the
-        optimizer got them; ``nu_max``, the running maximum of the
-        bias-corrected second moment, keeps the largest squared gradients of
-        the time step, which are its first."""
+    def moment_norms(self, which: str) -> dict[str, float]:
+        """Per parameter, the norm of the optimizer's moment ``which`` over
+        every pair that trained in this time step."""
         out: dict[str, float] = {}
         for o in self.opt.values():
             for k, v in o[which].items():
@@ -313,11 +231,12 @@ class Reference:
                     (v.astype(jnp.float32) ** 2).sum())
         return {k: v ** 0.5 for k, v in out.items()}
 
-    def moment_store_share(self) -> float:
-        """``store_share`` of the first moments of the pairs that trained."""
+    def moment_store_share(self, which: str) -> float:
+        """The share of the norm stored below bfloat16's last bit, of the
+        moment ``which`` of the pairs that trained."""
         res = tot = 0.0
         for o in self.opt.values():
-            for v in o["mu"].values():
+            for v in o[which].values():
                 r, n = _residue_sq(v)
                 res, tot = res + float(r), tot + float(n)
         return (res / max(tot, 1e-300)) ** 0.5
@@ -354,7 +273,7 @@ class Reference:
                              jnp.asarray(self.y[c, step]),
                              arch_key=self.akey, dtype=self.dtype)
                 tot += float(l)
-            out.append(tot / (self.C * self.N))
+            out.append(tot / (self.C * self.labels))
         return out[0], out[1]
 
     def change(self, models) -> dict[str, float]:

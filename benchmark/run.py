@@ -50,18 +50,27 @@ def find_cell(manifest: dict, name: str) -> dict:
                      f"{[c['name'] for c in manifest['workloads']]}")
 
 
+def driver_of(traffic: dict):
+    """The driver ``drivers/<kind>.py`` that the traffic file's ``kind``
+    names."""
+    return importlib.import_module(f"benchmark.drivers.{traffic['kind']}")
+
+
 def load_cell(manifest: dict, name: str, rehearse: bool = False):
     """(cell, config, traffic, sizes): the manifest's entry and the three
     files it names. ``sizes`` is ``cells/<cell>.json``: what belongs to the
     pair of configuration and traffic (clients per chip, time steps of data,
     the limits of ``correct``). A rehearsal lays each file's ``rehearse``
-    group over it."""
+    group over it. The driver refuses files it could not run to an end
+    (``check_cell``): a family or an optimizer with no file, a limit on a
+    number that the cell cannot give."""
     cell = find_cell(manifest, name)
     files = [load_json("configs", f"{cell['config']}.json"),
              load_json("traffic", f"{cell['traffic']}.json"),
              load_json("cells", f"{cell['name']}.json")]
     if rehearse:
         files = [overlay(f, f.get("rehearse", {})) for f in files]
+    driver_of(files[1]).check_cell(*files)
     return (cell, *files)
 
 
@@ -146,7 +155,7 @@ def main(argv=None) -> int:
         device = {"platform": "cpu", "kind": "cpu", "count": 1}
     else:
         device = require_chips(cell["chips"])
-    driver = importlib.import_module(f"benchmark.drivers.{traffic['kind']}")
+    driver = driver_of(traffic)
     # the program may print; the result line alone goes to standard output
     stdout, sys.stdout = sys.stdout, sys.stderr
     result = driver.run(manifest=manifest, cell=cell, config=config,

@@ -35,10 +35,15 @@ def main() -> int:
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    import numpy as np
+
+    from benchmark import family_of
+    from benchmark.drivers.train import experiment_config
     from benchmark.run import load_cell, load_manifest
     from feddrift_tpu.core.precision import PrecisionPolicy
     from feddrift_tpu.core.step import TrainStep, make_optimizer
-    from feddrift_tpu.models.resnet import ResNet18, ResNetCifar
+    from feddrift_tpu.data.drift_dataset import DriftDataset
+    from feddrift_tpu.models import create_model
 
     jax.config.update("jax_enable_compilation_cache", False)
     cell, config, traffic, sizes = load_cell(load_manifest(), args.workload)
@@ -50,8 +55,19 @@ def main() -> int:
     T1, N = prog["train_iterations"] + 1, prog["sample_num"]
     fused = traffic["round_program"] == "train_iteration_eval"
 
-    module = {"resnet18": ResNet18(num_classes=10),
-              "resnet20": ResNetCifar(num_classes=10, depth=20)}[prog["model"]]
+    # one sample's shapes from the family's file; the module through the
+    # program's own factory, over a data set of one sample of those shapes
+    shapes = family_of(config["arch"]).sample_shapes(config["arch"])
+    (x_shape, x_dtype), (y_shape, y_dtype) = shapes["x"], shapes["y"]
+    classes = shapes["num_classes"]
+    # (the factory reads the class count and x's shape; the program's data
+    # set holds one label per sample, so y stands in with no trailing axes)
+    module = create_model(prog["model"], DriftDataset(
+        x=np.zeros((1, 2, 1, *x_shape), x_dtype),
+        y=np.zeros((1, 2, 1), np.int32), num_classes=classes,
+        concepts=np.zeros((2, 1), np.int32),
+        is_sequence=np.issubdtype(np.dtype(x_dtype), np.integer)),
+        experiment_config(config, traffic, sizes, 0, C))
     # the apply boundary of runner._make_apply under "auto" on a TPU
     cdt = jnp.dtype(prog["compute_dtype"])
 
@@ -64,7 +80,7 @@ def main() -> int:
         optimizer=make_optimizer(prog["client_optimizer"], prog["lr"],
                                  prog["wd"]),
         batch_size=prog["batch_size"], num_steps=prog["epochs"],
-        num_classes=10, cost_capture="off",
+        num_classes=classes, cost_capture="off",
         precision=PrecisionPolicy(name="auto", param_dtype=prog["dtype"],
                                   compute_dtype=prog["compute_dtype"]))
 
@@ -78,18 +94,18 @@ def main() -> int:
             else NamedSharding(mesh, spec))
 
     one = jax.eval_shape(lambda: module.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))["params"])
+        jax.random.PRNGKey(0), jnp.zeros((1, *x_shape), x_dtype))["params"])
     params = jax.tree_util.tree_map(
         lambda l: sds((M, *l.shape), jnp.float32), one)
     opt = jax.eval_shape(lambda p: step.init_opt_states(p, M, C), params)
     opt = jax.tree_util.tree_map(
         lambda l: sds(l.shape, l.dtype,
                       P(None, "clients") if l.ndim >= 2 else None), opt)
-    x = sds((C, T1, N, 32, 32, 3), jnp.float32, P("clients"))
-    y = sds((C, T1, N), jnp.int32, P("clients"))
+    x = sds((C, T1, N, *x_shape), jnp.dtype(x_dtype), P("clients"))
+    y = sds((C, T1, N, *y_shape), jnp.dtype(y_dtype), P("clients"))
     tw = sds((M, C, T1), jnp.float32)
     sw = sds((M, C, N), jnp.float32)
-    fm = sds((M, 32, 32, 3), jnp.float32)
+    fm = sds((M, *x_shape), jnp.float32)
     key = sds((2,), jnp.uint32)
     lr = sds((), jnp.float32)
     if fused:

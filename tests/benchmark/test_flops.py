@@ -52,6 +52,9 @@ def test_parameter_counts_are_the_published_ones(name, params):
     assert flops.parameter_count(arch) == params
     import math
     assert sum(math.prod(s) for _, s, _ in reference.param_spec(arch)) == params
+    # the family's closed form, counted another way than its param_spec
+    from benchmark.families import resnet_basic
+    assert resnet_basic.parameter_count(arch) == params
 
 
 def test_training_counts_three_forwards():

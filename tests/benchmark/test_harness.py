@@ -105,3 +105,5 @@ def test_rehearsal_drives_a_whole_run_and_prints_no_device_number():
     assert p.stdout.strip() == ""
     assert "rehearsal done: correct=True" in p.stderr
     assert "examples/s" not in p.stderr and "mfu" not in p.stderr
+    # traced or not, a run names the segments of its longest time step
+    assert "longest time step of the window: t=" in p.stderr

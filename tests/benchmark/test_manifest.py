@@ -56,6 +56,11 @@ def test_every_cell_names_its_files_and_a_reason():
                           ("assignment", traffic["check"]["assignment"])):
             assert os.path.isfile(os.path.join(ROOT, "benchmark", sub,
                                                f"{stem}.py"))
+        config = bench.load_json("configs", f"{c['config']}.json")
+        for sub, stem in (("families", config["arch"]["family"]),
+                          ("optimizers", config["optimizer"]["kind"])):
+            assert os.path.isfile(os.path.join(ROOT, "benchmark", sub,
+                                               f"{stem}.py"))
         sizes = bench.load_json("cells", f"{c['name']}.json")
         # a rehearsal is held to the cell's own limits
         assert sizes["limits"] and "limits" not in sizes.get("rehearse", {})

@@ -106,8 +106,10 @@ def test_only_the_rounds_own_spans_are_counted(ring):
 def test_a_tiny_job_on_the_cells_traffic_leaves_spans_to_read():
     """IFCA ``hard-r`` on the per-round path at the rehearsal's size: per
     round a train_round and an acc_matrix dispatch and two fetches, per
-    evaluation two dispatches and a fetch, one of each at the time-step
-    boundary."""
+    evaluation two dispatches and a fetch, and at the time-step boundary
+    two dispatches (``acc_matrix`` and, since PR 26, ``fresh_opt_states``:
+    the time step's optimizer states as one tracked program) and one fetch:
+    ten tracked dispatches and seven fetches in a time step of 2 rounds."""
     from benchmark.drivers import train
     from feddrift_tpu.parallel.mesh import make_mesh
     from feddrift_tpu.simulation.runner import Experiment
@@ -123,7 +125,7 @@ def test_a_tiny_job_on_the_cells_traffic_leaves_spans_to_read():
                 exp, t, exp.last_round_breakdown["wall_s"], clients, cfg))
     rec = {"time_steps": steps}
     assert all(s["rounds"] == 2 for s in steps)
-    assert reader("dispatches_per_round").read(rec, None, cell) == 4.5
+    assert reader("dispatches_per_round").read(rec, None, cell) == 5.0
     assert reader("host_syncs_per_round").read(rec, None, cell) == 3.5
     assert 0.0 < reader("runner_host_share").read(rec, None, cell) < 100.0
     for s in steps:
