@@ -477,7 +477,7 @@ class SoftCluster(DriftAlgorithm):
         k = len(in_use)
         cluster_acc = np.zeros((k, k))
         for j_pos, j in enumerate(in_use):
-            vol = assigned[j].sum() * self.N
+            vol = assigned[j].sum() * self.N * self.ds.labels_per_sample
             if vol == 0:
                 continue
             for i_pos, i in enumerate(in_use):

@@ -94,6 +94,8 @@ class ExperimentConfig:
                                        # data/text.py SEQ_LEN; shrink for CPU
                                        # smokes — the drift semantics are
                                        # length-independent)
+    token_vocab: int = 16032           # ids of dataset "token_drift": the rows
+                                       # of its vocabulary that the model holds
 
     # --- reproducibility & numerics -------------------------------------
     seed: int = 0                      # reference --dummy_arg (main_fedavg.py:292-298)
@@ -106,6 +108,13 @@ class ExperimentConfig:
     # resident HBM, streamed bytes and wire frames (CPU runs it emulated).
     precision: str = "auto"
     remat: bool = False                # jax.checkpoint the forward (HBM <-> FLOPs)
+    # How the round programs run the (model, client) pairs (core/step.py):
+    # "vmap" trains all M x C pairs at once and keeps [M, C, ...] stacks of
+    # parameters, gradients and optimizer state; "scan" takes one pair at a
+    # time, skips pairs of zero weight and adds each result into one running
+    # weighted sum: for a model whose M x C copies do not fit. What reads
+    # an [M, C, ...] stack refuses "scan" (below).
+    client_axis: str = "vmap"          # vmap | scan
 
     # --- TPU execution ---------------------------------------------------
     mesh_shape: dict[str, int] = field(default_factory=dict)  # e.g. {"clients": 8}
@@ -506,6 +515,33 @@ class ExperimentConfig:
                 raise ValueError("secure_agg requires megastep_k == 1")
             if self.stream_data:
                 raise ValueError("secure_agg requires stream_data off")
+        if self.client_axis not in ("vmap", "scan"):
+            raise ValueError(f"unknown client_axis {self.client_axis!r}")
+        if self.client_axis == "scan":
+            # the scanned body holds no [M, C, ...] stack of any kind
+            needs_stack = [
+                name for name, on in (
+                    (f"client_optimizer={self.client_optimizer!r} (its state "
+                     "is kept per pair; only 'sgd' has none)",
+                     self.client_optimizer != "sgd"),
+                    (f"robust_agg={self.robust_agg!r}",
+                     self.robust_agg != "mean"),
+                    ("byzantine_clients", bool(self.byzantine_clients.strip())),
+                    (f"compress_codec={self.compress_codec!r}",
+                     self.compress_codec != "none"),
+                    ("hierarchy_edges", self.hierarchy_edges > 0),
+                    (f"secure_agg={self.secure_agg!r}",
+                     self.secure_agg != "off"),
+                    ("CFL (concept_drift_algo_arg 'cfl_...')",
+                     "cfl" in self.concept_drift_algo_arg),
+                    ("megastep_k > 1", self.megastep_k > 1)) if on]
+            if needs_stack:
+                raise ValueError(
+                    "client_axis='scan' keeps no [M, C, ...] parameter, "
+                    f"gradient or optimizer stack, which "
+                    f"{', '.join(needs_stack)} "
+                    f"need{'s' if len(needs_stack) == 1 else ''}; use "
+                    "client_axis='vmap'")
         if self.precision not in ("auto", "f32", "bf16_mixed", "bf16_pure"):
             raise ValueError(f"unknown precision {self.precision!r}")
         for name in ("dtype", "compute_dtype"):

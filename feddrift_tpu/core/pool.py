@@ -54,7 +54,12 @@ class ModelPool:
         from its very first leaf (None = keep the module's init dtype).
         """
         base_key = jax.random.PRNGKey(seed)
-        init_params = module.init(base_key, sample_input)["params"]
+        # a module may ask for a jitted init (``jit_init``): the compiler
+        # then drops the forward pass, which eagerly would run op by op at
+        # the sample's full size (minutes for a large sequence model)
+        init = jax.jit(module.init) if getattr(module, "jit_init", False) \
+            else module.init
+        init_params = init(base_key, sample_input)["params"]
         if param_dtype is not None:
             init_params = cast_floating(init_params, param_dtype)
         if identical:
@@ -87,7 +92,9 @@ class ModelPool:
 
     def distinct_reinit_slot(self, m: int, seed: int) -> None:
         """Fresh random params (IFCA symmetry breaking, AggregatorSoftCluster.py:66-69)."""
-        new = self.module.init(jax.random.PRNGKey(seed), self.example_input)["params"]
+        init = jax.jit(self.module.init) \
+            if getattr(self.module, "jit_init", False) else self.module.init
+        new = init(jax.random.PRNGKey(seed), self.example_input)["params"]
         # flax inits at f32; match the pool's stored dtype leaf-by-leaf so
         # a policy-typed pool never mixes dtypes across slots
         new = jax.tree_util.tree_map(

@@ -49,6 +49,7 @@ the POPSCALE regress axis gate.
 from __future__ import annotations
 
 import contextlib
+import math
 import time
 from dataclasses import dataclass, field
 from functools import partial
@@ -66,7 +67,8 @@ from feddrift_tpu.core.precision import (PrecisionPolicy, cast_floating,
 from feddrift_tpu.parallel.mesh import constrain_pool
 from feddrift_tpu.platform.faults import BYZ_MODES, apply_byzantine_updates
 from feddrift_tpu.platform.hierarchical import two_tier_aggregate
-from feddrift_tpu.resilience.robust_agg import RobustAggConfig, aggregate
+from feddrift_tpu.resilience.robust_agg import (RobustAggConfig, _stats,
+                                                 aggregate)
 from feddrift_tpu.utils.prng import iteration_key
 
 
@@ -157,6 +159,20 @@ class TrainStep:
     # memory_analysis() (exact static HBM) — one extra XLA compile per
     # program, which bench.py opts into.
     cost_capture: str = "lowered"
+    # Static: how the round programs run the (model, client) pairs
+    # (cfg.client_axis). "vmap" is the body the docstring above describes.
+    # "scan" (`_round_body_scan`) takes the models and, within a model, its
+    # clients one at a time, skips a pair whose time weights sum to 0 and
+    # adds each trained pair's parameters at once into one running weighted
+    # sum: no [M, C, ...] parameter, gradient or optimizer stack exists at
+    # any point, so a model whose M x C copies do not fit can train.
+    # `acc_matrix` then evaluates one forward at a time the same way.
+    client_axis: str = "vmap"
+    # Optional (params, x) -> (logits, stats): the forward that also returns
+    # the model's own counts of the call (models/mla_moe.py: the tokens and
+    # the assignments its held experts got). Read by the scanned body alone,
+    # which returns their sum over the round's training steps.
+    stats_fn: Callable | None = None
     # Optional device mesh (parallel/mesh.py). When it names a "models"
     # and/or "clients" axis, the megastep program annotates its carry
     # params / opt states / time-weight slices with with_sharding_constraint
@@ -277,10 +293,14 @@ class TrainStep:
 
     # ------------------------------------------------------------------
     def _local_sgd(self, params, opt_state, key, x_ct, y_ct, w_t, s_n,
-                   fmask, lr_scale):
+                   fmask, lr_scale, with_stats: bool = False):
         """Train ONE (model, client) pair for num_steps batches.
 
-        x_ct: [T1, N, ...]; w_t: [T1]; s_n: [N]; fmask: [F...]-broadcastable.
+        x_ct: [T1, N, ...]; y_ct: [T1, N, *label_shape] (a label per sample,
+        or per token of a sequence); w_t: [T1]; s_n: [N]; fmask:
+        [F...]-broadcastable. ``with_stats`` (the scanned body, for a model
+        with a ``stats_fn``) returns a fifth value: the model's counts,
+        summed over the local steps.
         """
         T1, N = x_ct.shape[0], x_ct.shape[1]
         B = min(self.batch_size, N)
@@ -307,11 +327,21 @@ class TrainStep:
         logits_t = jnp.log(wt_safe + 1e-30)
 
         x_flat = x_ct.reshape((T1 * N,) + x_ct.shape[2:])
-        y_flat = y_ct.reshape((T1 * N,))
+        y_flat = y_ct.reshape((T1 * N,) + y_ct.shape[2:])
+
+        def feature_masked(xb):
+            # token ids take no multiplicative feature mask
+            return xb if jnp.issubdtype(xb.dtype, jnp.integer) else xb * fmask
 
         def loss_fn(p, xb, yb):
-            return cross_entropy(self.apply_fn(p, xb * fmask
-                                               if xb.dtype != jnp.int32 else xb), yb)
+            return cross_entropy(self.apply_fn(p, feature_masked(xb)), yb)
+
+        grad_fn = jax.value_and_grad(loss_fn)
+        if with_stats:
+            def loss_stats(p, xb, yb):
+                logits, stats = self.stats_fn(p, feature_masked(xb))
+                return cross_entropy(logits, yb), stats
+            grad_fn = jax.value_and_grad(loss_stats, has_aux=True)
 
         def step(carry, k):
             p, o = carry
@@ -325,7 +355,8 @@ class TrainStep:
                 slot = jax.random.randint(k2, (), 0, nb)
                 idx = t_idx * N + slot * B + jnp.arange(B)
             xb, yb = x_flat[idx], y_flat[idx]
-            loss, grads = jax.value_and_grad(loss_fn)(p, xb, yb)
+            loss, grads = grad_fn(p, xb, yb)
+            loss, stats = loss if with_stats else (loss, None)
             updates, o = self.optimizer.update(grads, o, p)
             # pin the scan carry's dtypes: the f32 lr_scale operand (and
             # optax bias-correction internals) would promote bf16 updates /
@@ -334,16 +365,20 @@ class TrainStep:
             updates = jax.tree_util.tree_map(
                 lambda u, pp: (u * lr_scale).astype(pp.dtype), updates, p)
             p = optax.apply_updates(p, updates)
-            return (p, o), loss
+            return (p, o), (loss, stats)
 
         keys = jax.random.split(key, self.num_steps)
-        (p_new, o_new), losses = jax.lax.scan(step, (params, opt_state), keys)
+        (p_new, o_new), (losses, stats) = jax.lax.scan(
+            step, (params, opt_state), keys)
 
         p_out = tree_select(active, p_new, params)
         o_out = tree_select(active, o_new, opt_state)
         # Weighted sample count reported to the aggregator
         # (FedAvgEnsTrainerSoftCluster.py:72-74: sum_t w[t] * data volume).
         n = jnp.where(active, total_w * N, 0.0)
+        if with_stats:
+            return p_out, o_out, n, losses.mean(), jax.tree_util.tree_map(
+                lambda a: a.sum(axis=0), stats)
         return p_out, o_out, n, losses.mean()
 
     # ------------------------------------------------------------------
@@ -381,6 +416,10 @@ class TrainStep:
         if client_mask is not None:
             time_w = time_w * client_mask[None, :, None]
         if byz_modes is not None:
+            if y.ndim > 3:
+                raise ValueError(
+                    "byz_modes (label_flip) reads one label a sample; y has "
+                    f"trailing label axes {y.shape[3:]} (a label per token)")
             # label flipping at the data layer: y -> (K-1) - y for the
             # attackers (eval paths read the untouched dataset)
             flip = (byz_modes == BYZ_MODES["label_flip"])
@@ -452,6 +491,137 @@ class TrainStep:
         return (new_params, new_opt, client_params, n, losses, agg_stats,
                 new_codec_prev)
 
+    def _refuse_stack_users(self, opt_states, keep_client_params, *stack_args):
+        """``client_axis="scan"`` keeps no [M, C, ...] stack; whatever needs
+        one is refused by the name of the field that asks for it."""
+        asked = [name for name, on in (
+            ("an optimizer with state (client_optimizer='adam'): only an "
+             "optimizer without state ('sgd') is kept",
+             bool(jax.tree_util.tree_leaves(opt_states))),
+            ("keep_client_params=True", keep_client_params),
+            (f"robust_agg={self.robust_agg!r}", self.robust_agg != "mean"),
+            (f"codec={self.codec!r}", self.codec != "none"),
+            ("hier_edges > 0", self.hier_edges > 0),
+            ("byz_modes / stale_params / edge operands / codec_prev",
+             any(a is not None for a in stack_args)),
+            ("weighted_sampling", self.weighted_sampling)) if on]
+        if asked:
+            raise ValueError(
+                "client_axis='scan' takes one (model, client) pair at a time "
+                "and keeps no [M, C, ...] parameter, gradient or optimizer "
+                f"stack, which {'; '.join(asked)} "
+                f"need{'s' if len(asked) == 1 else ''}: use "
+                "client_axis='vmap'")
+
+    def _round_body_scan(self, params, opt_states, key, x, y, time_w,
+                         sample_w, feat_mask, lr_scale, client_mask=None):
+        """`_round_body` with the pairs taken one at a time
+        (``client_axis="scan"``): the models in turn and, within a model,
+        its clients in turn.
+
+        A pair whose time weights sum to 0 is skipped (``lax.cond``): not
+        trained, n = 0, loss 0. A trained pair runs `_local_sgd` as the vmap
+        body does, on the same key (the round's key split over M * C) and
+        so on the same batches, and its parameters are added at once, times
+        its share of the model's sample count, into the model's running sum
+        (at agg_dtype): the weighted mean of ``robust_agg="mean"``, term by
+        term, summed in client order. When a model's clients are done the
+        sum is written over the model's slot of the pool, which the program
+        owns (`_train_round_scan_jit` donates it): one pool, one model's
+        sum, one pair's parameters and gradient are all that is held. A
+        model no client trained keeps its parameters, as `aggregate` leaves
+        it, and so does a model one of whose trained pairs' losses is not
+        finite: the pool the caller handed in no longer exists, so the
+        NaN/Inf half of the runner's divergence guard is kept here.
+        ``client_params`` is None and the optimizer state, which has no
+        leaf (`_refuse_stack_users`), is returned as it came.
+
+        Returns the seven outputs of `_round_body` (``client_params`` and
+        the codec carry None) and an eighth, the round's counts:
+        ``pairs_trained`` (what the taken branches of the ``cond`` returned)
+        and, where the model gives them (``stats_fn``), its own counts
+        summed over the trained pairs' local steps.
+        """
+        if client_mask is not None:
+            time_w = time_w * client_mask[None, :, None]
+        M, C, N = time_w.shape[0], x.shape[0], x.shape[2]
+        keys = jax.random.split(key, M * C).reshape(M, C, 2)
+        agg_dt = self.precision.agg_jnp
+        totals = time_w.sum(axis=2)                            # [M, C]
+        n_all = jnp.where(totals > 0, totals * N, 0.0)
+        share = (n_all / jnp.maximum(n_all.sum(axis=1, keepdims=True), 1e-12)
+                 ).astype(agg_dt)
+        with_stats = self.stats_fn is not None
+        # the state of an optimizer without state: no leaf, one pair's
+        opt_one = jax.tree_util.tree_map(lambda s: s[0, 0], opt_states)
+
+        def slot(tree, m):
+            return jax.tree_util.tree_map(lambda l: l[m], tree)
+
+        stats0 = None
+        if with_stats:
+            B = min(self.batch_size, N)
+            stats0 = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(lambda p, xb: self.stats_fn(p, xb)[1],
+                               slot(params, 0), x[0, 0, :B]))
+
+        def model(m, carry):
+            pool, n, losses, trained, stats = carry
+
+            def client(c, inner):
+                acc, n, losses, trained, stats = inner
+
+                def train(_):
+                    # the model's slot is read here, pair by pair, so that
+                    # no copy of it lives through the clients' loop
+                    out = self._local_sgd(
+                        slot(pool, m), opt_one, keys[m, c], x[c], y[c],
+                        time_w[m, c], sample_w[m, c], feat_mask[m], lr_scale,
+                        with_stats=with_stats)
+                    p_new, _, n_k, loss = out[:4]
+                    return (jax.tree_util.tree_map(
+                        lambda a, p: a + p.astype(agg_dt) * share[m, c],
+                        acc, p_new), n_k.astype(n.dtype),
+                        loss.astype(losses.dtype), jnp.ones((), jnp.int32),
+                        out[4] if with_stats else None)
+
+                def skip(_):
+                    return (acc, jnp.zeros((), n.dtype),
+                            jnp.zeros((), losses.dtype),
+                            jnp.zeros((), jnp.int32), stats0)
+
+                acc, n_k, loss, ran, stats_k = jax.lax.cond(
+                    totals[m, c] > 0, train, skip, None)
+                return (acc, n.at[m, c].set(n_k), losses.at[m, c].set(loss),
+                        trained + ran,
+                        jax.tree_util.tree_map(jnp.add, stats, stats_k))
+
+            acc, n, losses, trained, stats = jax.lax.fori_loop(
+                0, C, client,
+                (jax.tree_util.tree_map(
+                    lambda l: jnp.zeros(l.shape[1:], agg_dt), pool),
+                 n, losses, trained, stats))
+            ran_m = n[m] > 0
+            keep = ~ran_m.any() | (ran_m & ~jnp.isfinite(losses[m])).any()
+            pool = jax.tree_util.tree_map(
+                lambda l, a: l.at[m].set(
+                    jnp.where(keep, l[m], a.astype(l.dtype))), pool, acc)
+            return pool, n, losses, trained, stats
+
+        with jax.named_scope("client_scan"):
+            new_params, n, losses, trained, stats = jax.lax.fori_loop(
+                0, M, model,
+                (params, jnp.zeros((M, C), time_w.dtype),
+                 jnp.zeros((M, C), jnp.float32), jnp.zeros((), jnp.int32),
+                 stats0))
+        counts = {"pairs_trained": trained}
+        if with_stats:
+            counts.update(stats)
+        agg_stats = _stats((n > 0).sum(axis=1).astype(jnp.int32))
+        return (new_params, opt_states, None, n, losses, agg_stats, None,
+                counts)
+
     def train_round(self, params, opt_states, key, x, y, time_w, sample_w,
                     feat_mask, lr_scale, client_mask=None, byz_modes=None,
                     stale_params=None, edge_ids=None, edge_mask=None,
@@ -469,18 +639,29 @@ class TrainStep:
         deltas (SURVEY.md §7 hard parts), and for deep models that output
         buffer is M x C full model copies of HBM the weighted-mean reduction
         can otherwise stream through.
+
+        Under ``client_axis="scan"`` the program is `_round_body_scan`:
+        ``keep_client_params`` must be False, ``params`` is DONATED (the
+        new pool is written over it) and, with ``with_agg_stats``, an
+        eighth output follows the seven, the round's counts
+        (``pairs_trained`` and the model's own).
         """
         args = (params, opt_states, key, x, y, time_w, sample_w, feat_mask,
                 lr_scale, client_mask, byz_modes, stale_params, edge_ids,
                 edge_mask, edge_modes, codec_prev)
         kwargs = {"keep_client_params": keep_client_params}
+        scan = self.client_axis == "scan"
         with self._tracked(
-                "train_round", type(self)._train_round_jit, args, kwargs,
+                "train_round", type(self)._train_round_scan_jit if scan
+                else type(self)._train_round_jit, args, kwargs,
                 sig=(params, opt_states, x, y, time_w, sample_w, feat_mask,
                      client_mask, byz_modes, stale_params, edge_ids,
                      edge_mask, edge_modes, codec_prev),
                 static=(keep_client_params,)):
-            out = self._train_round_jit(*args, **kwargs)
+            if scan:
+                out = self._train_round_scan_jit(*args, **kwargs)
+            else:
+                out = self._train_round_jit(*args, **kwargs)
         return out if with_agg_stats else out[:5]
 
     @partial(jax.jit, static_argnums=0,
@@ -498,6 +679,23 @@ class TrainStep:
             return out
         new_params, new_opt, _client_params, n, losses, agg_stats, cprev = out
         return new_params, new_opt, None, n, losses, agg_stats, cprev
+
+    # the scanned round writes the new pool over the old one: the pool is
+    # DONATED (argnum 1), and the caller's ``params`` do not outlive the call
+    @partial(jax.jit, static_argnums=0, donate_argnums=(1,),
+             static_argnames=("keep_client_params",))
+    def _train_round_scan_jit(self, params, opt_states, key, x, y, time_w,
+                              sample_w, feat_mask, lr_scale, client_mask=None,
+                              byz_modes=None, stale_params=None,
+                              edge_ids=None, edge_mask=None, edge_modes=None,
+                              codec_prev=None, *,
+                              keep_client_params: bool = True):
+        self._refuse_stack_users(
+            opt_states, keep_client_params, byz_modes, stale_params,
+            edge_ids, edge_mask, edge_modes, codec_prev)
+        return self._round_body_scan(
+            params, opt_states, key, x, y, time_w, sample_w, feat_mask,
+            lr_scale, client_mask)
 
     @staticmethod
     def eval_rounds(R: int, freq: int) -> list[int]:
@@ -663,7 +861,8 @@ class TrainStep:
             (jnp.arange(R, dtype=jnp.int32), client_masks, byz_modes,
              edge_ids, edge_masks, edge_byz))
         params, opt_states, bufs = carry[0], carry[1], carry[2]
-        total = jnp.full((C,), x.shape[2], dtype=jnp.int32)
+        total = jnp.full((C,), x.shape[2] * math.prod(y.shape[3:]),
+                         dtype=jnp.int32)
         return params, opt_states, ns[-1], ls[-1], bufs, total, stats
 
     # ------------------------------------------------------------------
@@ -794,16 +993,38 @@ class TrainStep:
         return self._acc_matrix_body(params, x, y, feat_mask)
 
     def _acc_matrix_body(self, params, x, y, feat_mask):
+        """Hits and summed loss count labels: one per sample, or one per
+        token where ``y`` is [C, N, L]; ``total`` is a client's labels."""
         def one(p_m, f_m):
             def per_client(xc, yc):
                 xin = xc * f_m if xc.dtype != jnp.int32 else xc
                 logits = self.apply_fn(p_m, xin)
                 logp = jax.nn.log_softmax(logits)
-                nll = -jnp.take_along_axis(logp, yc[:, None], axis=-1).sum()
+                nll = -jnp.take_along_axis(logp, yc[..., None], axis=-1).sum()
                 return (logits.argmax(-1) == yc).sum(), nll
+            if self.client_axis == "scan":
+                # the clients in turn, and a client's samples in chunks of
+                # the training batch: one forward at a time, of the size the
+                # local steps run, whatever M, C and N are
+                N = x.shape[1]
+                B = min(self.batch_size, N)
+                B = B if N % B == 0 else N
+
+                def chunked(xc_yc):
+                    xc, yc = (a.reshape((N // B, B) + a.shape[1:])
+                              for a in xc_yc)
+                    hits, nll = jax.lax.map(lambda ab: per_client(*ab),
+                                            (xc, yc))
+                    return hits.sum(), nll.sum()
+                return jax.lax.map(chunked, (x, y))
             return jax.vmap(per_client)(x, y)
-        correct, loss_sum = jax.vmap(one)(params, feat_mask)
-        total = jnp.full((x.shape[0],), x.shape[1], dtype=jnp.int32)
+        if self.client_axis == "scan":
+            correct, loss_sum = jax.lax.map(lambda pf: one(*pf),
+                                            (params, feat_mask))
+        else:
+            correct, loss_sum = jax.vmap(one)(params, feat_mask)
+        labels = x.shape[1] * math.prod(y.shape[2:])
+        total = jnp.full((x.shape[0],), labels, dtype=jnp.int32)
         return correct, loss_sum, total
 
     # ------------------------------------------------------------------
@@ -910,6 +1131,10 @@ class TrainStep:
     @partial(jax.jit, static_argnums=0)
     def confusion_matrices(self, params, x, y, feat_mask):
         """Per-(model, client) confusion matrices [M, C, K, K] (KUE kappa)."""
+        if y.ndim > 2:
+            raise ValueError(
+                "confusion_matrices (KUE's kappa) reads one label a sample; "
+                f"y has trailing label axes {y.shape[2:]} (a label per token)")
         K = self.num_classes
         def one(p_m, f_m):
             def per_client(xc, yc):

@@ -6,7 +6,9 @@ disk in every MPI process. Here the whole simulation's data is a pair of dense
 arrays with static shapes — ideal for XLA:
 
     x: [C, T+1, N, ...]   features  (T+1: step T is the final held-out test step)
-    y: [C, T+1, N]        int32 labels
+    y: [C, T+1, N]        int32 labels; [C, T+1, N, *label_shape] where a
+                          sample has more than one (a sequence with a label
+                          per token: [C, T+1, N, L])
 
 Per-(t, c) sample counts are constant (``sample_num``, reference default 500,
 run_fedavg_distributed_pytorch.sh:15), so no padding/ragged handling is needed.
@@ -23,7 +25,7 @@ import numpy as np
 @dataclass
 class DriftDataset:
     x: np.ndarray                # [C, T+1, N, *feature_shape] float32
-    y: np.ndarray                # [C, T+1, N] int32
+    y: np.ndarray                # [C, T+1, N, *label_shape] int32
     num_classes: int
     concepts: np.ndarray         # [T+1, C] concept id per (step, client)
     name: str = "synthetic"
@@ -32,7 +34,8 @@ class DriftDataset:
     meta: dict = field(default_factory=dict)
 
     def __post_init__(self) -> None:
-        assert self.x.shape[:3] == self.y.shape, (self.x.shape, self.y.shape)
+        assert self.x.shape[:3] == self.y.shape[:3], (self.x.shape,
+                                                      self.y.shape)
         assert self.concepts.shape[0] == self.x.shape[1]
 
     @property
@@ -53,11 +56,17 @@ class DriftDataset:
         return self.x.shape[3:]
 
     @property
+    def labels_per_sample(self) -> int:
+        """1, or the tokens of a sequence where each has its own label."""
+        return int(np.prod(self.y.shape[3:], dtype=np.int64))
+
+    @property
     def flat_feature_dim(self) -> int:
         return int(np.prod(self.feature_shape)) if self.feature_shape else 1
 
     def train_slice(self, t: int) -> tuple[np.ndarray, np.ndarray]:
-        """Data of training step t across clients: ([C, N, ...], [C, N])."""
+        """Data of training step t across clients: ([C, N, ...],
+        [C, N, *label_shape])."""
         return self.x[:, t], self.y[:, t]
 
     def test_slice(self, t: int) -> tuple[np.ndarray, np.ndarray]:

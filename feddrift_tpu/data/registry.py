@@ -93,6 +93,17 @@ def _mk_text(cfg: ExperimentConfig, change_points: np.ndarray) -> DriftDataset:
         seq_len=cfg.text_seq_len, data_dir=cfg.data_dir)
 
 
+@register_dataset("token_drift")
+def _mk_tokens(cfg: ExperimentConfig, change_points: np.ndarray) -> DriftDataset:
+    # next-token data, a label per token, over cfg.token_vocab ids (a model
+    # that holds another number of rows refuses the data set by name)
+    from feddrift_tpu.data.text import generate_token_drift
+    return generate_token_drift(
+        change_points, cfg.train_iterations, cfg.client_num_in_total,
+        cfg.sample_num, cfg.time_stretch, cfg.seed,
+        seq_len=cfg.text_seq_len, vocab=cfg.token_vocab)
+
+
 @register_dataset("susy", "ro")
 def _mk_uci(cfg: ExperimentConfig, change_points: np.ndarray) -> DriftDataset:
     from feddrift_tpu.data.tabular import generate_uci_drift

@@ -215,6 +215,22 @@ def _concept_transition(concept: int, vocab: int) -> np.ndarray:
     return mat / mat.sum(axis=1, keepdims=True)
 
 
+def _num_concepts(change_points: np.ndarray) -> int:
+    """Concepts a change-point matrix names, at least two."""
+    return max(int(change_points.max()) + 1, 2)
+
+
+def _affine_maps(n_concepts: int, vocab: int) -> tuple[np.ndarray, np.ndarray]:
+    """Per concept k the affine language ``next = (a_k * cur + b_k) mod V``:
+    a deterministic successor map whose parameters (the language
+    statistics) change at drift points. The same draw for every data set
+    that uses it."""
+    crng = np.random.default_rng(104729)
+    a = crng.integers(2, vocab - 1, size=n_concepts)
+    b = crng.integers(0, vocab, size=n_concepts)
+    return a, b
+
+
 def generate_word_drift(
     change_points: np.ndarray,
     train_iterations: int,
@@ -249,10 +265,8 @@ def generate_word_drift(
                                   seq_len, vocab, rng, noise_prob,
                                   "stackoverflow_nwp")
 
-    n_concepts = max(int(change_points.max()) + 1, 2)
-    crng = np.random.default_rng(104729)
-    a = crng.integers(2, vocab - 1, size=n_concepts)
-    b = crng.integers(0, vocab, size=n_concepts)
+    n_concepts = _num_concepts(change_points)
+    a, b = _affine_maps(n_concepts, vocab)
 
     x = np.zeros((num_clients, T + 1, sample_num, seq_len), dtype=np.int32)
     y = np.zeros((num_clients, T + 1, sample_num), dtype=np.int32)
@@ -301,8 +315,8 @@ def generate_text_drift(
                                   seq_len, vocab, rng, noise_prob,
                                   "shakespeare")
 
-    n_concepts = int(change_points.max()) + 1
-    chains = [_concept_transition(k, vocab) for k in range(max(n_concepts, 2))]
+    chains = [_concept_transition(k, vocab)
+              for k in range(_num_concepts(change_points))]
 
     x = np.zeros((num_clients, T + 1, sample_num, seq_len), dtype=np.int32)
     y = np.zeros((num_clients, T + 1, sample_num), dtype=np.int32)
@@ -327,3 +341,75 @@ def generate_text_drift(
     return DriftDataset(x=x, y=y, num_classes=vocab, concepts=concepts,
                         name="shakespeare", is_sequence=True,
                         meta={"vocab": vocab, "seq_len": seq_len})
+
+
+# ----------------------------------------------------------------------
+# next-token data with a label per token
+# the unigram law: p(rank r) ~ 1 / r, Zipf's own exponent for words. (At 1.5
+# one id is a fifth of all tokens, every position's attention reads much the
+# same mean, and a randomly initialised router sends a quarter to a half of
+# the tokens to one expert: CPU count at the published cut, PERF.md, PR 29.)
+ZIPF_EXPONENT = 1.0
+FOLLOW_PROB = 0.5        # share of tokens that follow the affine map
+
+
+def _zipf_cdf(vocab: int, exponent: float) -> np.ndarray:
+    p = np.arange(1, vocab + 1, dtype=np.float64) ** -exponent
+    return np.cumsum(p / p.sum())
+
+
+def _rank_permutation(concept: int, vocab: int) -> np.ndarray:
+    """Which id holds each rank of the unigram law under ``concept``."""
+    return np.random.default_rng(7919 + concept).permutation(vocab)
+
+
+def generate_token_drift(
+    change_points: np.ndarray,
+    train_iterations: int,
+    num_clients: int,
+    sample_num: int,
+    time_stretch: int = 1,
+    seed: int = 0,
+    seq_len: int = SEQ_LEN,
+    vocab: int = VOCAB_SIZE,
+    zipf_exponent: float = ZIPF_EXPONENT,
+    follow_prob: float = FOLLOW_PROB,
+) -> DriftDataset:
+    """Next-token data for a decoder: ``x`` [C, T+1, N, L] token ids and
+    ``y`` [C, T+1, N, L] = ``x`` shifted by one, a label per token.
+
+    Each concept k is a language over ``vocab`` ids (the rows of the
+    vocabulary that the model holds): a Zipfian unigram law whose ranks the
+    concept permutes (``_rank_permutation``), mixed with the affine successor
+    map of ``_affine_maps``: with probability ``follow_prob`` the next token
+    is ``(a_k * cur + b_k) mod V``, else a fresh draw from the concept's
+    unigram law. A drift changes both. The unigram part is what a few local
+    steps can learn (the frequent ids differ between concepts), the
+    successor map what longer training can. Fixed-length sequences, every
+    one from a fresh start: no packing and no document boundaries. Always
+    made from the seed: there is no on-disk form.
+    """
+    rng = np.random.default_rng(seed)
+    T1 = train_iterations + 1
+    concepts = concept_matrix(change_points, T1, num_clients, time_stretch)
+    n_concepts = _num_concepts(change_points)
+    a, b = _affine_maps(n_concepts, vocab)
+    perms = np.stack([_rank_permutation(k, vocab) for k in range(n_concepts)])
+    cdf = _zipf_cdf(vocab, zipf_exponent)
+
+    k = (concepts.T % n_concepts)[:, :, None, None]          # [C, T1, 1, 1]
+    shape = (num_clients, T1, sample_num, seq_len + 1)
+    ranks = np.minimum(np.searchsorted(cdf, rng.random(shape)), vocab - 1)
+    fresh = perms[np.broadcast_to(k, shape), ranks].astype(np.int64)
+    follow = rng.random(shape) < follow_prob
+    a_k, b_k = a[k[..., 0]], b[k[..., 0]]                    # [C, T1, 1]
+    seq = fresh.copy()
+    for s in range(seq_len):
+        nxt = (a_k * seq[..., s] + b_k) % vocab
+        seq[..., s + 1] = np.where(follow[..., s + 1], nxt, fresh[..., s + 1])
+    seq = seq.astype(np.int32)
+    return DriftDataset(
+        x=seq[..., :-1], y=seq[..., 1:], num_classes=vocab,
+        concepts=concepts, name="token_drift", is_sequence=True,
+        meta={"vocab": vocab, "seq_len": seq_len,
+              "zipf_exponent": zipf_exponent, "follow_prob": follow_prob})

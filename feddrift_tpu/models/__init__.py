@@ -120,6 +120,22 @@ def _transformer(ds: DriftDataset, cfg) -> nn.Module:
                                      if ds.is_sequence else 128, 128))
 
 
+@register_model("kanana2_30b_a3b_cut16", "mla_moe_tiny")
+def _mla_moe(ds: DriftDataset, cfg) -> nn.Module:
+    """A latent-attention, sparse-expert decoder preset (the model's name
+    is the preset's: the published sizes and the cut held here); the data
+    set's ids lie in the held rows of the vocabulary, a label per token."""
+    from feddrift_tpu.models.mla_moe import PRESETS, MLAMoEDecoder
+    rows = PRESETS[cfg.model]["vocab_rows_held"]
+    if ds.num_classes != rows or ds.labels_per_sample < 2:
+        raise ValueError(
+            f"model {cfg.model!r} holds {rows} rows of its vocabulary and "
+            f"takes a label per token; data set {ds.name!r} has "
+            f"{ds.num_classes} ids and {ds.labels_per_sample} label(s) a "
+            f"sample (use dataset 'token_drift')")
+    return MLAMoEDecoder(preset=cfg.model, remat=cfg.remat)
+
+
 @register_model("rnn")
 def _rnn(ds: DriftDataset, cfg) -> nn.Module:
     return CharLSTM(vocab_size=ds.num_classes)

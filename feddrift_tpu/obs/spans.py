@@ -37,11 +37,13 @@ imports JAX.
 from __future__ import annotations
 
 import collections
+import inspect
 import json
 import os
 import threading
 import time
 import uuid
+import weakref
 from typing import Any, Callable
 
 RING_SIZE = 8192
@@ -131,6 +133,7 @@ class _Span:
         rec.record(self._name, rec.wall(self._frame[0]), self.dur,
                    self._cat, **self._args)
         hook = getattr(rec._local, "hook", None)
+        hook = hook() if isinstance(hook, weakref.WeakMethod) else hook
         if hook is not None:
             hook(self._name, self._cat, self.dur, self_s)
 
@@ -190,8 +193,12 @@ class SpanRecorder:
                  | None) -> None:
         """The calling thread's completion hook (None takes it off). One
         per thread: two experiments driven from two threads each see their
-        own spans, whichever layer recorded them."""
-        self._local.hook = hook
+        own spans, whichever layer recorded them. A bound method is held
+        weakly: the process-wide recorder outlives an experiment, and
+        holding its hook would hold the experiment, its pool and whatever
+        they keep on the device."""
+        self._local.hook = weakref.WeakMethod(hook) \
+            if inspect.ismethod(hook) else hook
 
     # -- the self-time stack -------------------------------------------
     def _begin(self, now: float) -> list:

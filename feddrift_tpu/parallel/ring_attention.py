@@ -59,8 +59,12 @@ def _block_attn(q, k, v, acc, m, l, q_off, k_off, causal: bool, scale: float,
 
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         block_size: int = 512) -> jnp.ndarray:
-    """Single-device flash-style attention via lax.scan over key blocks."""
+    """Single-device flash-style attention via lax.scan over key blocks.
+
+    ``v`` may be narrower or wider than ``q`` and ``k`` ([B, H, L, Dv]): the
+    result has the value's width, the scores are scaled by the key's."""
     B, H, L, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / jnp.sqrt(D).astype(q.dtype)
     bs = min(block_size, L)
     nblocks = -(-L // bs)
@@ -70,9 +74,9 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     k_blocks = k.reshape(B, H, nblocks, bs, D).transpose(2, 0, 1, 3, 4)
-    v_blocks = v.reshape(B, H, nblocks, bs, D).transpose(2, 0, 1, 3, 4)
+    v_blocks = v.reshape(B, H, nblocks, bs, Dv).transpose(2, 0, 1, 3, 4)
 
-    acc = jnp.zeros_like(q)
+    acc = jnp.zeros((B, H, L, Dv), q.dtype)
     m = jnp.full((B, H, L), NEG_INF, dtype=q.dtype)
     l = jnp.zeros((B, H, L), dtype=q.dtype)
 

@@ -131,6 +131,11 @@ class Experiment:
             # (and memory_analysis under "compiled") into program_cost
             # events + gauges.
             cost_capture=cfg.cost_model,
+            # Static: vmap over all (model, client) pairs, or the pairs one
+            # at a time (core/step.py::_round_body_scan); the forward that
+            # also returns the model's own counts, where it gives any
+            client_axis=cfg.client_axis,
+            stats_fn=self._make_apply(stats=True),
             # The megastep program annotates its [M, C, ...] stacks with
             # with_sharding_constraint over this mesh (no-op on 1-D/1-device
             # meshes — parallel/mesh.py::constrain_pool).
@@ -450,6 +455,12 @@ class Experiment:
             # which attention path "auto" became here (None: the model has
             # no attention); see models/transformer.py
             attention_impl=self._attention_impl(),
+            # which round body runs the (model, client) pairs, and the
+            # routed experts this process holds of each expert layer
+            # ([first, end) of the router's outputs; None: no expert layer)
+            client_axis=cfg.client_axis,
+            experts_held=(list(self.module.experts_held)
+                          if hasattr(self.module, "experts_held") else None),
             seed=cfg.seed, concept_matrix=concept_matrix,
             population=cfg.population_size or None)
         if cfg.debug_checks:
@@ -467,8 +478,12 @@ class Experiment:
         from feddrift_tpu.models.transformer import resolve_attention_impl
         return resolve_attention_impl(impl)
 
-    def _make_apply(self):
+    def _make_apply(self, stats: bool = False):
         """Forward fn honoring the resolved precision policy.
+
+        ``stats=True`` is the forward that also returns the module's own
+        counts of the call (``return_stats``; models/mla_moe.py), cast at
+        the same boundary, or None for a module that counts nothing.
 
         When the policy's compute dtype differs from the stored leaves,
         params and float inputs are cast at the call boundary so
@@ -488,9 +503,12 @@ class Experiment:
         """
         module = self.module
         pol = self.precision
+        if stats and not getattr(module, "returns_stats", False):
+            return None
+        kw = {"return_stats": True} if stats else {}
         if pol.param_dtype == "float32" and pol.compute_dtype == "float32":
             def apply_fn(p, x):
-                return module.apply({"params": p}, x)
+                return module.apply({"params": p}, x, **kw)
         else:
             compute_dt = pol.compute_jnp
 
@@ -502,8 +520,14 @@ class Experiment:
                 if jnp.issubdtype(x.dtype, jnp.floating) \
                         and x.dtype != compute_dt:
                     x = x.astype(compute_dt)
-                return module.apply({"params": pc}, x).astype(jnp.float32)
-        if self.cfg.remat:
+                out = module.apply({"params": pc}, x, **kw)
+                if stats:
+                    return out[0].astype(jnp.float32), out[1]
+                return out.astype(jnp.float32)
+        # a module that remats its own blocks (``remat_blocks``) is not
+        # wrapped again: a checkpoint around checkpoints runs the forward
+        # a third time
+        if self.cfg.remat and not getattr(module, "remat_blocks", False):
             apply_fn = jax.checkpoint(apply_fn)
         return apply_fn
 
@@ -899,6 +923,7 @@ class Experiment:
                                  "with a non-ensemble test path")
             self._run_iteration_fused(t, opt_states, stream=True)
         elif (cfg.chunk_rounds and self.secure_driver is None
+                and cfg.client_axis != "scan"   # the fused body is vmap's
                 and self.algo.chunkable(t)
                 and self.algo.ensemble_spec(t) is None):
             self._run_iteration_fused(t, opt_states)
@@ -1060,21 +1085,34 @@ class Experiment:
                                     self.failure_detector.suspected.tolist())
         return masks
 
-    def _check_divergence(self, losses, n, fetched: bool = False) -> bool:
+    def _check_divergence(self, losses, n, fetched: bool = False,
+                          counts=None) -> bool:
         """Guard one round's losses; True = diverged (caller rolls back).
         The fetch goes through multihost so every process of a
         multi-controller run sees identical arrays and stays in lockstep;
         ``fetched`` says the caller hands in host arrays it fetched that
-        way already (the megastep replay)."""
+        way already (the megastep replay). ``counts`` (the scanned round's
+        eighth output) comes back in the same fetch and is recorded as
+        counters and on the ``guard`` span; with no guard there is no such
+        fetch and they are not read."""
         if self.divergence_guard is None:
             return False
-        with self._seg("guard"):
+        with self._seg("guard") as sp:
             if not fetched:
-                losses, n = multihost.fetch((losses, n))
+                losses, n, counts = multihost.fetch((losses, n, counts))
+            if counts is not None:
+                self._record_round_counts(sp, counts)
             diverged, reason, observed = self.divergence_guard.check(
                 np.asarray(losses), np.asarray(n))
         if not diverged:
             return False
+        if reason == "loss_spike" and self.cfg.client_axis == "scan":
+            from feddrift_tpu.resilience.divergence import DivergenceError
+            raise DivergenceError(
+                f"loss spike ({observed:.4g}) at round {self.global_round} "
+                "under client_axis='scan': the round wrote the new pool "
+                "over the old one (donated), so there is nothing to roll "
+                "back to; aborting the run")
         g = self.divergence_guard
         self.events.emit(
             "divergence_detected", reason=reason,
@@ -1087,6 +1125,37 @@ class Experiment:
         log.warning("divergence (%s) at round %d: rolling back pool params",
                     reason, self.global_round)
         return True
+
+    @staticmethod
+    def _record_round_counts(span, counts: dict) -> None:
+        """What the scanned round counted, on the span that fetched it and
+        as counters: the pairs whose local loop ran and, from a model with
+        an expert layer, over the expert layers of the round's training
+        steps: the tokens that went through, the (token, expert)
+        assignments that fell on held experts, the blocks of rows that went
+        through them (what the routed part cost: it follows the router),
+        and the fullest held expert's load over the mean held expert's (a
+        gauge)."""
+        reg = obs.registry()
+        args = {"pairs_trained": int(counts["pairs_trained"])}
+        reg.counter("pairs_trained").inc(args["pairs_trained"])
+        if "expert_tokens" in counts:
+            loads = np.asarray(counts["expert_load"], np.float64)
+            args.update(
+                expert_tokens=int(counts["expert_tokens"]),
+                expert_assignments_held=int(loads.sum()))
+            reg.counter("expert_tokens").inc(args["expert_tokens"])
+            reg.counter("expert_assignments_held").inc(
+                args["expert_assignments_held"])
+            args["expert_blocks"] = int(counts["expert_blocks"])
+            reg.counter("expert_blocks").inc(
+                args["expert_blocks"])
+            if loads.sum() > 0:
+                args["expert_load_max_over_mean"] = round(
+                    float(loads.max() / loads.mean()), 4)
+                reg.gauge("expert_load_max_over_mean").set(
+                    args["expert_load_max_over_mean"])
+        span.set(**args)
 
     def _byz_modes(self, rounds, t: int) -> "np.ndarray | None":
         """[len(rounds), C_pad] int32 attack schedule (phantom clients are
@@ -1213,9 +1282,12 @@ class Experiment:
                     None if a is None else jnp.asarray(a[0])
                     for a in (cm, bm, eids, emasks, ebyz))
             prev_params = self.pool.params
+            # the scanned round is given the pool to write over (donated):
+            # after the call nothing of ``prev_params`` may be read
+            pool_donated = cfg.client_axis == "scan"
             with self.tracer.phase("train_round"):
                 (new_params, opt_states, client_params, n, losses, agg_stats,
-                 codec_prev) = self.step.train_round(
+                 codec_prev, *counts) = self.step.train_round(
                     prev_params, opt_states, rkey, self.x, self.y, tw, sw,
                     fm, lr_scale, cm, bm,
                     self._byz_stale if (byz is not None and byz.has_stale)
@@ -1235,11 +1307,16 @@ class Experiment:
                     self._emit_robust_stats(
                         # lint: r2-ok (tiny gated [M, 3] evidence fetch)
                         multihost.fetch(agg_stats), self.global_round)
-                if self._check_divergence(losses, n):
+                if self._check_divergence(losses, n, counts=counts[0]
+                                          if counts else None):
                     # rollback: pre-round params, fresh optimizer state (the
                     # diverged step contaminated both); skip after_round and
-                    # this round's eval — its numbers would be garbage
-                    self.pool.params = prev_params
+                    # this round's eval — its numbers would be garbage.
+                    # A donated pool: the round itself kept the parameters
+                    # of every model with a loss that is not finite; a
+                    # spike it cannot undo (_check_divergence raised)
+                    self.pool.params = new_params if pool_donated \
+                        else prev_params
                     with self._seg("opt_init"):
                         opt_states = self.step.fresh_opt_states(
                             self.pool.params, self.C_pad)
@@ -1251,7 +1328,8 @@ class Experiment:
                         new_params = self._secure_substitute(
                             prev_params, new_params, client_params, n)
                     self.pool.params = self.algo.after_round(
-                        t, r, prev_params, new_params, client_params, n)
+                        t, r, None if pool_donated else prev_params,
+                        new_params, client_params, n)
             if r % cfg.frequency_of_the_test == 0 or r == cfg.comm_round - 1:
                 with self._seg("eval"):
                     self.evaluate(t, r)
