@@ -93,6 +93,12 @@ class DriftAlgorithm:
     # sampled this iteration. Stateless algorithms get this for free via
     # the base load_cohort_state; instance attribute where kind-dependent.
     supports_cohort = False
+    # The most models with weight in ``round_inputs``' time weights that any
+    # client has, where the algorithm counted them on the host as it made
+    # those weights: the round program then runs that many models a client
+    # and not M (TrainStep.train_round ``models_per_client``). None says
+    # nothing, and every (model, client) pair runs.
+    models_per_client: int | None = None
 
     def __init__(self, cfg, ds, pool, step) -> None:
         self.cfg = cfg

@@ -44,6 +44,15 @@ from feddrift_tpu.comm import multihost
 log = logging.getLogger("feddrift_tpu.softcluster")
 
 
+def live_models_per_client(weights: np.ndarray) -> int:
+    """The most models any client trains under the [T1, M, C] weights: a
+    (model, client) pair trains where its weights over time sum above 0
+    (core/step.py::_local_sgd). 1 under a hard assignment with a window of
+    one step, M under soft weights; at least 1, so that a round in which
+    nobody trains still has a program to run."""
+    return max(1, int((weights.sum(axis=0) > 0).sum(axis=0).max()))
+
+
 @register_algorithm("softcluster", "softclusterwin-1", "softclusterreset")
 class SoftCluster(DriftAlgorithm):
     name = "softcluster"
@@ -123,6 +132,7 @@ class SoftCluster(DriftAlgorithm):
     def _sync_device_weights(self) -> None:
         # [T1, M, C] -> [M, C, T1] for the train step
         self._tw = jnp.asarray(np.transpose(self.weights, (1, 2, 0)))
+        self.models_per_client = live_models_per_client(self.weights)
 
     def round_inputs(self, t: int, r: int):
         return self._tw, self._ones_sample_w, self._ones_feat_mask, jnp.float32(1.0)

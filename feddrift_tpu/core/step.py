@@ -27,6 +27,10 @@ axis, which GSPMD lowers to an all-reduce over ICI when C is sharded. Unused
 (model, client) pairs (zero total weight) still execute — static shapes — but
 their updates are masked out, mirroring the reference's skip logic
 (FedAvgEnsTrainerSoftCluster.py:67-79, AggregatorSoftCluster.py:151-169).
+Where the caller counted on the host that no client trains more than K < M
+models this round (``train_round``'s ``models_per_client``), the vmap runs
+over (K, C) instead — `_round_compact`: a hard assignment trains C pairs,
+not M x C.
 
 Batch sampling semantics match the reference: data is pre-shuffled once per
 time step (host side), a step picks time step t ~ Categorical(time_w) and a
@@ -247,7 +251,7 @@ class TrainStep:
             kind = self._note_signature(fn, *sig, static=static)
             self._capture_cost(kind, fn, jit_fn, args, kwargs)
             track_us = round((time.perf_counter() - p0) * 1e6, 1)
-            yield
+            yield sp
             if kind is None:
                 sp.set(track_us=track_us)
             else:
@@ -385,7 +389,7 @@ class TrainStep:
     def _round_body(self, params, opt_states, key, x, y, time_w, sample_w,
                     feat_mask, lr_scale, client_mask=None, byz_modes=None,
                     stale_params=None, edge_ids=None, edge_mask=None,
-                    edge_modes=None, codec_prev=None):
+                    edge_modes=None, codec_prev=None, slots=None):
         """One communication round (untraced body shared by train_round and
         the fused train_iteration_eval scan).
 
@@ -408,6 +412,12 @@ class TrainStep:
         last round's decoded diff stack, the delta codec's carry (None ->
         zeros: round 0 deltas against the broadcast params).
 
+        slots (static K, 1 <= K < M): local SGD runs for K models a client
+        and not for M (`_round_compact`); given by ``train_round`` alone,
+        and only where nothing of the call needs the [M, C, ...] parameter
+        stack (`_stack_users`), so with none of the operands above but
+        ``client_mask``. ``client_params`` is then None.
+
         Returns ``(new_params, new_opt, client_params, n, losses,
         agg_stats, new_codec_prev)`` — agg_stats is [M, 3] on the flat
         path and [1 + E, M, 3] (server tier in row 0) on the hierarchy
@@ -428,6 +438,9 @@ class TrainStep:
         M = time_w.shape[0]
         C = x.shape[0]
         keys = jax.random.split(key, M * C).reshape(M, C, 2)
+        if slots is not None:
+            return self._round_compact(params, opt_states, keys, x, y, time_w,
+                                       sample_w, feat_mask, lr_scale, slots)
 
         # vmap over clients (inner), then models (outer).
         def per_model(p_m, o_m, k_m, w_m, s_m, f_m):
@@ -491,20 +504,112 @@ class TrainStep:
         return (new_params, new_opt, client_params, n, losses, agg_stats,
                 new_codec_prev)
 
-    def _refuse_stack_users(self, opt_states, keep_client_params, *stack_args):
-        """``client_axis="scan"`` keeps no [M, C, ...] stack; whatever needs
-        one is refused by the name of the field that asks for it."""
-        asked = [name for name, on in (
-            ("an optimizer with state (client_optimizer='adam'): only an "
-             "optimizer without state ('sgd') is kept",
-             bool(jax.tree_util.tree_leaves(opt_states))),
+    def _round_compact(self, params, opt_states, keys, x, y, time_w,
+                       sample_w, feat_mask, lr_scale, K: int):
+        """`_round_body` from the pairs' keys on, with local SGD run for K
+        models a client and not for M: the same pairs on the same keys, and
+        so on the same batches, in a batch of K x C where the dense body's
+        is M x C. ``time_w`` comes masked by the round's participants.
+
+        Per client its models are put in order, those with weight first
+        (a stable sort: by model index), and cut to K; the host counted at
+        most K with weight (``train_round``'s ``models_per_client``). A slot
+        past a client's own count holds a model without weight and trains
+        masked as the dense body's pairs do: parameters and optimizer state
+        as they came, n = 0. What is small is gathered along the model axis
+        (parameters, optimizer state, keys, weights, feature masks); ``x``
+        and ``y`` stay where they are, mapped over the client axis, which
+        stays the sharded one on a mesh. The weighted mean is `weighted_mean`
+        term by term, summed over the slots that hold the model; a pair that
+        did not run contributes ``0 * params`` there and nothing here.
+
+        Returns what `_round_body` returns, in its layouts: the optimizer
+        state the full [M, C, ...] stack with the trained slots written
+        back, ``n`` and ``losses`` [M, C] (0 for a pair in no slot),
+        ``client_params`` and the codec carry None.
+        """
+        M = keys.shape[0]
+        active = time_w.sum(axis=2) > 0                        # [M, C]
+        order = jnp.argsort(~active, axis=0, stable=True)
+        m_idx = order[:K]                 # [K, C]: slot k of client c trains
+        slot_of = jnp.argsort(order, axis=0)    # [M, C]: m_idx's inverse
+        held = slot_of < K
+
+        def along_models(leaf, idx):
+            # leaf [A, C, ...], idx [B, C] -> [B, C, ...]: leaf[idx[b, c], c]
+            return jnp.take_along_axis(
+                leaf, idx.reshape(idx.shape + (1,) * (leaf.ndim - 2)), axis=0)
+
+        def pick(tree):                   # [M, C, ...] -> [K, C, ...]
+            return jax.tree_util.tree_map(
+                lambda l: along_models(l, m_idx), tree)
+
+        def spread(leaf_kc, rest):        # [K, C, ...] -> [M, C, ...]
+            got = along_models(leaf_kc, jnp.minimum(slot_of, K - 1))
+            return jnp.where(
+                held.reshape(held.shape + (1,) * (got.ndim - 2)), got, rest)
+
+        # vmap over clients (inner), then a client's slots (outer).
+        def per_slot(p_k, o_k, k_k, w_k, s_k, f_k):
+            return jax.vmap(
+                lambda p, o, k, xc, yc, w, s, f: self._local_sgd(
+                    p, o, k, xc, yc, w, s, f, lr_scale)
+            )(p_k, o_k, k_k, x, y, w_k, s_k, f_k)
+
+        p_new, o_new, n_k, loss_k = jax.vmap(per_slot)(
+            jax.tree_util.tree_map(lambda l: l[m_idx], params),
+            pick(opt_states), pick(keys), pick(time_w), pick(sample_w),
+            feat_mask[m_idx])
+        new_opt = jax.tree_util.tree_map(spread, o_new, opt_states)
+        n = spread(n_k, 0.0)
+        losses = spread(loss_k, 0.0)
+
+        agg_dt = self.precision.agg_jnp
+        n_agg = cast_floating(n, agg_dt)
+        denom = n_agg.sum(axis=1)                              # [M]
+        share = along_models(
+            n_agg / jnp.maximum(denom[:, None], 1e-12), m_idx)  # [K, C]
+        mine = m_idx[None] == jnp.arange(M)[:, None, None]     # [M, K, C]
+
+        def avg(leaf_kc, leaf_m):
+            tail = (1,) * (leaf_kc.ndim - 2)
+            terms = leaf_kc * share.reshape(share.shape + tail)
+            agg = jnp.where(mine.reshape(mine.shape + tail), terms[None],
+                            0).sum(axis=(1, 2))
+            keep = (denom > 0).reshape((-1,) + (1,) * (leaf_m.ndim - 1))
+            return jnp.where(keep, agg, leaf_m)
+
+        new_params = match_dtypes(jax.tree_util.tree_map(
+            avg, cast_floating(p_new, agg_dt), cast_floating(params, agg_dt)),
+            params)
+        agg_stats = _stats((n > 0).sum(axis=1).astype(jnp.int32))
+        return new_params, new_opt, None, n, losses, agg_stats, None
+
+    def _stack_users(self, keep_client_params, *stack_args) -> list[str]:
+        """What of a round's call needs the [M, C, ...] parameter stack or
+        `aggregate`'s own closing of the round, by the name of the field
+        that asks for it. The scanned body refuses these
+        (`_refuse_stack_users`); the compact vmap body was measured with
+        none of them, and ``train_round`` runs the dense body where there
+        is one."""
+        return [name for name, on in (
             ("keep_client_params=True", keep_client_params),
             (f"robust_agg={self.robust_agg!r}", self.robust_agg != "mean"),
+            ("robust_cfg.dp_stddev > 0", self.robust_cfg.dp_stddev > 0.0),
             (f"codec={self.codec!r}", self.codec != "none"),
             ("hier_edges > 0", self.hier_edges > 0),
             ("byz_modes / stale_params / edge operands / codec_prev",
              any(a is not None for a in stack_args)),
             ("weighted_sampling", self.weighted_sampling)) if on]
+
+    def _refuse_stack_users(self, opt_states, keep_client_params, *stack_args):
+        """``client_axis="scan"`` keeps no [M, C, ...] stack; whatever needs
+        one is refused by the name of the field that asks for it."""
+        asked = self._stack_users(keep_client_params, *stack_args)
+        if jax.tree_util.tree_leaves(opt_states):
+            asked.insert(0, "an optimizer with state (client_optimizer="
+                            "'adam'): only an optimizer without state "
+                            "('sgd') is kept")
         if asked:
             raise ValueError(
                 "client_axis='scan' takes one (model, client) pair at a time "
@@ -627,7 +732,8 @@ class TrainStep:
                     stale_params=None, edge_ids=None, edge_mask=None,
                     edge_modes=None, codec_prev=None, *,
                     keep_client_params: bool = True,
-                    with_agg_stats: bool = False):
+                    with_agg_stats: bool = False,
+                    models_per_client: int | None = None):
         """One communication round. Returns (new_params [M, ...],
         new_opt_states, client_params [M, C, ...], n [M, C], mean_loss [M, C])
         plus, when ``with_agg_stats``, the robust-aggregation stats
@@ -640,6 +746,18 @@ class TrainStep:
         buffer is M x C full model copies of HBM the weighted-mean reduction
         can otherwise stream through.
 
+        ``models_per_client`` (K): the most models with weight in ``time_w``
+        that any client has, as the caller counted them on the host
+        (`DriftAlgorithm.models_per_client`); None says nothing. With
+        1 <= K < M, and nothing in the call that `_stack_users` names, the
+        vmap body runs local SGD for K x C pairs and not for M x C
+        (`_round_compact`): the same results, in the same layouts, and a
+        program of its own for each K. Otherwise the dense body runs, the
+        program it has always been. Counter ``pairs_run`` and the
+        ``dispatch`` span's ``pairs_run`` say how many pairs the dispatched
+        program runs local steps for (not under ``client_axis="scan"``,
+        which counts the pairs it trained itself: ``pairs_trained``).
+
         Under ``client_axis="scan"`` the program is `_round_body_scan`:
         ``keep_client_params`` must be False, ``params`` is DONATED (the
         new pool is written over it) and, with ``with_agg_stats``, an
@@ -651,30 +769,44 @@ class TrainStep:
                 edge_mask, edge_modes, codec_prev)
         kwargs = {"keep_client_params": keep_client_params}
         scan = self.client_axis == "scan"
+        M, C = time_w.shape[:2]
+        K = models_per_client
+        if K is not None and K < 1:
+            raise ValueError(f"models_per_client must be at least 1, got {K}")
+        if scan or K is None or K >= M or self._stack_users(
+                keep_client_params, byz_modes, stale_params, edge_ids,
+                edge_mask, edge_modes, codec_prev):
+            K = None
+        else:
+            kwargs["models_per_client"] = K
         with self._tracked(
                 "train_round", type(self)._train_round_scan_jit if scan
                 else type(self)._train_round_jit, args, kwargs,
                 sig=(params, opt_states, x, y, time_w, sample_w, feat_mask,
                      client_mask, byz_modes, stale_params, edge_ids,
                      edge_mask, edge_modes, codec_prev),
-                static=(keep_client_params,)):
+                static=(keep_client_params, K)) as sp:
             if scan:
                 out = self._train_round_scan_jit(*args, **kwargs)
             else:
                 out = self._train_round_jit(*args, **kwargs)
+                pairs_run = (K or M) * C
+                obs.registry().counter("pairs_run").inc(pairs_run)
+                sp.set(pairs_run=pairs_run)
         return out if with_agg_stats else out[:5]
 
     @partial(jax.jit, static_argnums=0,
-             static_argnames=("keep_client_params",))
+             static_argnames=("keep_client_params", "models_per_client"))
     def _train_round_jit(self, params, opt_states, key, x, y, time_w,
                          sample_w, feat_mask, lr_scale, client_mask=None,
                          byz_modes=None, stale_params=None, edge_ids=None,
                          edge_mask=None, edge_modes=None, codec_prev=None, *,
-                         keep_client_params: bool = True):
+                         keep_client_params: bool = True,
+                         models_per_client: int | None = None):
         out = self._round_body(params, opt_states, key, x, y, time_w,
                                sample_w, feat_mask, lr_scale, client_mask,
                                byz_modes, stale_params, edge_ids, edge_mask,
-                               edge_modes, codec_prev)
+                               edge_modes, codec_prev, models_per_client)
         if keep_client_params:
             return out
         new_params, new_opt, _client_params, n, losses, agg_stats, cprev = out
@@ -732,6 +864,10 @@ class TrainStep:
         when ``self.codec == "delta"``.
         ``with_agg_stats`` additionally returns the per-round stats
         ([R, M, 3] flat, [R, 1 + E, M, 3] hierarchical).
+
+        The rounds run the dense body (M x C pairs) whatever the time
+        weights hold: ``train_round``'s ``models_per_client`` has no
+        counterpart here until a fused cell is measured (ROADMAP Reach B2).
         """
         args = (params, opt_states, iter_key, x, y, time_w, sample_w,
                 feat_mask, lr_scale, R, freq, t, client_masks, byz_modes,
@@ -894,7 +1030,9 @@ class TrainStep:
         to a K=1 dispatch at t0+j because the scan folds the same
         ``iteration_key(base_key, t0+j)`` and re-inits the optimizer states
         (and the stale-replay / delta-codec carries) from the same
-        value-independent seeds.
+        value-independent seeds. Like ``train_iteration_eval``, every round
+        runs the dense body: no ``models_per_client`` here yet (ROADMAP
+        Reach B2).
         """
         args = (params, base_key, x, y, time_ws, sample_w, feat_mask,
                 lr_scale, t0, R, freq, K, client_masks, byz_modes, edge_ids,

@@ -1293,7 +1293,8 @@ class Experiment:
                     self._byz_stale if (byz is not None and byz.has_stale)
                     else None,
                     eids, emasks, ebyz, self._codec_prev,
-                    keep_client_params=keep_cp, with_agg_stats=True)
+                    keep_client_params=keep_cp, with_agg_stats=True,
+                    models_per_client=self.algo.models_per_client)
                 if cfg.trace_sync:
                     # attribute the device time to this phase instead of
                     # letting async dispatch spill it into whichever call

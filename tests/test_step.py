@@ -154,3 +154,222 @@ class TestWeightedSamplingDistribution:
         nonzero = np.asarray(probs) > 0
         sigma = np.sqrt(expected[nonzero].clip(1))
         assert (np.abs(counts[nonzero] - expected[nonzero]) < 5 * sigma + 5).all()
+
+
+# ----------------------------------------------------------------------
+# the compact vmap body (ISSUE 30): K models a client against all M
+def _tree_close(a, b, **tol):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x_, y_ in zip(la, lb):
+        np.testing.assert_allclose(np.asarray(x_), np.asarray(y_), **tol)
+
+
+def _one_hot_tw(rows, M=3, T1=4, t=0):
+    """[M, C, T1] weights: client c trains the models ``rows[c]`` on step t."""
+    tw = np.zeros((M, len(rows), T1), np.float32)
+    for c, models in enumerate(rows):
+        for m in models:
+            tw[m, c, t] = 1.0
+    return tw
+
+
+def _train_round_spans():
+    from feddrift_tpu import obs
+    return [s for s in obs.spans.get_recorder().spans("dispatch")
+            if s["args"].get("fn") == "train_round"]
+
+
+@pytest.fixture()
+def spans_in_memory():
+    """The compact body's tests read ``dispatch`` spans: the process's
+    recorder is on for them, in memory, and off again afterwards as the
+    library leaves it."""
+    from feddrift_tpu.obs import spans
+    spans.configure(None)
+    yield
+    spans.configure(None)
+    spans.get_recorder().enabled = False
+
+
+@pytest.fixture(scope="module")
+def warmed():
+    """One TrainStep and one dense round behind it, so that every case
+    starts from optimizer states that differ by pair and shares the
+    compiled programs."""
+    cfg, ds, pool, step, x, y, opt, sw, fm = _setup(M=3, C=4)
+    tw = _one_hot_tw([(0, 1), (1,), (2,), (0, 2)])
+    params, opt, *_ = step.train_round(
+        pool.params, opt, jax.random.PRNGKey(5), x, y, jnp.asarray(tw), sw,
+        fm, jnp.float32(1.0), keep_client_params=False)
+    return step, params, opt, x, y, sw, fm
+
+
+# the assignment, the round's participants, the K the host would count
+COMPACT_CASES = {
+    "ifca_rows": ([(0,), (2,), (2,), (0,)], None, 1),
+    "one_and_two_live_models": ([(0, 2), (1,), (2,), (0, 1)], None, 2),
+    "client_mask_drops_a_client": ([(0,), (2,), (1,), (0,)], [1, 0, 1, 1], 1),
+    "phantom_padded_clients": ([(1,), (0,), (), ()], None, 1),
+    "a_model_nobody_trains": ([(0,), (0,), (2,), (2,)], None, 1),
+    "k_above_every_client's_count": ([(0,), (2,), (2,), (0,)], None, 2),
+}
+
+
+@pytest.mark.usefixtures("spans_in_memory")
+class TestCompactRound:
+    @pytest.mark.parametrize("case", COMPACT_CASES)
+    def test_compact_equals_dense(self, warmed, case):
+        step, params, opt, x, y, sw, fm = warmed
+        rows, mask, K = COMPACT_CASES[case]
+        tw = jnp.asarray(_one_hot_tw(rows))
+        cm = None if mask is None else jnp.asarray(mask, jnp.float32)
+        call = lambda **kw: step.train_round(          # noqa: E731
+            params, opt, jax.random.PRNGKey(11), x, y, tw, sw, fm,
+            jnp.float32(1.0), cm, keep_client_params=False,
+            with_agg_stats=True, **kw)
+        dense, compact = call(), call(models_per_client=K)
+        assert _train_round_spans()[-1]["args"]["pairs_run"] == K * 4
+        tol = dict(rtol=1e-5, atol=1e-7)
+        _tree_close(compact[0], dense[0], **tol)         # new_params
+        _tree_close(compact[1], dense[1], **tol)         # the [M, C] stack
+        assert compact[2] is None and compact[6] is None
+        n = np.asarray(dense[3])
+        np.testing.assert_array_equal(np.asarray(compact[3]), n)
+        trained = n > 0
+        assert trained.sum() == sum(
+            len(r) * (mask is None or mask[c]) for c, r in enumerate(rows))
+        np.testing.assert_allclose(np.asarray(compact[4])[trained],
+                                   np.asarray(dense[4])[trained], **tol)
+        np.testing.assert_array_equal(np.asarray(compact[5]),
+                                      np.asarray(dense[5]))
+        # a model no pair trained keeps its parameters, bit for bit, and a
+        # pair that did not train its optimizer state
+        for m in np.where(~trained.any(axis=1))[0]:
+            assert _leafdiff(jax.tree_util.tree_map(lambda l: l[m], compact[0]),
+                             jax.tree_util.tree_map(lambda l: l[m], params)) == 0
+        for got, was in zip(jax.tree_util.tree_leaves(compact[1]),
+                            jax.tree_util.tree_leaves(opt)):
+            np.testing.assert_array_equal(np.asarray(got)[~trained],
+                                          np.asarray(was)[~trained])
+
+    def test_same_k_other_assignment_is_one_signature(self, warmed):
+        from feddrift_tpu import obs
+        step, params, opt, x, y, sw, fm = warmed
+        reg = obs.registry()
+        key = 'jit_recompiles{fn="train_round"}'
+        outs = []
+        for i, rows in enumerate(([(0,), (1,), (2,), (0,)],
+                                  [(2,), (2,), (0,), (1,)])):
+            outs.append(step.train_round(
+                params, opt, jax.random.PRNGKey(3), x, y,
+                jnp.asarray(_one_hot_tw(rows)), sw, fm, jnp.float32(1.0),
+                keep_client_params=False, models_per_client=1))
+            if i == 0:
+                sigs = len(step._signatures["train_round"])
+                cached = TrainStep._train_round_jit._cache_size()
+                recompiles = reg.snapshot().get(key, 0)
+        assert len(step._signatures["train_round"]) == sigs
+        assert TrainStep._train_round_jit._cache_size() == cached
+        assert reg.snapshot().get(key, 0) == recompiles
+        assert _leafdiff(outs[0][0], outs[1][0]) > 0
+
+    def test_pairs_run_counts_k_times_c(self, warmed):
+        from feddrift_tpu import obs
+        step, params, opt, x, y, sw, fm = warmed
+        tw = jnp.asarray(_one_hot_tw([(0, 2), (1,), (2,), (0, 1)]))
+        reg = obs.registry()
+        for K, pairs in ((1, 4), (2, 8), (3, 12), (None, 12)):
+            before = reg.snapshot().get("pairs_run", 0)
+            step.train_round(params, opt, jax.random.PRNGKey(3), x, y, tw, sw,
+                             fm, jnp.float32(1.0), keep_client_params=False,
+                             models_per_client=K)
+            assert reg.snapshot().get("pairs_run", 0) - before == pairs
+            assert _train_round_spans()[-1]["args"]["pairs_run"] == pairs
+        with pytest.raises(ValueError, match="models_per_client"):
+            step.train_round(params, opt, jax.random.PRNGKey(3), x, y, tw, sw,
+                             fm, jnp.float32(1.0), keep_client_params=False,
+                             models_per_client=0)
+
+    # what of a call keeps the dense body: (TrainStep fields, call's
+    # operands by name, keep_client_params, K)
+    DENSE_CASES = {
+        "k_equals_m": ({}, {}, False, 3),
+        "no_k": ({}, {}, False, None),
+        "keep_client_params": ({}, {}, True, 1),
+        "robust_agg": ({"robust_agg": "median"}, {}, False, 1),
+        "dp_noise": ({"dp": 0.01}, {}, False, 1),
+        "codec": ({"codec": "int8"}, {}, False, 1),
+        "hier_edges": ({"hier_edges": 2}, {}, False, 1),
+        "byz_modes": ({}, {"byz_modes": "zeros"}, False, 1),
+        "stale_params": ({}, {"stale_params": "stack"}, False, 1),
+        "edge_operands": ({}, {"edge_ids": "zeros"}, False, 1),
+        "codec_prev": ({}, {"codec_prev": "stack"}, False, 1),
+        "weighted_sampling": ({"weighted_sampling": True}, {}, False, 1),
+    }
+
+    @pytest.mark.parametrize("case", DENSE_CASES)
+    def test_falls_back_to_the_dense_program(self, case, monkeypatch):
+        import dataclasses
+        from feddrift_tpu.resilience.robust_agg import RobustAggConfig
+        fields, operands, keep, K = self.DENSE_CASES[case]
+        cfg, ds, pool, step, x, y, opt, sw, fm = _setup(M=3, C=4)
+        fields = dict(fields)
+        if "dp" in fields:
+            fields["robust_cfg"] = RobustAggConfig(dp_stddev=fields.pop("dp"))
+        step = dataclasses.replace(step, _signatures={}, **fields)
+
+        def boom(*a, **kw):
+            raise AssertionError("the compact body was traced")
+        monkeypatch.setattr(TrainStep, "_round_compact", boom)
+        stack = jax.tree_util.tree_map(
+            lambda l: jnp.broadcast_to(l[:, None], (3, 4, *l.shape[1:])),
+            pool.params)
+        given = {name: stack if kind == "stack"
+                 else jnp.zeros((4,), jnp.int32)
+                 for name, kind in operands.items()}
+        tw = jnp.asarray(_one_hot_tw([(0,), (2,), (2,), (0,)]))
+        call = lambda **kw: step.train_round(          # noqa: E731
+            pool.params, opt, jax.random.PRNGKey(2), x, y, tw, sw, fm,
+            jnp.float32(1.0), keep_client_params=keep, **given, **kw)
+        today = call()
+        sigs = set(step._signatures["train_round"])
+        cached = TrainStep._train_round_jit._cache_size()
+        asked = call(models_per_client=K)
+        # the same program: no new signature, no new entry in jit's cache
+        assert step._signatures["train_round"] == sigs and len(sigs) == 1
+        assert TrainStep._train_round_jit._cache_size() == cached
+        assert _train_round_spans()[-1]["args"]["pairs_run"] == 3 * 4
+        assert _leafdiff(today[0], asked[0]) == 0
+
+    def test_compact_equals_dense_on_the_host_mesh(self):
+        """x, y and the optimizer stack split over a ``clients`` axis of 4
+        (the 8-device host platform of conftest.py), the pool replicated:
+        the gathers run along the replicated model axis."""
+        from feddrift_tpu.parallel.mesh import (make_mesh, replicate,
+                                                shard_client_arrays)
+        mesh = make_mesh(num_devices=4)
+        cfg, ds, pool, step, x, y, opt, sw, fm = _setup(M=3, C=8)
+        step.mesh = mesh
+        x, y = shard_client_arrays(mesh, (x, y))
+        opt = shard_client_arrays(mesh, opt, client_axis=1)
+        params, sw, fm = replicate(mesh, (pool.params, sw, fm))
+        tw = replicate(mesh, jnp.asarray(_one_hot_tw(
+            [(0, 2), (1,), (2,), (0,), (1,), (1, 2), (), (0,)])))
+        call = lambda **kw: step.train_round(          # noqa: E731
+            params, opt, jax.random.PRNGKey(4), x, y, tw, sw, fm,
+            jnp.float32(1.0), keep_client_params=False, with_agg_stats=True,
+            **kw)
+        dense, compact = call(), call(models_per_client=2)
+        assert _train_round_spans()[-1]["args"]["pairs_run"] == 16
+        tol = dict(rtol=1e-5, atol=1e-7)
+        _tree_close(compact[0], dense[0], **tol)
+        _tree_close(compact[1], dense[1], **tol)
+        np.testing.assert_array_equal(np.asarray(compact[3]),
+                                      np.asarray(dense[3]))
+        np.testing.assert_array_equal(np.asarray(compact[5]),
+                                      np.asarray(dense[5]))
+        # the stack comes back split over the clients as it went in
+        for got, was in zip(jax.tree_util.tree_leaves(compact[1]),
+                            jax.tree_util.tree_leaves(dense[1])):
+            assert got.sharding == was.sharding
