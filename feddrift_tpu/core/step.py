@@ -57,7 +57,7 @@ import math
 import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +74,34 @@ from feddrift_tpu.platform.hierarchical import two_tier_aggregate
 from feddrift_tpu.resilience.robust_agg import (RobustAggConfig, _stats,
                                                  aggregate)
 from feddrift_tpu.utils.prng import iteration_key
+
+
+class StackOperands(NamedTuple):
+    """The operands of a round that need the [M, C, ...] parameter stack or
+    `aggregate`'s own closing of the round: one pytree, a None field an
+    empty subtree, as a None argument is. ``train_round`` takes a round's
+    rows; the fused entries take them with a leading [R] (megastep: [K, R])
+    axis and keep the two carries, ``stale_params`` and ``codec_prev``,
+    inside their scan (None here).
+
+    byz_modes [C] int32 (platform/faults.BYZ_MODES, 0 = honest): adversary
+    injection. label_flip corrupts the training labels before local SGD,
+    every other mode the submitted update stack after it, BEFORE
+    aggregation, so the server-side defense sees what a malicious client
+    would send. stale_params [M, C, ...]: each client's previous-round
+    submission, needed only when stale_replay can occur.
+    edge_ids [C] int32 / edge_mask [E] / edge_modes [E]: the two-tier
+    hierarchy (platform/hierarchical.py::two_tier_aggregate), read only
+    when ``hier_edges > 0``. codec_prev [M, C, ...]: last round's decoded
+    diff stack, the delta codec's carry (None -> zeros: round 0 deltas
+    against the broadcast params).
+    """
+    byz_modes: object = None
+    stale_params: object = None
+    edge_ids: object = None
+    edge_mask: object = None
+    edge_modes: object = None
+    codec_prev: object = None
 
 
 def weight_cdf(weights: jnp.ndarray) -> jnp.ndarray:
@@ -387,9 +415,8 @@ class TrainStep:
 
     # ------------------------------------------------------------------
     def _round_body(self, params, opt_states, key, x, y, time_w, sample_w,
-                    feat_mask, lr_scale, client_mask=None, byz_modes=None,
-                    stale_params=None, edge_ids=None, edge_mask=None,
-                    edge_modes=None, codec_prev=None, slots=None):
+                    feat_mask, lr_scale, client_mask=None,
+                    operands=StackOperands(), slots=None):
         """One communication round (untraced body shared by train_round and
         the fused train_iteration_eval scan).
 
@@ -397,32 +424,21 @@ class TrainStep:
         client_sampling, AggregatorSoftCluster.py:197-205). Non-sampled
         clients train masked (total weight 0 -> params/opt untouched, n=0)
         and drop out of the aggregation, like the reference's absent ranks.
-
-        byz_modes [C] int32 (platform/faults.BYZ_MODES, 0 = honest):
-        adversary injection — label_flip corrupts the training labels
-        before local SGD, every other mode corrupts the submitted update
-        stack after it, BEFORE aggregation, so the server-side defense
-        (self.robust_agg) sees exactly what a malicious client would send.
-        stale_params: each client's previous-round submission ([M, C, ...]),
-        needed only when stale_replay can occur.
-
-        edge_ids [C] int32 / edge_mask [E] / edge_modes [E]: the two-tier
-        hierarchy operands (platform/hierarchical.py::two_tier_aggregate),
-        used only when ``self.hier_edges > 0``. codec_prev [M, C, ...]:
-        last round's decoded diff stack, the delta codec's carry (None ->
-        zeros: round 0 deltas against the broadcast params).
+        operands: one round's `StackOperands`.
 
         slots (static K, 1 <= K < M): local SGD runs for K models a client
         and not for M (`_round_compact`); given by ``train_round`` alone,
         and only where nothing of the call needs the [M, C, ...] parameter
-        stack (`_stack_users`), so with none of the operands above but
-        ``client_mask``. ``client_params`` is then None.
+        stack (`_stack_users`), so with no operand but ``client_mask``.
+        ``client_params`` is then None.
 
         Returns ``(new_params, new_opt, client_params, n, losses,
         agg_stats, new_codec_prev)`` — agg_stats is [M, 3] on the flat
         path and [1 + E, M, 3] (server tier in row 0) on the hierarchy
         path; new_codec_prev is None unless codec == "delta".
         """
+        (byz_modes, stale_params, edge_ids, edge_mask, edge_modes,
+         codec_prev) = operands
         if client_mask is not None:
             time_w = time_w * client_mask[None, :, None]
         if byz_modes is not None:
@@ -585,12 +601,12 @@ class TrainStep:
         agg_stats = _stats((n > 0).sum(axis=1).astype(jnp.int32))
         return new_params, new_opt, None, n, losses, agg_stats, None
 
-    def _stack_users(self, keep_client_params, *stack_args) -> list[str]:
+    def _stack_users(self, keep_client_params, operands) -> list[str]:
         """What of a round's call needs the [M, C, ...] parameter stack or
         `aggregate`'s own closing of the round, by the name of the field
         that asks for it. The scanned body refuses these
         (`_refuse_stack_users`); the compact vmap body was measured with
-        none of them, and ``train_round`` runs the dense body where there
+        none of them, and `_round_program` runs the dense body where there
         is one."""
         return [name for name, on in (
             ("keep_client_params=True", keep_client_params),
@@ -598,14 +614,14 @@ class TrainStep:
             ("robust_cfg.dp_stddev > 0", self.robust_cfg.dp_stddev > 0.0),
             (f"codec={self.codec!r}", self.codec != "none"),
             ("hier_edges > 0", self.hier_edges > 0),
-            ("byz_modes / stale_params / edge operands / codec_prev",
-             any(a is not None for a in stack_args)),
+            *((f"the operand {name}", given is not None)
+              for name, given in zip(operands._fields, operands)),
             ("weighted_sampling", self.weighted_sampling)) if on]
 
-    def _refuse_stack_users(self, opt_states, keep_client_params, *stack_args):
+    def _refuse_stack_users(self, opt_states, keep_client_params, operands):
         """``client_axis="scan"`` keeps no [M, C, ...] stack; whatever needs
         one is refused by the name of the field that asks for it."""
-        asked = self._stack_users(keep_client_params, *stack_args)
+        asked = self._stack_users(keep_client_params, operands)
         if jax.tree_util.tree_leaves(opt_states):
             asked.insert(0, "an optimizer with state (client_optimizer="
                             "'adam'): only an optimizer without state "
@@ -617,6 +633,35 @@ class TrainStep:
                 f"stack, which {'; '.join(asked)} "
                 f"need{'s' if len(asked) == 1 else ''}: use "
                 "client_axis='vmap'")
+
+    @property
+    def donates_pool(self) -> bool:
+        """Whether ``train_round`` writes the new pool over the ``params`` it
+        is given, which nothing may read after the call: true exactly where
+        the scanned program runs (`_train_round_scan_jit` donates them)."""
+        return self.client_axis == "scan"
+
+    @property
+    def fuses_rounds(self) -> bool:
+        """Whether the fused programs (``train_iteration_eval``,
+        ``train_megastep``) exist for this step: their body is vmap's."""
+        return self.client_axis != "scan"
+
+    def _round_program(self, num_models: int, models_per_client,
+                       keep_client_params: bool, operands):
+        """The jitted program ``train_round`` dispatches and its static K:
+        the scanned one, or vmap's, compact where the caller counted
+        1 <= K < M and the call has none of `_stack_users`, else dense (K
+        None)."""
+        K = models_per_client
+        if K is not None and K < 1:
+            raise ValueError(f"models_per_client must be at least 1, got {K}")
+        if self.donates_pool:
+            return type(self)._train_round_scan_jit, None
+        if K is None or K >= num_models or self._stack_users(
+                keep_client_params, operands):
+            K = None
+        return type(self)._train_round_jit, K
 
     def _round_body_scan(self, params, opt_states, key, x, y, time_w,
                          sample_w, feat_mask, lr_scale, client_mask=None):
@@ -728,9 +773,8 @@ class TrainStep:
                 counts)
 
     def train_round(self, params, opt_states, key, x, y, time_w, sample_w,
-                    feat_mask, lr_scale, client_mask=None, byz_modes=None,
-                    stale_params=None, edge_ids=None, edge_mask=None,
-                    edge_modes=None, codec_prev=None, *,
+                    feat_mask, lr_scale, client_mask=None,
+                    operands=StackOperands(), *,
                     keep_client_params: bool = True,
                     with_agg_stats: bool = False,
                     models_per_client: int | None = None):
@@ -765,31 +809,20 @@ class TrainStep:
         (``pairs_trained`` and the model's own).
         """
         args = (params, opt_states, key, x, y, time_w, sample_w, feat_mask,
-                lr_scale, client_mask, byz_modes, stale_params, edge_ids,
-                edge_mask, edge_modes, codec_prev)
-        kwargs = {"keep_client_params": keep_client_params}
-        scan = self.client_axis == "scan"
+                lr_scale, client_mask, operands)
         M, C = time_w.shape[:2]
-        K = models_per_client
-        if K is not None and K < 1:
-            raise ValueError(f"models_per_client must be at least 1, got {K}")
-        if scan or K is None or K >= M or self._stack_users(
-                keep_client_params, byz_modes, stale_params, edge_ids,
-                edge_mask, edge_modes, codec_prev):
-            K = None
-        else:
+        program, K = self._round_program(M, models_per_client,
+                                         keep_client_params, operands)
+        kwargs = {"keep_client_params": keep_client_params}
+        if K is not None:
             kwargs["models_per_client"] = K
         with self._tracked(
-                "train_round", type(self)._train_round_scan_jit if scan
-                else type(self)._train_round_jit, args, kwargs,
+                "train_round", program, args, kwargs,
                 sig=(params, opt_states, x, y, time_w, sample_w, feat_mask,
-                     client_mask, byz_modes, stale_params, edge_ids,
-                     edge_mask, edge_modes, codec_prev),
+                     client_mask, operands),
                 static=(keep_client_params, K)) as sp:
-            if scan:
-                out = self._train_round_scan_jit(*args, **kwargs)
-            else:
-                out = self._train_round_jit(*args, **kwargs)
+            out = program(self, *args, **kwargs)
+            if not self.donates_pool:
                 pairs_run = (K or M) * C
                 obs.registry().counter("pairs_run").inc(pairs_run)
                 sp.set(pairs_run=pairs_run)
@@ -799,18 +832,13 @@ class TrainStep:
              static_argnames=("keep_client_params", "models_per_client"))
     def _train_round_jit(self, params, opt_states, key, x, y, time_w,
                          sample_w, feat_mask, lr_scale, client_mask=None,
-                         byz_modes=None, stale_params=None, edge_ids=None,
-                         edge_mask=None, edge_modes=None, codec_prev=None, *,
+                         operands=StackOperands(), *,
                          keep_client_params: bool = True,
                          models_per_client: int | None = None):
         out = self._round_body(params, opt_states, key, x, y, time_w,
                                sample_w, feat_mask, lr_scale, client_mask,
-                               byz_modes, stale_params, edge_ids, edge_mask,
-                               edge_modes, codec_prev, models_per_client)
-        if keep_client_params:
-            return out
-        new_params, new_opt, _client_params, n, losses, agg_stats, cprev = out
-        return new_params, new_opt, None, n, losses, agg_stats, cprev
+                               operands, models_per_client)
+        return out if keep_client_params else (*out[:2], None, *out[3:])
 
     # the scanned round writes the new pool over the old one: the pool is
     # DONATED (argnum 1), and the caller's ``params`` do not outlive the call
@@ -818,13 +846,9 @@ class TrainStep:
              static_argnames=("keep_client_params",))
     def _train_round_scan_jit(self, params, opt_states, key, x, y, time_w,
                               sample_w, feat_mask, lr_scale, client_mask=None,
-                              byz_modes=None, stale_params=None,
-                              edge_ids=None, edge_mask=None, edge_modes=None,
-                              codec_prev=None, *,
+                              operands=StackOperands(), *,
                               keep_client_params: bool = True):
-        self._refuse_stack_users(
-            opt_states, keep_client_params, byz_modes, stale_params,
-            edge_ids, edge_mask, edge_modes, codec_prev)
+        self._refuse_stack_users(opt_states, keep_client_params, operands)
         return self._round_body_scan(
             params, opt_states, key, x, y, time_w, sample_w, feat_mask,
             lr_scale, client_mask)
@@ -840,12 +864,20 @@ class TrainStep:
 
     def train_iteration_eval(self, params, opt_states, iter_key, x, y, time_w,
                              sample_w, feat_mask, lr_scale, R: int, freq: int,
-                             t, client_masks=None, byz_modes=None,
-                             edge_ids=None, edge_masks=None, edge_byz=None, *,
-                             byz_stale: bool = False,
+                             t, client_masks=None, operands=StackOperands(),
+                             *, byz_stale: bool = False,
                              with_agg_stats: bool = False):
         """ALL R communication rounds of a time step + every scheduled eval
-        as ONE device program (dispatches ``_train_iteration_eval_jit``).
+        as ONE device program (dispatches ``_train_iteration_eval_jit``):
+        one host->device->host round trip a time step where the per-round
+        driver makes one a round and one an eval. For small models the
+        per-call latency, not the device, bounds wall-clock, as the
+        reference's 0.3 s comm polls did (SURVEY.md §7). The runner enters
+        it for a chunkable algorithm (DriftAlgorithm.chunkable) with a
+        non-ensemble test path. Trajectories are bitwise-identical to the
+        per-round driver's: round r folds the same fold_in(iter_key, r)
+        key, and eval matrices are computed on the params right after each
+        eval round.
 
         Argument signatures are tracked per donated-buffer layout: this is
         the donating program (params/opt_states, argnums 1-2), where an
@@ -853,32 +885,33 @@ class TrainStep:
         donated buffers' HBM — exactly the recompile the event stream must
         surface.
 
-        byz_modes [R, C]: per-round adversary schedule
-        (ByzantineInjector.schedule). ``byz_stale=True`` makes the scan
-        carry every client's previous submission so stale_replay attacks
-        replay it (costs one extra [M, C, ...] buffer in the carry).
-        edge_ids [R, C] / edge_masks [R, E] / edge_byz [R, E]: per-round
-        hierarchy operands (edge ids vary across rounds only after a
-        re-home; faults are precomputed host-side like byz_modes). The
-        delta codec's decoded-diff carry rides the scan automatically
-        when ``self.codec == "delta"``.
-        ``with_agg_stats`` additionally returns the per-round stats
-        ([R, M, 3] flat, [R, 1 + E, M, 3] hierarchical).
+        client_masks [R, C] and operands (`StackOperands` with a leading
+        [R] axis): the rounds' rows, made on the host before the step
+        (ByzantineInjector.schedule; edge ids vary across rounds only after
+        a re-home). The two carries stay None: ``byz_stale=True`` makes the
+        scan carry every client's previous submission so stale_replay
+        attacks replay it (one extra [M, C, ...] buffer in the carry), and
+        the delta codec's decoded-diff carry rides the scan when
+        ``self.codec == "delta"``.
+
+        Returns (params, opt_states, n [M, C], losses [M, C],
+        (corr_tr, loss_tr, corr_te, loss_te) each [E, M, C], total [C])
+        where E = len(eval_rounds(R, freq)) and, with ``with_agg_stats``,
+        the per-round stats ([R, M, 3] flat, [R, 1 + E, M, 3] hierarchical).
 
         The rounds run the dense body (M x C pairs) whatever the time
-        weights hold: ``train_round``'s ``models_per_client`` has no
-        counterpart here until a fused cell is measured (ROADMAP Reach B2).
+        weights hold. A compact fused round (ROADMAP Reach B2) is a static K
+        handed from here to `_iteration_body`'s `_round_body` call, chosen
+        as `_round_program` chooses ``train_round``'s.
         """
         args = (params, opt_states, iter_key, x, y, time_w, sample_w,
-                feat_mask, lr_scale, R, freq, t, client_masks, byz_modes,
-                edge_ids, edge_masks, edge_byz)
+                feat_mask, lr_scale, R, freq, t, client_masks, operands)
         kwargs = {"byz_stale": byz_stale}
         with self._tracked(
                 "train_iteration_eval",
                 type(self)._train_iteration_eval_jit, args, kwargs,
                 sig=(params, opt_states, x, y, time_w, sample_w, feat_mask,
-                     client_masks, byz_modes, edge_ids, edge_masks,
-                     edge_byz),
+                     client_masks, operands),
                 static=(R, freq, byz_stale)):
             out = self._train_iteration_eval_jit(*args, **kwargs)
         return out if with_agg_stats else out[:6]
@@ -888,35 +921,17 @@ class TrainStep:
     def _train_iteration_eval_jit(self, params, opt_states, iter_key, x, y,
                                   time_w, sample_w, feat_mask, lr_scale,
                                   R: int, freq: int, t, client_masks=None,
-                                  byz_modes=None, edge_ids=None,
-                                  edge_masks=None, edge_byz=None, *,
+                                  operands=StackOperands(), *,
                                   byz_stale: bool = False):
-        """ALL R communication rounds of a time step + every scheduled eval
-        as ONE device program.
-
-        Collapses the per-chunk dispatch of train_rounds_eval into a single
-        host->device->host round trip per time step: for small models the
-        per-call latency, not the device, bounds wall-clock, as the
-        reference's 0.3 s comm polls did (SURVEY.md §7). Valid under the same
-        conditions as train_rounds_eval (DriftAlgorithm.chunkable) plus a
-        non-ensemble test path. Trajectories are bitwise-identical to the
-        per-round and per-chunk paths: round r folds the same
-        fold_in(iter_key, r) key, and eval matrices are computed on the params
-        right after each eval round.
-
-        Returns (params, opt_states, n [M, C], losses [M, C],
-        (corr_tr, loss_tr, corr_te, loss_te) each [E, M, C], total [C],
-        agg_stats [R, M, 3]) where E = len(eval_rounds(R, freq)).
-        """
+        """The program of ``train_iteration_eval``, documented there."""
         return self._iteration_body(
             params, opt_states, iter_key, x, y, time_w, sample_w, feat_mask,
-            lr_scale, R, freq, t, client_masks, byz_modes, edge_ids,
-            edge_masks, edge_byz, byz_stale=byz_stale)
+            lr_scale, R, freq, t, client_masks, operands,
+            byz_stale=byz_stale)
 
     def _iteration_body(self, params, opt_states, iter_key, x, y, time_w,
                         sample_w, feat_mask, lr_scale, R: int, freq: int, t,
-                        client_masks=None, byz_modes=None, edge_ids=None,
-                        edge_masks=None, edge_byz=None, *,
+                        client_masks=None, operands=StackOperands(), *,
                         byz_stale: bool = False):
         """Untraced body of ``_train_iteration_eval_jit``, shared with the
         multi-iteration ``_train_megastep_jit`` outer scan — extracting it
@@ -941,18 +956,13 @@ class TrainStep:
                      jnp.zeros((M, C), jnp.int32), jnp.zeros((M, C), ev_dt))
 
         def one(carry, rx):
-            r, cm, bz, eid, em, eb = rx
-            p, o, bufs = carry[:3]
-            rest = carry[3:]
-            stale = cprev = None
-            if byz_stale:
-                stale, rest = rest[0], rest[1:]
-            if self.codec == "delta":
-                cprev = rest[0]
+            r, cm, ops = rx
+            # the two carried operands: None (no leaf) where not kept
+            p, o, bufs, stale, cprev = carry
             key = jax.random.fold_in(iter_key, r)
-            p, o, cp, n, losses, agg_stats, cprev_new = self._round_body(
+            p, o, cp, n, losses, agg_stats, cprev = self._round_body(
                 p, o, key, x, y, time_w, sample_w, feat_mask, lr_scale, cm,
-                bz, stale, eid, em, eb, cprev)
+                ops._replace(stale_params=stale, codec_prev=cprev))
 
             is_eval = ((r % freq) == 0) | (r == R - 1)
             slot = jnp.where(r == R - 1, E - 1, r // freq)
@@ -969,34 +979,26 @@ class TrainStep:
                           jax.lax.dynamic_update_index_in_dim(b, m, slot, 0),
                           b)
                 for b, m in zip(bufs, mats))
-            out_carry = (p, o, bufs)
-            if byz_stale:
-                out_carry = out_carry + (cp,)
-            if self.codec == "delta":
-                out_carry = out_carry + (cprev_new,)
-            return out_carry, (n, losses, agg_stats)
+            return ((p, o, bufs, cp if byz_stale else None, cprev),
+                    (n, losses, agg_stats))
 
         bufs0 = tuple(jnp.zeros((E, M, C), d) for d in
                       (jnp.int32, ev_dt, jnp.int32, ev_dt))
-        carry0 = (params, opt_states, bufs0)
+        stale0 = cprev0 = None
         if byz_stale:
             # round 0's stale replay degenerates to "re-send the broadcast
             # params" (a zero update) — there is no earlier submission
             stale0 = jax.tree_util.tree_map(
                 lambda l: jnp.broadcast_to(
                     l[:, None], (l.shape[0], C, *l.shape[1:])), params)
-            carry0 = carry0 + (stale0,)
         if self.codec == "delta":
             # round 0 deltas against the broadcast params (zero history)
             cprev0 = jax.tree_util.tree_map(
                 lambda l: jnp.zeros((l.shape[0], C, *l.shape[1:]), l.dtype),
                 params)
-            carry0 = carry0 + (cprev0,)
-        carry, (ns, ls, stats) = jax.lax.scan(
-            one, carry0,
-            (jnp.arange(R, dtype=jnp.int32), client_masks, byz_modes,
-             edge_ids, edge_masks, edge_byz))
-        params, opt_states, bufs = carry[0], carry[1], carry[2]
+        (params, opt_states, bufs, _, _), (ns, ls, stats) = jax.lax.scan(
+            one, (params, opt_states, bufs0, stale0, cprev0),
+            (jnp.arange(R, dtype=jnp.int32), client_masks, operands))
         total = jnp.full((C,), x.shape[2] * math.prod(y.shape[3:]),
                          dtype=jnp.int32)
         return params, opt_states, ns[-1], ls[-1], bufs, total, stats
@@ -1004,20 +1006,20 @@ class TrainStep:
     # ------------------------------------------------------------------
     def train_megastep(self, params, base_key, x, y, time_ws, sample_w,
                        feat_mask, lr_scale, t0, R: int, freq: int, K: int,
-                       client_masks=None, byz_modes=None, edge_ids=None,
-                       edge_masks=None, edge_byz=None, x_steps=None,
-                       y_steps=None, *, byz_stale: bool = False):
+                       client_masks=None, operands=StackOperands(),
+                       x_steps=None, y_steps=None, *,
+                       byz_stale: bool = False):
         """K whole time steps (each an R-round fused scan with scheduled
         evals) as ONE device program (dispatches ``_train_megastep_jit``).
 
         time_ws: [K, M, C, T1] — the per-step time weights the algorithm
         decided host-side BEFORE the block (the megastep contract: no drift
         decision may depend on results inside the block, which is what
-        ``DriftAlgorithm.megastep_horizon`` certifies). client_masks:
-        [K, R, C] or None; byz_modes [K, R, C], edge_ids [K, R, C],
-        edge_masks [K, R, E], edge_byz [K, R, E] are the per-step fault /
-        hierarchy schedules (None when the feature is off) — each step's
-        row feeds ``_iteration_body`` exactly as the K=1 fused path would.
+        ``DriftAlgorithm.megastep_horizon`` certifies). client_masks
+        [K, R, C] or None and operands (`StackOperands` with leading
+        [K, R] axes, a field None when the feature is off) are the per-step
+        fault / hierarchy schedules — each step's row feeds
+        ``_iteration_body`` exactly as the K=1 fused path would.
         Population cohorts pass ``x=y=None`` and the stacked per-step
         gathers as ``x_steps/y_steps`` [K, C, T1, N, ...] instead — the
         scan re-binds each step's cohort shard the way the host re-binds
@@ -1030,20 +1032,18 @@ class TrainStep:
         to a K=1 dispatch at t0+j because the scan folds the same
         ``iteration_key(base_key, t0+j)`` and re-inits the optimizer states
         (and the stale-replay / delta-codec carries) from the same
-        value-independent seeds. Like ``train_iteration_eval``, every round
-        runs the dense body: no ``models_per_client`` here yet (ROADMAP
-        Reach B2).
+        value-independent seeds. Like ``train_iteration_eval``, whose body
+        it scans, every round runs the dense body.
         """
         args = (params, base_key, x, y, time_ws, sample_w, feat_mask,
-                lr_scale, t0, R, freq, K, client_masks, byz_modes, edge_ids,
-                edge_masks, edge_byz, x_steps, y_steps)
+                lr_scale, t0, R, freq, K, client_masks, operands, x_steps,
+                y_steps)
         kwargs = {"byz_stale": byz_stale}
         with self._tracked(
                 "train_megastep", type(self)._train_megastep_jit, args,
                 kwargs,
                 sig=(params, x, y, time_ws, sample_w, feat_mask,
-                     client_masks, byz_modes, edge_ids, edge_masks, edge_byz,
-                     x_steps, y_steps),
+                     client_masks, operands, x_steps, y_steps),
                 static=(R, freq, K, byz_stale)):
             return self._train_megastep_jit(*args, **kwargs)
 
@@ -1054,10 +1054,9 @@ class TrainStep:
              static_argnames=("byz_stale",))
     def _train_megastep_jit(self, params, base_key, x, y, time_ws, sample_w,
                             feat_mask, lr_scale, t0, R: int, freq: int,
-                            K: int, client_masks=None, byz_modes=None,
-                            edge_ids=None, edge_masks=None, edge_byz=None,
-                            x_steps=None, y_steps=None, *,
-                            byz_stale: bool = False):
+                            K: int, client_masks=None,
+                            operands=StackOperands(), x_steps=None,
+                            y_steps=None, *, byz_stale: bool = False):
         """Outer scan over K time steps, each one `_iteration_body` call.
 
         The host round-trip this kills: the K=1 driver fetches params,
@@ -1081,7 +1080,7 @@ class TrainStep:
         C = x.shape[0] if x is not None else x_steps.shape[1]
 
         def one_step(p, xs):
-            k, tw_k, cm_k, bz_k, eid_k, em_k, eb_k, x_k, y_k = xs
+            k, tw_k, cm_k, ops_k, x_k, y_k = xs
             # population mode: each step trains on ITS cohort's gathered
             # shard; the time index inside the shard is still t (gathers
             # keep the full [T1] axis, only the client axis is re-drawn)
@@ -1098,16 +1097,15 @@ class TrainStep:
             # reset the host driver performs (_byz_stale/_codec_prev = None)
             p, _o, n, losses, bufs, total, stats = self._iteration_body(
                 p, o0, it_key, xx, yy, tw_k, sample_w, feat_mask, lr_scale,
-                R, freq, t, cm_k, bz_k, eid_k, em_k, eb_k,
-                byz_stale=byz_stale)
+                R, freq, t, cm_k, ops_k, byz_stale=byz_stale)
             p = constrain_pool(self.mesh, p, model_axis=0)
             return p, (p, n, losses, bufs, total, stats)
 
         params = constrain_pool(self.mesh, params, model_axis=0)
         _, (ps, ns, ls, bufs, tots, stats) = jax.lax.scan(
             one_step, params,
-            (jnp.arange(K, dtype=jnp.int32), time_ws, client_masks,
-             byz_modes, edge_ids, edge_masks, edge_byz, x_steps, y_steps))
+            (jnp.arange(K, dtype=jnp.int32), time_ws, client_masks, operands,
+             x_steps, y_steps))
         # eval totals are a pure function of (x, feat_mask) — constant over
         # the block, so return one step's [C] row, same shape as K=1
         return ps, ns, ls, bufs, tots[0], stats
@@ -1336,7 +1334,7 @@ class ForwardStep:
         rows = jax.tree_util.tree_map(lambda p: p[model_idx], params)
 
         def one(p_r, x_r):
-            # [1, ...] -> [1, K]: same batched apply the eval programs use,
-            # so a B=1 bucket is bitwise-identical to a direct pool.apply
+            # [1, ...] -> [1, K]: the batched apply the eval programs use. On
+            # the CPU a bucket of 2+ is bitwise pool.apply, of 1 within an ulp
             return self.apply_fn(p_r, x_r[None])[0]
         return jax.vmap(one)(rows, x)

@@ -36,7 +36,7 @@ from feddrift_tpu.comm import multihost
 from feddrift_tpu.config import ExperimentConfig
 from feddrift_tpu.core.pool import ModelPool
 from feddrift_tpu.core.precision import resolve_precision
-from feddrift_tpu.core.step import TrainStep, make_optimizer
+from feddrift_tpu.core.step import StackOperands, TrainStep, make_optimizer
 from feddrift_tpu.data.registry import make_dataset
 from feddrift_tpu.models import create_model
 from feddrift_tpu.parallel.mesh import (
@@ -923,7 +923,7 @@ class Experiment:
                                  "with a non-ensemble test path")
             self._run_iteration_fused(t, opt_states, stream=True)
         elif (cfg.chunk_rounds and self.secure_driver is None
-                and cfg.client_axis != "scan"   # the fused body is vmap's
+                and self.step.fuses_rounds
                 and self.algo.chunkable(t)
                 and self.algo.ensemble_spec(t) is None):
             self._run_iteration_fused(t, opt_states)
@@ -1106,7 +1106,7 @@ class Experiment:
                 np.asarray(losses), np.asarray(n))
         if not diverged:
             return False
-        if reason == "loss_spike" and self.cfg.client_axis == "scan":
+        if reason == "loss_spike" and self.step.donates_pool:
             from feddrift_tpu.resilience.divergence import DivergenceError
             raise DivergenceError(
                 f"loss spike ({observed:.4g}) at round {self.global_round} "
@@ -1242,26 +1242,55 @@ class Experiment:
             self.edge_map.rehome(inj.dead, gr)   # effective next round
         return ids, masks, byz
 
+    def _round_rows(self, t: int, rounds):
+        """What the host makes for ``rounds`` of step ``t``, as host arrays
+        with a leading [len(rounds)] axis: the client masks and the
+        `StackOperands` (a field None where its feature is off; the two
+        carries are not rows). The one order in which the three drivers
+        draw them: masks, attack schedule, edge plan."""
+        cms = self._client_masks(t, rounds)
+        bms = self._byz_modes(rounds, t)
+        eids = emasks = ebyz = None
+        if self.hierarchy:
+            eids, emasks, ebyz = self._edge_state(t, rounds)
+        return cms, StackOperands(byz_modes=bms, edge_ids=eids,
+                                  edge_mask=emasks, edge_modes=ebyz)
+
+    @property
+    def _keeps_stale(self) -> bool:
+        return self.byzantine is not None and self.byzantine.has_stale
+
+    def _seed_round_carries(self) -> None:
+        """The per-round driver's two carries (the fused programs keep
+        theirs inside the scan), seeded so that a time step's first round
+        meets the later rounds' jit signature: "no update" submissions for
+        stale_replay, zero baseline diffs for the delta codec."""
+        C = self.C_pad
+        if self._keeps_stale and self._byz_stale is None:
+            self._byz_stale = jax.tree_util.tree_map(
+                lambda l: jnp.broadcast_to(
+                    l[:, None], (l.shape[0], C, *l.shape[1:])),
+                self.pool.params)
+        if self.step.codec == "delta" and self._codec_prev is None:
+            self._codec_prev = jax.tree_util.tree_map(
+                lambda l: jnp.zeros((l.shape[0], C, *l.shape[1:]), l.dtype),
+                self.pool.params)
+
+    def _keep_round_carries(self, client_params, codec_prev) -> None:
+        if self._keeps_stale:
+            self._byz_stale = client_params
+        if self.step.codec == "delta":
+            self._codec_prev = codec_prev
+
     def _run_rounds(self, t: int, opt_states) -> None:
         """Per-round host loop: algorithms that steer every round."""
         cfg = self.cfg
-        byz = self.byzantine
-        if byz is not None and byz.has_stale and self._byz_stale is None:
-            # seed the replay buffer with "no update" submissions so the
-            # first round's jit signature matches the later rounds'
-            self._byz_stale = jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(
-                    l[:, None], (l.shape[0], self.C_pad, *l.shape[1:])),
-                self.pool.params)
-        if self.step.codec == "delta" and self._codec_prev is None:
-            # zero baseline diffs so round 0 shares the rounds' jit signature
-            self._codec_prev = jax.tree_util.tree_map(
-                lambda l: jnp.zeros((l.shape[0], self.C_pad, *l.shape[1:]),
-                                    l.dtype),
-                self.pool.params)
-        keep_cp = self.algo.needs_client_params or (
-            byz is not None and byz.has_stale) or (
-            self.secure_driver is not None)
+        self._seed_round_carries()
+        keep_cp = (self.algo.needs_client_params or self._keeps_stale
+                   or self.secure_driver is not None)
+        # the scanned round is given the pool to write over (donated):
+        # after the call nothing of ``prev_params`` may be read
+        pool_donated = self.step.donates_pool
         # lint: hot-path-begin (per-round dispatch loop — every host sync
         # here serializes all comm_round dispatches)
         for r in range(cfg.comm_round):
@@ -1270,29 +1299,19 @@ class Experiment:
                 tw, sw, fm, lr_scale = self.algo.round_inputs(t, r)
                 tw = self._pad_clients(tw)              # phantom clients: w=0
                 sw = self._pad_clients(sw, value=1.0)
-                cm = self._client_masks(t, [r])
-                bm = self._byz_modes([r], t)
-                eids = emasks = ebyz = None
-                if self.hierarchy:
-                    eids, emasks, ebyz = self._edge_state(t, [r])
                 # the round's key and device copies of its host-made rows
                 # (eager dispatches, each tens of microseconds)
                 rkey = round_key(self.key, t, r)
-                cm, bm, eids, emasks, ebyz = (
-                    None if a is None else jnp.asarray(a[0])
-                    for a in (cm, bm, eids, emasks, ebyz))
+                cm, operands = jax.tree_util.tree_map(
+                    lambda a: jnp.asarray(a[0]), self._round_rows(t, [r]))
+                operands = operands._replace(stale_params=self._byz_stale,
+                                             codec_prev=self._codec_prev)
             prev_params = self.pool.params
-            # the scanned round is given the pool to write over (donated):
-            # after the call nothing of ``prev_params`` may be read
-            pool_donated = cfg.client_axis == "scan"
             with self.tracer.phase("train_round"):
                 (new_params, opt_states, client_params, n, losses, agg_stats,
                  codec_prev, *counts) = self.step.train_round(
                     prev_params, opt_states, rkey, self.x, self.y, tw, sw,
-                    fm, lr_scale, cm, bm,
-                    self._byz_stale if (byz is not None and byz.has_stale)
-                    else None,
-                    eids, emasks, ebyz, self._codec_prev,
+                    fm, lr_scale, cm, operands,
                     keep_client_params=keep_cp, with_agg_stats=True,
                     models_per_client=self.algo.models_per_client)
                 if cfg.trace_sync:
@@ -1300,10 +1319,7 @@ class Experiment:
                     # letting async dispatch spill it into whichever call
                     # blocks next (the guard's fetch, without trace_sync)
                     self._block(new_params)
-                if byz is not None and byz.has_stale:
-                    self._byz_stale = client_params
-                if self.step.codec == "delta":
-                    self._codec_prev = codec_prev
+                self._keep_round_carries(client_params, codec_prev)
                 if self._robust_active or self.hierarchy:
                     self._emit_robust_stats(
                         # lint: r2-ok (tiny gated [M, 3] evidence fetch)
@@ -1390,11 +1406,11 @@ class Experiment:
                              stream: bool = False) -> None:
         """ALL rounds of the time step + every scheduled eval as ONE device
         program (TrainStep.train_iteration_eval): a single dispatch and a
-        single bulk D2H fetch per time step — ~E× fewer host<->device round
-        trips than the per-chunk path. Entered only for
-        chunkable algorithms with a non-ensemble test path; trajectories are
-        bitwise-identical to both other paths (same fold_in keys, same eval
-        cadence).
+        single bulk D2H fetch per time step, where `_run_rounds` makes a
+        dispatch a round and one an eval. Entered only for chunkable
+        algorithms with a non-ensemble test path on a step that
+        ``fuses_rounds``; trajectories are bitwise-identical to the other
+        two drivers' (same fold_in keys, same eval cadence).
 
         ``stream=True`` swaps the device-resident dataset for a [C, 2, N]
         window of steps (t, t+1): the local time axis is (current, test), so
@@ -1425,15 +1441,10 @@ class Experiment:
             else:
                 x, y = self.x, self.y
                 t_idx = t
-            cms = self._client_masks(t, range(R))
-            bms = self._byz_modes(range(R), t)
-            eids = emasks = ebyz = None
-            if self.hierarchy:
-                # whole-step edge plan up front: kills/re-homes land between
-                # scanned rounds exactly as they would on the per-round path
-                eids, emasks, ebyz = self._edge_state(t, range(R))
-            byz_stale = (self.byzantine is not None
-                         and self.byzantine.has_stale)
+            # the whole step's rows up front: edge kills/re-homes land
+            # between scanned rounds exactly as on the per-round path
+            cms, operands = jax.tree_util.tree_map(
+                jnp.asarray, self._round_rows(t, range(R)))
             # The fused program DONATES its params input (HBM economy), so
             # the divergence rollback target must live on host: a numpy
             # snapshot of the iteration-start pool — the same D2H the
@@ -1449,13 +1460,9 @@ class Experiment:
             new_params, opt_states, n, losses, bufs, total, agg_stats = \
                 self.step.train_iteration_eval(
                     self.pool.params, opt_states, it_key, x, y,
-                    tw, sw, fm, lr_scale, R, freq, jnp.int32(t_idx),
-                    None if cms is None else jnp.asarray(cms),
-                    None if bms is None else jnp.asarray(bms),
-                    None if eids is None else jnp.asarray(eids),
-                    None if emasks is None else jnp.asarray(emasks),
-                    None if ebyz is None else jnp.asarray(ebyz),
-                    byz_stale=byz_stale, with_agg_stats=True)
+                    tw, sw, fm, lr_scale, R, freq, jnp.int32(t_idx), cms,
+                    operands, byz_stale=self._keeps_stale,
+                    with_agg_stats=True)
             # One dispatch covers all R rounds, so one dispatch-to-ready
             # wait covers them too (the stats/eval fetches below would
             # block here anyway — this only attributes the wait).
@@ -1617,9 +1624,7 @@ class Experiment:
         g0 = self.global_round
         # -- plan ------------------------------------------------------
         # lint: hot-path-begin (megastep plan: K-step cohort/fault stacking)
-        tws, cms_list = [], []
-        bms_list = [] if self.byzantine is not None else None
-        eids_list, emasks_list, ebyz_list = [], [], []
+        tws, rows = [], []
         xs_list, ys_list, slot_valids, members_list = [], [], [], []
         sw = fm = lr_scale = None
         for j in range(K):
@@ -1651,16 +1656,9 @@ class Experiment:
                         "megastep requires the algorithm's plain all-ones "
                         "feature mask (megastep_horizon contract violated)")
                 tws.append(self._pad_clients(tw))
-                cms_list.append(self._client_masks(t, range(R)))
-                if bms_list is not None:
-                    bms_list.append(self._byz_modes(range(R), t))
-                if self.hierarchy:
-                    # sequential per-step planning: edge kills/re-homes land
-                    # between steps exactly as on the per-iteration path
-                    eid_j, em_j, eb_j = self._edge_state(t, range(R))
-                    eids_list.append(eid_j)
-                    emasks_list.append(em_j)
-                    ebyz_list.append(eb_j)
+                # sequential per-step planning: edge kills/re-homes land
+                # between steps exactly as on the per-iteration path
+                rows.append(self._round_rows(t, range(R)))
             if self.population_mode:
                 xs_list.append(self.x)
                 ys_list.append(self.y)
@@ -1686,21 +1684,16 @@ class Experiment:
         with self._seg("round_prep"):
             sw = self._pad_clients(sw, value=1.0)
             time_ws = jnp.stack(tws)                      # [K, M, C_pad, T1]
-            cms = None
-            if cms_list[0] is not None:
-                cms = jnp.asarray(np.stack(cms_list))     # [K, R, C_pad]
-            bms = None
-            if bms_list:
-                bms = jnp.asarray(np.stack(bms_list))     # [K, R, C_pad]
-            eids = emasks = ebyz = None
-            if self.hierarchy:
-                eids = jnp.asarray(np.stack(eids_list))   # [K, R, C_pad]
-                if emasks_list[0] is not None:
-                    emasks = jnp.asarray(np.stack(emasks_list))   # [K, R, E]
-                if any(b is not None for b in ebyz_list):
-                    zeros = np.zeros((R, cfg.hierarchy_edges), dtype=np.int32)
-                    ebyz = jnp.asarray(np.stack(
-                        [b if b is not None else zeros for b in ebyz_list]))
+            def steps(field):
+                # one row kind over the K steps -> [K, R, ...]; None where no
+                # step has it, and zeros for a step without (an edge plan
+                # in which nothing corrupts) beside steps with
+                have = next((a for a in field if a is not None), None)
+                return None if have is None else jnp.asarray(np.stack(
+                    [np.zeros_like(have) if a is None else a for a in field]))
+            cms_list, operands_list = zip(*rows)
+            cms = steps(cms_list)
+            operands = StackOperands(*map(steps, zip(*operands_list)))
             x_steps = y_steps = None
             if self.population_mode:
                 # [K, C_pad, T1, N, ...] stacked per-step cohort shards — the
@@ -1708,7 +1701,6 @@ class Experiment:
                 # signature (and therefore the compile cache) is stable
                 x_steps = jnp.stack(xs_list)
                 y_steps = jnp.stack(ys_list)
-            byz_stale = self.byzantine is not None and self.byzantine.has_stale
         # lint: hot-path-end
         # -- dispatch --------------------------------------------------
         # lint: hot-path-begin (megastep: one program per K-step block)
@@ -1718,8 +1710,8 @@ class Experiment:
                 None if self.population_mode else self.x,
                 None if self.population_mode else self.y,
                 time_ws, sw, fm,
-                lr_scale, jnp.int32(t0), R, freq, K, cms, bms, eids,
-                emasks, ebyz, x_steps, y_steps, byz_stale=byz_stale)
+                lr_scale, jnp.int32(t0), R, freq, K, cms, operands, x_steps,
+                y_steps, byz_stale=self._keeps_stale)
             # one dispatch-to-ready wait per K-step block
             self._block(ps)
         # lint: hot-path-end

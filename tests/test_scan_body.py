@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from feddrift_tpu.config import ExperimentConfig
-from feddrift_tpu.core.step import TrainStep, make_optimizer
+from feddrift_tpu.core.step import StackOperands, TrainStep, make_optimizer
 from feddrift_tpu.models.mlp import FeedForwardNN
 
 M, C, T1, N, F = 3, 4, 3, 20, 3
@@ -221,7 +221,8 @@ def test_the_round_program_refuses_what_reads_a_stack_under_scan(how, named):
         step.robust_agg = "median"
     if how == "codec":
         step.codec = "int8"
-    kw = {"byz_modes": jnp.zeros((C,), jnp.int32)} if how == "byz" else {}
+    kw = {"operands": StackOperands(byz_modes=jnp.zeros((C,), jnp.int32))
+          } if how == "byz" else {}
     with pytest.raises(ValueError, match="client_axis='scan'") as e:
         step.train_round(params, step.init_opt_states(params, M, C),
                          jax.random.PRNGKey(5), x, y, _weights("full"),

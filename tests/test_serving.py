@@ -80,20 +80,35 @@ class TestRoutingTable:
 
 class TestBatchParity:
     def test_mixed_cluster_batch_bitwise_equals_per_request(self):
+        """Drives the batcher itself (``_serve_batch``): which requests share
+        a micro-batch, in which rows of which bucket, is written here. Eight
+        submitter threads used to decide it, and under load one of them
+        could land alone: the bucket of one is the one forward program that
+        is not bitwise ``pool.apply`` on the CPU backend (an ulp, on three
+        of these eight inputs), and a lone request is no coalesced batch."""
+        from feddrift_tpu.obs import spans
+        from feddrift_tpu.platform.serving import _Request
         pool = _pool(M=3)
         table = [0, 1, 2, 1, 0, 2, 2, 1]
-        eng = _engine(pool, table).start()
+        eng = _engine(pool, table)      # no dispatcher thread: batches below
         try:
-            eng.warmup()
             rng = np.random.RandomState(0)
             xs = rng.standard_normal((8, 3)).astype(np.float32)
-            with ThreadPoolExecutor(max_workers=8) as ex:
-                futs = [ex.submit(eng.submit, c, xs[c]) for c in range(8)]
-                results = [f.result(timeout=30) for f in futs]
-            for c, r in enumerate(results):
-                assert r.model == table[c]
-                expect = pool.apply(pool.slot(table[c]), xs[c][None])[0]
-                np.testing.assert_array_equal(r.logits, np.asarray(expect))
+            expect = [np.asarray(pool.apply(pool.slot(table[c]),
+                                            xs[c][None])[0])
+                      for c in range(8)]
+            # two full buckets of 4, the same eight in another order, a
+            # bucket of 2, and 3 requests padded into the bucket of 4
+            for batch in ([0, 1, 2, 3], [4, 5, 6, 7], [7, 2, 4, 1],
+                          [3, 6, 0, 5], [3, 4], [7, 0], [2, 7, 3], [4, 1, 6]):
+                reqs = [_Request(c, xs[c], spans.new_trace(), rid)
+                        for rid, c in enumerate(batch)]
+                eng._serve_batch(reqs)
+                for r in reqs:
+                    assert r.done.is_set() and r.error is None
+                    assert r.result.model == table[r.client]
+                    np.testing.assert_array_equal(r.result.logits,
+                                                  expect[r.client])
         finally:
             eng.close()
 

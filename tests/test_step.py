@@ -7,7 +7,7 @@ import numpy as np
 
 from feddrift_tpu.config import ExperimentConfig
 from feddrift_tpu.core.pool import ModelPool
-from feddrift_tpu.core.step import TrainStep, make_optimizer
+from feddrift_tpu.core.step import StackOperands, TrainStep, make_optimizer
 from feddrift_tpu.data.registry import make_dataset
 from feddrift_tpu.models import create_model
 
@@ -180,6 +180,19 @@ def _train_round_spans():
             if s["args"].get("fn") == "train_round"]
 
 
+def _param_stack(pool, M=3, C=4):
+    """The pool's parameters as an [M, C, ...] stack of client copies."""
+    return jax.tree_util.tree_map(
+        lambda l: jnp.broadcast_to(l[:, None], (M, C, *l.shape[1:])),
+        pool.params)
+
+
+def _forbid_the_compact_body(monkeypatch):
+    def boom(*a, **kw):
+        raise AssertionError("the compact body was traced")
+    monkeypatch.setattr(TrainStep, "_round_compact", boom)
+
+
 @pytest.fixture()
 def spans_in_memory():
     """The compact body's tests read ``dispatch`` spans: the process's
@@ -318,20 +331,16 @@ class TestCompactRound:
         if "dp" in fields:
             fields["robust_cfg"] = RobustAggConfig(dp_stddev=fields.pop("dp"))
         step = dataclasses.replace(step, _signatures={}, **fields)
-
-        def boom(*a, **kw):
-            raise AssertionError("the compact body was traced")
-        monkeypatch.setattr(TrainStep, "_round_compact", boom)
-        stack = jax.tree_util.tree_map(
-            lambda l: jnp.broadcast_to(l[:, None], (3, 4, *l.shape[1:])),
-            pool.params)
+        _forbid_the_compact_body(monkeypatch)
+        stack = _param_stack(pool)
         given = {name: stack if kind == "stack"
                  else jnp.zeros((4,), jnp.int32)
                  for name, kind in operands.items()}
         tw = jnp.asarray(_one_hot_tw([(0,), (2,), (2,), (0,)]))
         call = lambda **kw: step.train_round(          # noqa: E731
             pool.params, opt, jax.random.PRNGKey(2), x, y, tw, sw, fm,
-            jnp.float32(1.0), keep_client_params=keep, **given, **kw)
+            jnp.float32(1.0), operands=StackOperands(**given),
+            keep_client_params=keep, **kw)
         today = call()
         sigs = set(step._signatures["train_round"])
         cached = TrainStep._train_round_jit._cache_size()
@@ -373,3 +382,93 @@ class TestCompactRound:
         for got, was in zip(jax.tree_util.tree_leaves(compact[1]),
                             jax.tree_util.tree_leaves(dense[1])):
             assert got.sharding == was.sharding
+
+
+# ----------------------------------------------------------------------
+# the seam (ISSUE 31): what a round is given (`StackOperands`), and what
+# TrainStep makes of it (`_round_program`, `donates_pool`, `fuses_rounds`)
+@pytest.mark.usefixtures("spans_in_memory")
+class TestRoundSeam:
+    @pytest.mark.parametrize("field", StackOperands._fields)
+    def test_a_field_set_alone_keeps_the_stack(self, field, monkeypatch):
+        """Named by `_stack_users`, sent to the dense program, refused under
+        ``client_axis="scan"`` by its name."""
+        import dataclasses
+        cfg, ds, pool, step, x, y, opt, sw, fm = _setup(M=3, C=4)
+        if field in ("stale_params", "codec_prev"):
+            value = _param_stack(pool)
+        else:
+            value = jnp.zeros((2,) if field in ("edge_mask", "edge_modes")
+                              else (4,), jnp.int32)
+        none, alone = StackOperands(), StackOperands(**{field: value})
+        assert step._stack_users(False, none) == []
+        assert step._stack_users(False, alone) == [f"the operand {field}"]
+        assert step._round_program(3, 1, False, none) == (
+            TrainStep._train_round_jit, 1)
+        assert step._round_program(3, 1, False, alone) == (
+            TrainStep._train_round_jit, None)
+        _forbid_the_compact_body(monkeypatch)
+        tw = jnp.asarray(_one_hot_tw([(0,), (2,), (2,), (0,)]))
+        step.train_round(pool.params, opt, jax.random.PRNGKey(2), x, y, tw,
+                         sw, fm, jnp.float32(1.0), None, alone,
+                         keep_client_params=False, models_per_client=1)
+        assert _train_round_spans()[-1]["args"]["pairs_run"] == 3 * 4
+
+        scanned = dataclasses.replace(
+            step, client_axis="scan", _signatures={},
+            optimizer=make_optimizer("sgd", cfg.lr, cfg.wd))
+        assert scanned.donates_pool and not scanned.fuses_rounds
+        assert step.fuses_rounds and not step.donates_pool
+        assert scanned._round_program(3, 1, False, none) == (
+            TrainStep._train_round_scan_jit, None)
+        with pytest.raises(ValueError, match="client_axis='scan'") as e:
+            scanned.train_round(
+                jax.tree_util.tree_map(jnp.copy, pool.params),
+                scanned.init_opt_states(pool.params, 3, 4),
+                jax.random.PRNGKey(2), x, y, tw, sw, fm, jnp.float32(1.0),
+                None, alone, keep_client_params=False)
+        assert f"the operand {field} needs" in str(e.value)
+
+    def test_the_entries_keep_what_the_benchmark_binds(self):
+        """`benchmark/drivers/train.py::Recorder` binds ``train_round`` /
+        ``train_iteration_eval`` and reads ``time_w`` and ``client_mask`` /
+        ``client_masks`` by name; `benchmark/sizing.py` and `sizing_scan.py`
+        lower the three jits on their positional prefix alone, with
+        ``keep_client_params=False``; the device-trace readers find the
+        programs by the jitted functions' names."""
+        import inspect
+
+        def names(fn):
+            return list(inspect.signature(fn).parameters)
+        a_round = ["self", "params", "opt_states", "key", "x", "y", "time_w",
+                   "sample_w", "feat_mask", "lr_scale"]
+        a_step = a_round[:3] + ["iter_key"] + a_round[4:] + ["R", "freq", "t"]
+        assert names(TrainStep.train_round)[:11] == a_round + ["client_mask"]
+        assert names(TrainStep.train_iteration_eval)[:14] == (
+            a_step + ["client_masks"])
+        for name in ("_acc_matrix_jit", "init_opt_states",
+                     "fresh_opt_states"):
+            assert callable(getattr(TrainStep, name))
+
+        cfg, ds, pool, step, x, y, opt, sw, fm = _setup(M=3, C=4)
+        tw = jnp.ones((3, 4, 4), jnp.float32)
+        nine = (pool.params, opt, jax.random.PRNGKey(0), x, y, tw, sw, fm,
+                jnp.float32(1.0))
+        scanned = TrainStep(pool.apply, make_optimizer("sgd", cfg.lr, 0.0),
+                            20, cfg.epochs, ds.num_classes, client_axis="scan")
+        for jit, prefix, lowered in (
+                (TrainStep._train_round_jit, a_round,
+                 lambda j: j.lower(step, *nine, keep_client_params=False)),
+                (TrainStep._train_round_scan_jit, a_round,
+                 lambda j: j.lower(
+                     scanned, pool.params,
+                     scanned.init_opt_states(pool.params, 3, 4), *nine[2:],
+                     keep_client_params=False)),
+                (TrainStep._train_iteration_eval_jit, a_step,
+                 lambda j: j.lower(step, *nine, 2, 1, jnp.int32(0)))):
+            params = inspect.signature(jit).parameters
+            assert list(params)[:len(prefix)] == prefix
+            assert all(p.default is not p.empty
+                       for p in list(params.values())[len(prefix):])
+            assert lowered(jit).as_text().startswith(
+                f"module @jit_{jit.__name__} ")

@@ -117,7 +117,8 @@ def test_a_data_set_takes_labels_with_trailing_axes_and_refuses_a_mismatch():
 def test_what_reads_single_labels_refuses_a_label_per_token_by_name():
     import jax
     import jax.numpy as jnp
-    from feddrift_tpu.core.step import TrainStep, make_optimizer
+    from feddrift_tpu.core.step import (StackOperands, TrainStep,
+                                        make_optimizer)
     step = TrainStep(apply_fn=lambda p, xb: p["t"][xb],
                      optimizer=make_optimizer("sgd", 0.1, 0), batch_size=2,
                      num_steps=1, num_classes=5, cost_capture="off")
@@ -127,7 +128,8 @@ def test_what_reads_single_labels_refuses_a_label_per_token_by_name():
         step.train_round(p, step.init_opt_states(p, 1, 2),
                          jax.random.PRNGKey(0), x, x, jnp.ones((1, 2, 2)),
                          jnp.ones((1, 2, 2)), jnp.ones((1, 1)),
-                         jnp.float32(1.0), None, jnp.zeros((2,), jnp.int32))
+                         jnp.float32(1.0), None,
+                         StackOperands(byz_modes=jnp.zeros((2,), jnp.int32)))
     with pytest.raises(ValueError, match="confusion_matrices.*per token"):
         step.confusion_matrices(p, x[:, 0], x[:, 0], jnp.ones((1, 1)))
 
