@@ -135,19 +135,18 @@ class DriftAlgorithm:
         self.y = y
         self.logger = logger
         self.C_pad = c_pad
-        # Belt-and-braces alongside the params-identity cache key: a rebind
-        # with a different dataset must never serve accuracies computed on
-        # the previous one.
-        self._acc_offer = None
+        # the store of evaluated counts is keyed on the pool object alone:
+        # what it holds was counted on the data bound before
+        self._acc_store = None
 
     def rebind_data(self, x, y) -> None:
         """Population mode: swap in this iteration's gathered cohort shard
         (same shapes as the previous one — XLA never recompiles). Clears
-        the accuracy-offer cache: a hit keyed to the old data would serve
-        the previous cohort's accuracies."""
+        the store of evaluated counts: under an unchanged pool a hit would
+        serve the previous cohort's."""
         self.x = x
         self.y = y
-        self._acc_offer = None
+        self._acc_store = None
 
     # -- cohort state bridge (population mode) --------------------------
     def load_cohort_state(self, t: int, members: np.ndarray,
@@ -174,32 +173,6 @@ class DriftAlgorithm:
         """[C] per-slot drift-detector arm accuracies to persist per
         member (None = algorithm has no drift detector)."""
         return None
-
-    def offer_acc_matrix(self, params, offers: "dict[int, np.ndarray]") -> None:
-        """Runner ride-along: the fused iteration program's final eval slot
-        already holds the accuracy of the FINAL params on step t data (the
-        end_iteration consumers) and step t+1 data (the next cluster
-        phase) — exactly what ``acc_matrix_at`` would dispatch fresh device
-        calls to recompute. Caching them saves 1-2 host<->device round
-        trips per iteration.
-
-        ``params`` must be the EVALUATED params object (the fused program's
-        output), not ``pool.params`` after ``after_round``: an after_round
-        that returns transformed params would otherwise key accuracies of
-        the pre-transform params to the post-transform object. The cache is
-        keyed on that object's identity — any pool mutation rebinds
-        ``pool.params`` and silently invalidates it, so correctness never
-        depends on the cache hitting.
-
-        Offered matrices are frozen (read-only) because a cache hit hands
-        the SAME ndarray to every consumer; an in-place edit by one would
-        silently corrupt every later cluster decision this iteration."""
-        frozen = {}
-        for t, arr in offers.items():
-            arr = np.asarray(arr)
-            arr.setflags(write=False)
-            frozen[t] = arr
-        self._acc_offer = (params, frozen)
 
     def set_client_staleness(self, ages, suspected=()) -> None:
         """Runner hook: per-client absence ages ([C] rounds since the last
@@ -229,20 +202,84 @@ class DriftAlgorithm:
             out |= self._invalid_slots
         return out
 
-    def acc_matrix_at(self, t: int, feat_mask=None) -> np.ndarray:
-        """[M, C] accuracy of every model on every client's step-t data
-        (reference train_acc_matrix, FedAvgEnsDataLoader.py:1074-1085)."""
-        offer = getattr(self, "_acc_offer", None)
-        if (offer is not None and feat_mask is None
-                and offer[0] is self.pool.params and t in offer[1]):
-            return offer[1][t]
+    def _resident_data(self):
         if self.x is None:
             raise RuntimeError(
                 "full-dataset eval is unavailable under cfg.stream_data")
-        fm = feat_mask if feat_mask is not None else self._ones_feat_mask
-        correct, _, total = self.step.acc_matrix(
-            self.pool.params, self.x[:, t], self.y[:, t], fm)
-        correct, total = multihost.fetch((correct, total))
+        return self.x, self.y
+
+    # -- the store of evaluated counts ----------------------------------
+    # One (pool, time step) pair is evaluated once: the drift decision, the
+    # per-round re-assignment and the runner's evaluation ask for the same
+    # accuracy matrices (IFCA on the per-round path: 3 of a time step's 10).
+    # The store sits above ``TrainStep.acc_matrix``, so whatever that method
+    # returns, a test's patch included, is what every reader sees.
+    def _acc_entries(self, params) -> dict:
+        """{t: (correct, loss_sum, total)} as the store holds them for the
+        pool object ``params``. The key is identity: JAX arrays are
+        immutable and every writer of ``pool.params`` rebinds it (a round, a
+        donated round too, ``set_slot``, a restore, a rollback), so the same
+        object holds the same values, and a rollback to an object the store
+        still knows finds its entries true. Entries of any other object are
+        dropped first: the store never keeps a dead pool alive."""
+        if self._acc_store is None or self._acc_store[0] is not params:
+            self._acc_store = (params, {})
+        return self._acc_store[1]
+
+    def store_acc_counts(self, params, counts: "dict[int, tuple]") -> dict:
+        """Keep ``{t: (correct [M, C_pad], loss_sum [M, C_pad], total
+        [C_pad])}``, host arrays as ``step.acc_matrix(params, x[:, t],
+        y[:, t], all-ones mask)`` gives them, under the pool object
+        ``params``: the EVALUATED object (a fused program's output), not
+        ``pool.params`` after an ``after_round`` that may have transformed
+        it. The fused drivers hand in their final eval slot this way; the
+        arrays are made read-only because a hit hands the same array to
+        every consumer. Returns what it kept."""
+        def frozen(arr):
+            arr = np.asarray(arr)
+            arr.setflags(write=False)
+            return arr
+        kept = {t: tuple(frozen(a) for a in triple)
+                for t, triple in counts.items()}
+        self._acc_entries(params).update(kept)
+        return kept
+
+    def acc_counts_at(self, ts, feat_mask=None) -> list:
+        """For each time step of ``ts`` the triple (correct [M, C_pad],
+        loss_sum [M, C_pad], total [C_pad]) of ``pool.params`` on that
+        step's data, read-only host arrays. Under the plain mask (None, or
+        the all-ones object ``round_inputs`` hands out) what the store holds
+        for this pool object is served from it; the steps that miss are
+        dispatched in order and fetched together, one host sync however
+        many, and stored. Under a feature mask of the caller's nothing is
+        served or stored. Counted per requested step in the counters
+        ``acc_matrix_reused`` / ``acc_matrix_computed`` and as
+        ``acc_reused`` / ``acc_computed`` on the span the caller has open
+        (``drift_decision``, ``writeback``, ``eval``)."""
+        x, y = self._resident_data()
+        params = self.pool.params
+        plain = feat_mask is None or feat_mask is self._ones_feat_mask
+        held = self._acc_entries(params) if plain else {}
+        found = {t: held[t] for t in ts if t in held}
+        missing = [t for t in dict.fromkeys(ts) if t not in found]
+        if missing:
+            fm = self._ones_feat_mask if plain else feat_mask
+            fetched = dict(zip(missing, multihost.fetch([
+                self.step.acc_matrix(params, x[:, t], y[:, t], fm)
+                for t in missing])))
+            found.update(self.store_acc_counts(params, fetched) if plain
+                         else fetched)
+        reused, computed = len(ts) - len(missing), len(missing)
+        reg = obs.registry()
+        reg.counter("acc_matrix_reused").inc(reused)
+        reg.counter("acc_matrix_computed").inc(computed)
+        obs.spans.innermost().add(acc_reused=reused, acc_computed=computed)
+        return [found[t] for t in ts]
+
+    def acc_matrix_at(self, t: int, feat_mask=None) -> np.ndarray:
+        """[M, C] accuracy of every model on every client's step-t data
+        (reference train_acc_matrix, FedAvgEnsDataLoader.py:1074-1085)."""
+        (correct, _, total), = self.acc_counts_at([t], feat_mask)
         return np.asarray(correct)[:, :self.C] / np.asarray(total)[None, :self.C]
 
     def acc_cells_upto(self, t: int, feat_mask=None) -> np.ndarray:
@@ -251,11 +288,9 @@ class DriftAlgorithm:
         Evaluates the full [T1] axis (static shape -> one compile) and slices
         on host; the extra cells are cheap relative to a recompilation per t.
         """
-        if self.x is None:
-            raise RuntimeError(
-                "full-dataset eval is unavailable under cfg.stream_data")
+        x, y = self._resident_data()
         fm = feat_mask if feat_mask is not None else self._ones_feat_mask
-        correct = self.step.acc_cells(self.pool.params, self.x, self.y, fm)
+        correct = self.step.acc_cells(self.pool.params, x, y, fm)
         return np.asarray(multihost.fetch(correct))[:, :self.C, : t + 1]
 
     # -- hooks ----------------------------------------------------------

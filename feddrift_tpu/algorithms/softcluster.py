@@ -167,7 +167,6 @@ class SoftCluster(DriftAlgorithm):
     # ------------------------------------------------------------------
     # life cycle
     def begin_iteration(self, t: int) -> None:
-        acc_t = None   # cache: the [M, C] acc matrix at step t, if computed
         if t == 0:
             self._cluster_init()
             if self.kind in ("hard", "hard-r"):
@@ -175,8 +174,7 @@ class SoftCluster(DriftAlgorithm):
                 # (AggregatorSoftCluster.py:64-71)
                 for m in range(self.M):
                     self.pool.distinct_reinit_slot(m, seed=self.cfg.seed + 7700 + m)
-                acc_t = self.acc_matrix_at(0)
-                self._cluster(acc_t, 0, round_idx=0)
+                self._cluster(self.acc_matrix_at(0), 0, round_idx=0)
         elif not self._is_decision_step(t):
             # cadence carry-forward: the last decision's assignment extends
             # to this step's data — no accuracy matrix, no cluster pass, no
@@ -205,8 +203,9 @@ class SoftCluster(DriftAlgorithm):
             self.weights[:t] = 0.0                       # (:1263-1265)
 
         if t == 0:
-            # arm the drift detector with initial accuracies (:106-116)
-            acc = acc_t if acc_t is not None else self.acc_matrix_at(0)
+            # arm the drift detector with initial accuracies (:106-116);
+            # where IFCA asked for them above, the store has them
+            acc = self.acc_matrix_at(0)
             idx = self.test_model_idx(0)
             for c in range(self.C):
                 self.mmacc_acc[c] = acc[idx[c], c]

@@ -98,6 +98,9 @@ class _NullSpan:
     def set(self, **args: Any) -> None:
         """Arguments known only at the end of the interval; dropped here."""
 
+    def add(self, **counts: float) -> None:
+        """Counts that callers inside the interval add up; dropped here."""
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -117,12 +120,17 @@ class _Span:
         """Arguments known only at the end of the interval."""
         self._args.update(args)
 
+    def add(self, **counts: float) -> None:
+        """Counts that several callers inside the interval add up."""
+        for k, v in counts.items():
+            self._args[k] = self._args.get(k, 0) + v
+
     def __enter__(self) -> "_Span":
         rec = self._rec
         if rec.annotate is not None:
             self._ann = rec.annotate(self._name)
             self._ann.__enter__()
-        self._frame = rec._begin(_now())
+        self._frame = rec._begin(_now(), self)
         return self
 
     def __exit__(self, *exc) -> None:
@@ -201,13 +209,14 @@ class SpanRecorder:
             if inspect.ismethod(hook) else hook
 
     # -- the self-time stack -------------------------------------------
-    def _begin(self, now: float) -> list:
+    def _begin(self, now: float, span: "_Span") -> list:
         """Open an interval at ``now`` (perf_counter seconds) on this
-        thread's stack; the frame is [start, seconds covered by children]."""
+        thread's stack; the frame is [start, seconds covered by children,
+        the span that opened it]."""
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
-        frame = [now, 0.0]
+        frame = [now, 0.0, span]
         stack.append(frame)
         return frame
 
@@ -219,9 +228,17 @@ class SpanRecorder:
         while stack and stack.pop() is not frame:
             pass
         dur = now - frame[0]
+        frame[2] = None     # the span holds the frame: no cycle left behind
         if stack:
             stack[-1][1] += dur
         return dur, max(dur - frame[1], 0.0)
+
+    def innermost(self) -> "_Span | _NullSpan":
+        """The innermost ``span()`` still open on the calling thread, for a
+        layer that counts something on behalf of whichever segment called
+        it; the null span where none is open."""
+        stack = getattr(self._local, "stack", None)
+        return stack[-1][2] if stack else _NULL_SPAN
 
     def record(self, name: str, ts: float, dur: float, cat: str = "phase",
                **args: Any) -> dict | None:
@@ -336,6 +353,10 @@ def configure(path: str | None, pid: int = 0, max_bytes: int = 0,
 
 def span(name: str, cat: str = "phase", **args: Any):
     return _recorder.span(name, cat, **args)
+
+
+def innermost():
+    return _recorder.innermost()
 
 
 def record(name: str, ts: float, dur: float, cat: str = "phase",

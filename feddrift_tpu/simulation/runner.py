@@ -532,37 +532,26 @@ class Experiment:
         return apply_fn
 
     # ------------------------------------------------------------------
-    def evaluate(self, t: int, round_idx: int, precomputed=None) -> dict:
+    def evaluate(self, t: int, round_idx: int) -> dict:
         """Reference ``test_on_all_clients`` (AggregatorSoftCluster.py:210-285):
         per-client train acc on step t with that client's plurality model, and
         test acc on step t+1 data (temporal holdout); AUE/KUE use ensemble
         votes instead (FedAvgEnsAggregatorAue.py:256-283, Kue:234-262).
 
-        ``precomputed``: optional ((corr_tr, loss_tr, corr_te, loss_te),
-        total) matrices already computed on device inside the chunked train
-        program (TrainStep.train_iteration_eval) — skips both acc_matrix calls.
+        The train half on step t and the test half on step t+1 (not asked
+        for where an ensemble votes on the test data) come in one fetch,
+        through the algorithm's store of evaluated counts: under its plain
+        mask a per-round re-assignment has evaluated this pool on step t
+        already.
         """
-        cfg = self.cfg
         C = self.C_
-        xtest, ytest = self.x[:, t + 1], self.y[:, t + 1]
         fm = self.algo.round_inputs(t, round_idx)[2]
-
         spec = self.algo.ensemble_spec(t)
-        if precomputed is not None:
-            # one bulk D2H transfer: per-array fetches each pay their own
-            # host<->device round trip
-            (correct, loss_sum, corr_te, loss_te), total = \
-                multihost.fetch(precomputed)
-        else:
-            xt, yt = self.x[:, t], self.y[:, t]
-            fetch = [self.step.acc_matrix(self.pool.params, xt, yt, fm)]
-            if spec is None:
-                fetch.append(self.step.acc_matrix(
-                    self.pool.params, xtest, ytest, fm))
-            fetched = multihost.fetch(fetch)
-            correct, loss_sum, total = fetched[0]
-            if spec is None:
-                corr_te, loss_te, _ = fetched[1]
+        fetched = self.algo.acc_counts_at(
+            [t, t + 1] if spec is None else [t], fm)
+        correct, loss_sum, total = fetched[0]
+        if spec is None:
+            corr_te, loss_te, _ = fetched[1]
         correct = correct[:, :C]
         loss_sum = loss_sum[:, :C]
         total = total[:C]
@@ -581,7 +570,8 @@ class Experiment:
         if ew.ndim == 2:      # per-client weights (AUE-PC): pad phantom clients
             ew = self._pad_clients(ew)
         ec, et, el = self.step.ensemble_eval(
-            self.pool.params, xtest, ytest, ew, spec.mode,
+            self.pool.params, self.x[:, t + 1], self.y[:, t + 1], ew,
+            spec.mode,
             None if spec.model_mask is None
             else jnp.asarray(spec.model_mask, jnp.float32),
             fm)
@@ -1499,18 +1489,17 @@ class Experiment:
                                total[:C])
         self.global_round = g0 + R
         # lint: hot-path-end
-        # The final eval slot holds acc(final params, step t) and
-        # acc(final params, step t+1) — offer both so end_iteration
-        # consumers (MultiModel selection) and the next cluster phase each
-        # skip a device round trip (offer_acc_matrix's params-identity key,
-        # taken from the EVALUATED new_params, makes this a pure
-        # optimisation). Only valid when the chunk ran the algorithm's
-        # plain all-ones feature mask on the resident dataset.
-        if not stream and fm is getattr(self.algo, "_ones_feat_mask", None):
-            tot = np.maximum(total[None, :C], 1)
-            self.algo.offer_acc_matrix(
-                new_params, {t: corr_tr[-1][:, :C] / tot,
-                             t + 1: corr_te[-1][:, :C] / tot})
+        # The final eval slot holds the counts of the final params on step
+        # t and on step t+1 — store both so end_iteration consumers
+        # (MultiModel selection) and the next cluster phase each skip a
+        # device round trip (the store's params-identity key, taken from
+        # the EVALUATED new_params, makes this a pure optimisation). Only
+        # valid when the chunk ran the algorithm's plain all-ones feature
+        # mask on the resident dataset.
+        if not stream and fm is self.algo._ones_feat_mask:
+            self.algo.store_acc_counts(
+                new_params, {t: (corr_tr[-1], loss_tr[-1], total),
+                             t + 1: (corr_te[-1], loss_te[-1], total)})
 
     # ------------------------------------------------------------------
     # multi-iteration megastep (TrainStep.train_megastep)
@@ -1793,13 +1782,13 @@ class Experiment:
             with self._drift_decision():
                 self.algo.end_iteration(t)
             final_p = step_p
-        # Final-slot accuracy offer, exactly like the K=1 fused path —
-        # keyed to the sliced final-step params object the pool now holds.
+        # The final slot's counts into the store, exactly like the K=1
+        # fused path — keyed to the sliced final-step params object the
+        # pool now holds.
         if final_p is not None and committed == K and not skipping:
-            tot = np.maximum(total_h[None, :C], 1)
-            self.algo.offer_acc_matrix(
-                final_p, {t0 + K - 1: corr_tr[K - 1, -1][:, :C] / tot,
-                          t0 + K: corr_te[K - 1, -1][:, :C] / tot})
+            self.algo.store_acc_counts(final_p, {
+                t0 + K - 1: (corr_tr[K - 1, -1], loss_tr[K - 1, -1], total_h),
+                t0 + K: (corr_te[K - 1, -1], loss_te[K - 1, -1], total_h)})
         last_t = t0 + committed - 1
         if cfg.checkpoint_every_iteration and self.out_dir:
             # one checkpoint per BLOCK (the per-iteration generations

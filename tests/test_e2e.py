@@ -52,11 +52,11 @@ class TestEndToEnd:
         assert a == b, (a, b)
 
     def test_acc_matrix_ride_along_cache(self, monkeypatch):
-        # The fused path offers its final eval slot as next iteration's
+        # The fused path stores its final eval slot as next iteration's
         # cluster-phase acc matrix (runner._run_iteration_fused ->
-        # DriftAlgorithm.offer_acc_matrix): the cache must actually hit
+        # DriftAlgorithm.store_acc_counts): the store must actually hit
         # (saving one device round trip per iteration) AND the clustering
-        # trajectory must be identical with the cache defeated.
+        # trajectory must be identical with the store defeated.
         from feddrift_tpu.algorithms.base import DriftAlgorithm
         from feddrift_tpu.core.step import TrainStep
 
@@ -75,8 +75,10 @@ class TestEndToEnd:
         exp_a = run_experiment(_cfg(chunk_rounds=True, **kw))
         hits = calls["n"]
 
-        monkeypatch.setattr(DriftAlgorithm, "offer_acc_matrix",
-                            lambda self, params, offers: None)
+        # a store that holds nothing: every lookup misses, every write is
+        # lost (tests/test_acc_reuse.py defeats it the same way)
+        monkeypatch.setattr(DriftAlgorithm, "_acc_entries",
+                            lambda self, params: {})
         calls["n"] = 0
         exp_b = run_experiment(_cfg(chunk_rounds=True, **kw))
         misses = calls["n"]

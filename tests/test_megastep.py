@@ -282,36 +282,48 @@ class TestMegastepGate:
         assert DriftAlgorithm.megastep_horizon.__get__(object())(5) == 1
 
 
-class TestOfferCacheAliasing:
-    """offer_acc_matrix hands the SAME ndarray to every consumer; the
-    frozen-array + identity-key + rebind-invalidation trio keeps one
-    consumer's mutation (or a dataset swap) from corrupting the rest."""
+class TestStoreAliasing:
+    """The store of evaluated counts hands the SAME ndarrays to every
+    consumer; the frozen-array + identity-key + rebind-invalidation trio
+    keeps one consumer's mutation (or a dataset swap) from corrupting the
+    rest."""
 
-    def test_offered_matrix_is_frozen(self):
+    @staticmethod
+    def _counts(exp, hits):
+        M, Cp = exp.pool.num_models, exp.C_pad
+        return (np.full((M, Cp), hits, np.int32),
+                np.zeros((M, Cp), np.float32), np.full((Cp,), 16, np.int32))
+
+    def test_stored_counts_are_frozen(self):
         exp = Experiment(_cfg())
-        m = np.full((exp.pool.num_models, exp.algo.C), 0.5, np.float32)
-        exp.algo.offer_acc_matrix(exp.pool.params, {0: m})
-        got = exp.algo.acc_matrix_at(0)
-        assert got is not m or not got.flags.writeable
+        exp.algo.store_acc_counts(exp.pool.params,
+                                  {0: self._counts(exp, 8)})
+        (correct, loss, total), = exp.algo.acc_counts_at([0])
+        for arr in (correct, loss, total):
+            assert not arr.flags.writeable
         with pytest.raises(ValueError):
-            got[0, 0] = 0.0
+            correct[0, 0] = 0
+        # the ratio is the caller's own array, made anew from the counts
+        got = exp.algo.acc_matrix_at(0)
+        assert got.shape == (exp.pool.num_models, exp.algo.C)
+        assert (got == 0.5).all() and got.flags.writeable
 
-    def test_rebind_invalidates_offer(self):
+    def test_rebind_invalidates_the_store(self):
         exp = Experiment(_cfg())
-        m = np.full((exp.pool.num_models, exp.algo.C), 0.5, np.float32)
-        exp.algo.offer_acc_matrix(exp.pool.params, {0: m})
+        exp.algo.store_acc_counts(exp.pool.params,
+                                  {0: self._counts(exp, 8)})
         exp.algo.rebind_data(exp.x, exp.y)
-        assert exp.algo._acc_offer is None
+        assert exp.algo._acc_store is None
 
-    def test_pool_mutation_misses_cache(self):
+    def test_pool_mutation_misses_the_store(self):
         exp = Experiment(_cfg())
-        m = np.zeros((exp.pool.num_models, exp.algo.C), np.float32)
-        exp.algo.offer_acc_matrix(exp.pool.params, {0: m})
+        exp.algo.store_acc_counts(exp.pool.params,
+                                  {0: self._counts(exp, 0)})
         # any writeback rebinds pool.params to a new object: identity key
         exp.pool.params = jax.tree_util.tree_map(lambda l: l + 0,
                                                  exp.pool.params)
         fresh = exp.algo.acc_matrix_at(0)
-        assert fresh is not m and float(fresh.max()) > 0.0
+        assert float(fresh.max()) > 0.0
 
 
 class TestMegastepRegressAxis:
