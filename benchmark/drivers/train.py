@@ -354,8 +354,9 @@ def run(*, manifest, cell, config, traffic, sizes, seed, seconds, trace,
 
     trace_red = None
     if trace:
-        trace_red = _traced_steps(exp, t, traced_steps, traced, clients, cfg,
-                                  rehearse)
+        trace_red = _traced_steps(
+            exp, t, traced_steps, traced, clients, cfg, rehearse,
+            scopes=getattr(family_of(arch), "DEVICE_SCOPES", ()))
     stats = memory("after window")
     peak = max(u or 0 for u in stats["peak_bytes_in_use"])
     records["peak_bytes"] = peak
@@ -420,10 +421,11 @@ def _tracked_compiles() -> int:
                if str(k).startswith("jit_compiles"))
 
 
-def _traced_steps(exp, t, n, traced, clients, cfg, rehearse):
+def _traced_steps(exp, t, n, traced, clients, cfg, rehearse, scopes=()):
     """Runs ``n`` more time steps under the profiler and reduces the trace.
     Each is wrapped in a ``bench_time_step`` annotation from here; the
-    program's own spans give the host segment of each idle gap."""
+    program's own spans give the host segment of each idle gap, the model
+    family's ``scopes`` the layer of each device op."""
     import jax
     out_dir = os.path.join(ROOT, ".bench_trace")
     shutil.rmtree(out_dir, ignore_errors=True)
@@ -457,9 +459,13 @@ def _traced_steps(exp, t, n, traced, clients, cfg, rehearse):
     host_spans = [(s["name"], s["ts"] * 1e-6, s["dur"] * 1e-6)
                   for s in exp.spans.spans()]
     red = xplane.reduce(raw, sync_wall=sync_wall, host_spans=host_spans,
-                        rounds=sum(s["rounds"] for s in traced))
+                        rounds=sum(s["rounds"] for s in traced),
+                        scopes=scopes)
     log(f"trace of {n} time steps: {os.path.getsize(paths[0]) / 1e6:.1f} MB, "
-        f"reduced in {time.perf_counter() - r0:.1f} s")
+        f"reduced in {time.perf_counter() - r0:.1f} s; device time by scope "
+        f"{json.dumps(red['scope_s'])}")
+    for scope, ops in (red["scope_ops"] or {}).items():
+        log(f"device ops of scope {scope}: " + json.dumps(ops))
     shutil.rmtree(out_dir, ignore_errors=True)
     return red
 

@@ -64,6 +64,14 @@ import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
+# The ``jax.named_scope`` names that the program's module of this family
+# (``feddrift_tpu/models/mla_moe.py``) puts around its parts, in the order
+# ``xplane.scope_of`` tries them: the trace's reduction books an op's device
+# time to the first that occurs in its ``tf_op``
+# (``tests/benchmark/test_device_scopes.py`` lowers the module and finds
+# each name there).
+DEVICE_SCOPES = ("lm_head", "expert_layer", "mla_attention")
+
 
 # ----------------------------------------------------------------------
 # parameters

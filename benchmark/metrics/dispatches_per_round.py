@@ -2,11 +2,14 @@
 window's time steps (one per call through ``TrainStep``'s tracked wrappers:
 ``train_round``, ``acc_matrix``, ``train_iteration_eval``,
 ``fresh_opt_states``, ...) over its rounds. The time-step boundary's
-dispatches are spread over the rounds: since PR 26 the time step's fresh
-optimizer states are one of them (one tracked program where some four
-hundred eager ops went uncounted), so the cell reads 3.2 where it read 3.0,
-and the rehearsal's tiny job 5.0 (ten in 2 rounds) where it read 4.5. Eager
-dispatches (slices, ``jnp.asarray`` of masks) are not counted."""
+dispatches are spread over the rounds: the time step's fresh optimizer
+states are one of them since PR 26 (one tracked program where some four
+hundred eager ops went uncounted), and since PR 32 an ``acc_matrix`` that the
+host already holds for the pool and time step is served from the store and
+not dispatched: 13 in the cells' 5 rounds (5 ``train_round``, 1
+``fresh_opt_states``, 7 ``acc_matrix``), 2.6, and 7 in the 2 rounds of the
+rehearsal's tiny job, 3.5. Eager dispatches (slices, ``jnp.asarray`` of
+masks) are not counted."""
 
 from benchmark.metrics._round_spans import per_round
 

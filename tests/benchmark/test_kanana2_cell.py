@@ -3,12 +3,13 @@ CPU: the command's rehearsal comes out correct and the program's own
 lower-precision path does not, by the cell's own limits; the family's
 operation count against a count made by hand; the configuration file against
 the published one; the three new metrics' readers on made-up records and on
-the fixture trace. (``test_kanana2_faults.py`` plants the faults.) No number
+the fixture trace; the scope names of ``DEVICE_SCOPES`` in the lowered module. (``test_kanana2_faults.py`` plants the faults.) No number
 from here is a device number."""
 
 import importlib
 import json
 import os
+import re
 import sys
 
 import pytest
@@ -202,21 +203,30 @@ def test_the_new_metrics_readers_on_made_up_records_and_the_fixture(ring):
 
 
 def test_the_cells_entries_come_after_the_ones_that_were_there():
-    """One configuration, one cell and three per-layer metrics, each after
-    the accepted ones; the cell reports all fourteen metrics, the accepted
-    cell the eleven it reported."""
+    """One configuration, one cell and three per-layer metrics (PR 29), then
+    the four shares of device time by scope and the resnet cell's
+    ``pairs_run_per_round`` (PR 33), each after the accepted ones; the cell
+    reports eighteen of the nineteen metrics, the accepted cell the eleven
+    it reported and its one."""
     assert [c["name"] for c in MANIFEST["configs"]] == [
         "cifar10_resnet20", "kanana2_30b_a3b"]
     assert [c["name"] for c in MANIFEST["workloads"]] == [
         "resnet20.ifca_perround", CELL]
     names = [m["name"] for m in MANIFEST["per_layer"]]
     new = ["pairs_trained_per_round", "held_expert_assignments_per_token",
-           "eval_program_device_ms"]
-    assert names[-3:] == new and len(names) == 14
+           "eval_program_device_ms", "lm_head_device_share",
+           "expert_layer_device_share", "attention_device_share",
+           "outside_scopes_device_share"]
+    assert names[11:] == new + ["pairs_run_per_round"] and len(names) == 19
     assert names[4] == "train_step_mfu"
-    for m in MANIFEST["per_layer"][-3:]:
-        assert m["workloads"] == [CELL]
+    for m in MANIFEST["per_layer"][11:]:
+        assert m["workloads"] == [
+            "resnet20.ifca_perround" if m["name"] == "pairs_run_per_round"
+            else CELL]
         assert m["moves"] == "train_examples_per_s"
+    for m in MANIFEST["per_layer"][14:18]:
+        assert (m["unit"], m["source"], m["better"]) == (
+            "%", "device_trace", "lower")
     assert all("workloads" not in m for m in MANIFEST["per_layer"][:11])
     cell = MANIFEST["workloads"][1]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -227,6 +237,50 @@ def test_the_cells_entries_come_after_the_ones_that_were_there():
     assert MANIFEST["configs"][1]["source"] == CONFIG["source"]
     reported = [m["name"] for m in bench.metrics_of(MANIFEST, "per_layer",
                                                     CELL)]
-    assert reported == names                      # all fourteen
+    assert reported == names[:18]                 # all but the resnet's
     old = bench.metrics_of(MANIFEST, "per_layer", "resnet20.ifca_perround")
-    assert [m["name"] for m in old] == names[:11]
+    assert [m["name"] for m in old] == names[:11] + ["pairs_run_per_round"]
+
+
+def test_the_programs_module_still_names_the_familys_device_scopes():
+    """The shares of device time are read by ``jax.named_scope`` names that
+    the program's module sets (``feddrift_tpu/models/mla_moe.py``) and the
+    family's file lists: each occurs in the ``op_name`` metadata of the
+    tiny preset's lowered forward and backward pass, so that a renamed scope
+    fails here and not as a silent metric."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark import family_of
+    from benchmark.drivers.train import experiment_config
+    from feddrift_tpu.data.drift_dataset import DriftDataset
+    from feddrift_tpu.models import create_model
+    _, config, traffic, sizes = bench.load_cell(MANIFEST, CELL, rehearse=True)
+    family = family_of(config["arch"])
+    shapes = family.sample_shapes(config["arch"])
+    (x_shape, x_dtype), (y_shape, y_dtype) = shapes["x"], shapes["y"]
+    module = create_model(config["program"]["model"], DriftDataset(
+        x=np.zeros((1, 2, 1, *x_shape), x_dtype),
+        y=np.zeros((1, 2, 1, *y_shape), y_dtype),
+        num_classes=shapes["num_classes"],
+        concepts=np.zeros((2, 1), np.int32), is_sequence=True),
+        experiment_config(config, traffic, sizes, 0, 2))
+    x = jnp.zeros((2, *x_shape), x_dtype)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+
+    def loss(p):
+        return module.apply(p, x).sum()
+    text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text(
+        debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]*)"', text))
+    assert family.DEVICE_SCOPES == ("lm_head", "expert_layer",
+                                    "mla_attention")
+    for scope in family.DEVICE_SCOPES:
+        mine = [p for p in paths if f"/{scope}/" in p]
+        assert any(p.startswith("jit(loss)/jvp(") for p in mine), scope
+        assert any(p.startswith("jit(loss)/transpose(jvp(") for p in mine), \
+            scope
+        # the head is outside the blocks that are rematerialised
+        assert any("rematted_computation" in p for p in mine) \
+            == (scope != "lm_head"), scope
+        assert xplane.scope_of(mine[0], family.DEVICE_SCOPES) == scope

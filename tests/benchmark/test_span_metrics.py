@@ -104,12 +104,14 @@ def test_only_the_rounds_own_spans_are_counted(ring):
 
 
 def test_a_tiny_job_on_the_cells_traffic_leaves_spans_to_read():
-    """IFCA ``hard-r`` on the per-round path at the rehearsal's size: per
-    round a train_round and an acc_matrix dispatch and two fetches, per
-    evaluation two dispatches and a fetch, and at the time-step boundary
-    two dispatches (``acc_matrix`` and, since PR 26, ``fresh_opt_states``:
-    the time step's optimizer states as one tracked program) and one fetch:
-    ten tracked dispatches and seven fetches in a time step of 2 rounds."""
+    """IFCA ``hard-r`` on the per-round path at the rehearsal's size (2
+    rounds a time step, an evaluation behind both). Per time step 2
+    ``train_round``, 1 ``fresh_opt_states`` (the time step's optimizer
+    states as one tracked program, PR 26) and 4 ``acc_matrix`` (the
+    re-assignment after each round and the test half of each evaluation;
+    the drift decision's and the evaluations' train halves are served from
+    the store of evaluated accuracy counts, PR 32): seven tracked dispatches
+    and six fetches. Each ``train_round`` runs K x C = 1 x 2 pairs."""
     from benchmark.drivers import train
     from feddrift_tpu.parallel.mesh import make_mesh
     from feddrift_tpu.simulation.runner import Experiment
@@ -125,8 +127,9 @@ def test_a_tiny_job_on_the_cells_traffic_leaves_spans_to_read():
                 exp, t, exp.last_round_breakdown["wall_s"], clients, cfg))
     rec = {"time_steps": steps}
     assert all(s["rounds"] == 2 for s in steps)
-    assert reader("dispatches_per_round").read(rec, None, cell) == 5.0
-    assert reader("host_syncs_per_round").read(rec, None, cell) == 3.5
+    assert reader("dispatches_per_round").read(rec, None, cell) == 3.5
+    assert reader("host_syncs_per_round").read(rec, None, cell) == 3.0
+    assert reader("pairs_run_per_round").read(rec, None, cell) == 2.0
     assert 0.0 < reader("runner_host_share").read(rec, None, cell) < 100.0
     for s in steps:
         assert s["segments"]["device_compute"] > 0
