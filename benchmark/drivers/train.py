@@ -188,30 +188,56 @@ def initial_models(flat: dict, traffic: dict) -> list[dict]:
 
 
 def install_weights(exp, arch, traffic, seed):
-    """The pool starts from the benchmark's weights (``initial_models``)."""
+    """The pool starts from the benchmark's weights (``initial_models``,
+    which it returns): model 0 in every slot and as the program's reinit
+    target, at the pool's own types. Where the job re-draws distinct models
+    at the first time step, the re-draw uploads slot m's start model from
+    those host arrays when the program asks for it, so the harness keeps
+    nothing on the device. The program's first pool goes before the weights
+    are made and the weights' device copy before the new pool is: never
+    more than a pool and one model beside the data."""
     import jax
     from feddrift_tpu.parallel.mesh import replicate
-    M = exp.pool.num_models
-    flat = weights.make_weights(arch, seed, M)
-    same = {k: jax.numpy.broadcast_to(v[:1], v.shape) for k, v in flat.items()}
-    like = exp.pool.params
-    tree = weights.to_program_tree(arch, same)
+    pool = exp.pool
+    like = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), pool.params)
+    _delete((pool.params, pool.init_params))
+    pool.params = pool.init_params = None
+    flat = weights.make_weights(arch, seed, pool.num_models)
+    host = {k: np.asarray(v) for k, v in flat.items()}
+    _delete(flat)
+    tree = weights.to_program_tree(arch, host)
     if jax.tree_util.tree_structure(tree) != jax.tree_util.tree_structure(like) \
             or [l.shape for l in jax.tree_util.tree_leaves(tree)] \
             != [l.shape for l in jax.tree_util.tree_leaves(like)]:
         raise ValueError("the configuration's arch does not describe the "
                          "program's model")
     # stored at the pool's own type (float32 as the configurations state it)
-    cast = lambda t: jax.tree_util.tree_map(          # noqa: E731
-        lambda l, ref: l.astype(ref.dtype), t, like)
-    tree = cast(tree)
-    exp.pool.params = replicate(exp.mesh, tree)
-    exp.pool.init_params = jax.tree_util.tree_map(lambda l: l[0], tree)
-    distinct = cast(weights.to_program_tree(arch, flat))
+    pool.params = jax.tree_util.tree_map(
+        lambda l, ref: replicate(exp.mesh, jax.numpy.broadcast_to(
+            jax.numpy.asarray(l[:1], ref.dtype), ref.shape)), tree, like)
+    pool.init_params = jax.tree_util.tree_map(lambda l: l[0], pool.params)
+    init = initial_models(host, traffic)
     if traffic.get("distinct_init"):
-        exp.pool.distinct_reinit_slot = lambda m, seed=None: exp.pool.set_slot(
-            m, jax.tree_util.tree_map(lambda l: l[m], distinct))
-    return initial_models(flat, traffic)
+        def redraw(m, seed=None):
+            pool.set_slot(m, jax.tree_util.tree_map(
+                lambda l, ref: np.asarray(l, ref.dtype),
+                weights.to_program_tree(arch, init[m]), like))
+            # set_slot builds its pool beside the one it reads: the line
+            # says whether that or a round sets the run's peak
+            jax.block_until_ready(pool.params)
+            memory(f"re-draw of slot {m}")
+        pool.distinct_reinit_slot = redraw
+    return init
+
+
+def _delete(tree) -> None:
+    """Frees the device arrays of ``tree`` now, whoever else refers to
+    them."""
+    import jax
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if hasattr(leaf, "delete"):
+            leaf.delete()
 
 
 def memory(stage: str) -> dict:
@@ -472,13 +498,10 @@ def _traced_steps(exp, t, n, traced, clients, cfg, rehearse, scopes=()):
 
 def _free(exp) -> None:
     import jax
-    for tree in (exp.x, exp.y, exp.pool.params, exp.pool.init_params,
-                 getattr(exp.algo, "_tw", None),
-                 getattr(exp.algo, "_ones_sample_w", None),
-                 getattr(exp.algo, "_ones_feat_mask", None)):
-        for leaf in jax.tree_util.tree_leaves(tree):
-            if hasattr(leaf, "delete"):
-                leaf.delete()
+    _delete((exp.x, exp.y, exp.pool.params, exp.pool.init_params,
+             getattr(exp.algo, "_tw", None),
+             getattr(exp.algo, "_ones_sample_w", None),
+             getattr(exp.algo, "_ones_feat_mask", None)))
     jax.clear_caches()
 
 

@@ -8,15 +8,24 @@ What ``sizing.py`` does for the vmap body, for a configuration whose
 ``TrainStep`` from a fixed list of arguments, without the program's
 ``client_axis``, so it would lower the vmap body (PERF.md section 7 names the
 edit that folds this file into it). Lowers ``train_round`` and ``acc_matrix`` with
-shapes only and compiles them for a described ``v5e:2x2`` device. Nothing
-runs, so nothing here is a chip measurement; what the harness and the runner
-hold beside a program (the start models, the pre-round pool) it does not see.
+shapes only and compiles them for a described ``v5e:2x2`` device, one line a
+program. A last line says what a run holds on the device beside a program:
+the pool (M models at the pool's type), ``init_params`` (the program's reinit
+target, one model) and the data, counted from the shapes; the harness holds
+nothing there after time step 0 (``drivers/train.py::install_weights``).
+Their sum with the larger program's temporaries is held against the same
+limit. ``ModelPool.set_slot`` builds its pool beside the one it reads, so
+while a slot is written (IFCA's re-draw at time step 0, before a round
+program is loaded) a run holds one pool more: that is what set the decoder
+cell's ``peak_bytes_in_use`` on the chip (PERF.md section 4). Nothing runs
+here, so nothing here is a chip measurement.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 
@@ -40,7 +49,7 @@ def main() -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from benchmark import family_of
+    from benchmark import family_of, flops
     from benchmark.drivers.train import experiment_config
     from benchmark.run import load_cell, load_manifest
     from feddrift_tpu.core.precision import PrecisionPolicy
@@ -114,8 +123,10 @@ def main() -> int:
         "acc_matrix": TrainStep._acc_matrix_jit.lower(
             step, params, sds((C, N, *x_shape), x_dtype),
             sds((C, N, *y_shape), y_dtype), fm)}
+    temporaries = 0
     for name, lowered in programs.items():
         ma = lowered.compile().memory_analysis()
+        temporaries = max(temporaries, ma.temp_size_in_bytes)
         parts = {"arguments": ma.argument_size_in_bytes,
                  "outputs": ma.output_size_in_bytes,
                  "aliases": ma.alias_size_in_bytes,
@@ -128,6 +139,29 @@ def main() -> int:
             "total_gb": round(total / 1e9, 3),
             "share_of_compiler_limit": round(total / COMPILER_LIMIT, 3),
             "within_rule": total <= 0.8 * COMPILER_LIMIT}), flush=True)
+
+    count = flops.parameter_count(config["arch"])
+    if count != sum(l.size // M for l in jax.tree_util.tree_leaves(params)):
+        raise SystemExit("the configuration's arch does not describe the "
+                         "program's model")
+    width = jnp.dtype(prog["dtype"]).itemsize
+    held = {"pool": M * width * count, "init_params": width * count,
+            "data": C * T1 * N * (
+                math.prod(x_shape) * jnp.dtype(x_dtype).itemsize
+                + math.prod(y_shape) * jnp.dtype(y_dtype).itemsize)}
+    total = sum(held.values()) + temporaries
+    print(json.dumps({
+        "workload": cell["name"], "held": "beside a program",
+        "clients_per_chip": C, "parameters": count,
+        "bytes_a_parameter_held": (M + 1) * width,
+        **{f"{k}_gb": round(v / 1e9, 3) for k, v in held.items()},
+        "held_gb": round(sum(held.values()) / 1e9, 3),
+        "held_while_a_slot_is_written_gb": round(
+            (sum(held.values()) + held["pool"]) / 1e9, 3),
+        "largest_temporaries_gb": round(temporaries / 1e9, 3),
+        "total_gb": round(total / 1e9, 3),
+        "share_of_compiler_limit": round(total / COMPILER_LIMIT, 3),
+        "within_rule": total <= 0.8 * COMPILER_LIMIT}), flush=True)
     return 0
 
 
