@@ -99,6 +99,12 @@ class DriftAlgorithm:
     # and not M (TrainStep.train_round ``models_per_client``). None says
     # nothing, and every (model, client) pair runs.
     models_per_client: int | None = None
+    # The number of trailing time steps that can carry weight in
+    # ``round_inputs``' time weights, where the algorithm's own definition
+    # bounds it (a window of one step: 1): the round program is then handed
+    # those steps of the data and not all T1 (`time_window`,
+    # TrainStep.train_round ``time_window``). None: the whole axis.
+    train_window: int | None = None
 
     def __init__(self, cfg, ds, pool, step) -> None:
         self.cfg = cfg
@@ -125,6 +131,32 @@ class DriftAlgorithm:
         # and the slots with no member behind them (active pop < slots).
         self._cohort_members: np.ndarray | None = None
         self._invalid_slots: np.ndarray | None = None
+
+    def time_window(self, t: int) -> tuple[int, int] | None:
+        """``(lo, W)``: the W = ``train_window`` time steps that can carry
+        weight at time step ``t``, the trailing ones, held inside the axis
+        so that W is the same at every ``t``; None where the algorithm
+        declares no window (or one as long as the axis)."""
+        W = self.train_window
+        if W is None or W >= self.T1:
+            return None
+        return min(max(t + 1 - W, 0), self.T1 - W), W
+
+    def _check_time_window(self, t: int, weights: np.ndarray) -> None:
+        """Raise where host weights ``[T1, ...]`` carry weight outside
+        `time_window`: the round program would not see those steps."""
+        window = self.time_window(t)
+        if window is None:
+            return
+        lo, W = window
+        weighted = (weights.reshape(self.T1, -1) != 0).any(axis=1)
+        weighted[lo:lo + W] = False
+        steps = np.nonzero(weighted)[0]
+        if steps.size:
+            raise ValueError(
+                f"{type(self).__name__} declares train_window={W} (time "
+                f"steps {lo}..{lo + W - 1} at t={t}) but gives weight to "
+                f"time step(s) {steps.tolist()}")
 
     # -- runtime binding ------------------------------------------------
     def bind(self, x, y, logger, c_pad: int) -> None:

@@ -40,11 +40,14 @@ class WindowBaseline(DriftAlgorithm):
             spec = "all"
         self.spec = spec
         self._tw = None
-        # win-1 trains on the current step only -> streamable
+        # win-1 trains on the current step only -> streamable, and the
+        # round program needs that step's data alone
         self.supports_streaming = spec == "win-1"
+        self.train_window = 1 if spec == "win-1" else None
 
     def begin_iteration(self, t: int) -> None:
         w = time_weights(self.spec, self.C, t, self.T1)      # [C, T1]
+        self._check_time_window(t, w.T)
         self._tw = jnp.asarray(w[None], jnp.float32)          # [1, C, T1]
 
     def round_inputs(self, t: int, r: int):

@@ -93,6 +93,12 @@ class SoftCluster(DriftAlgorithm):
         # from phase timings alone (SCALING_r04 weak point).
         self.event_counts = {"spawns": 0, "merges": 0, "linkage_calls": 0}
         self._tw = None
+        # win-1 zeroes every step before t (`begin_iteration`) and no step
+        # after t is assigned yet: one time step carries weight, unless
+        # CFL's retrain "all" copies a split back over the earlier steps
+        if cfg.concept_drift_algo == "softclusterwin-1" and not (
+                self.kind == "cfl" and self.cfl_retrain == "all"):
+            self.train_window = 1
         # only the CFL variant reads per-client deltas in after_round
         self.needs_client_params = self.kind == "cfl"
         # Population mode (cfg.population_size > 0): the hard-assignment
@@ -129,7 +135,8 @@ class SoftCluster(DriftAlgorithm):
                 used.add(0)     # degenerate fresh population: model 0
         return [m for m in sorted(used) if m not in marked]
 
-    def _sync_device_weights(self) -> None:
+    def _sync_device_weights(self, t: int) -> None:
+        self._check_time_window(t, self.weights)
         # [T1, M, C] -> [M, C, T1] for the train step
         self._tw = jnp.asarray(np.transpose(self.weights, (1, 2, 0)))
         self.models_per_client = live_models_per_client(self.weights)
@@ -216,7 +223,7 @@ class SoftCluster(DriftAlgorithm):
             # validated by their own normalization)
             from feddrift_tpu.utils.invariants import check_weight_partition
             check_weight_partition(self.weights, t)
-        self._sync_device_weights()
+        self._sync_device_weights(t)
 
     def after_round(self, t: int, r: int, prev_params, agg_params,
                     client_params, n):
@@ -226,13 +233,13 @@ class SoftCluster(DriftAlgorithm):
             if did_split:
                 # skip this round's aggregation: local updates correspond to
                 # an outdated model assignment (AggregatorSoftCluster.py:140-146)
-                self._sync_device_weights()
+                self._sync_device_weights(t)
                 return self.pool.params
         self.pool.params = agg_params
         if self.kind == "hard-r":
             # re-cluster every round (:187-191)
             self._cluster(self.acc_matrix_at(t), t, round_idx=r + 1)
-            self._sync_device_weights()
+            self._sync_device_weights(t)
         return self.pool.params
 
     # ------------------------------------------------------------------

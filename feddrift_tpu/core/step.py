@@ -30,7 +30,9 @@ their updates are masked out, mirroring the reference's skip logic
 Where the caller counted on the host that no client trains more than K < M
 models this round (``train_round``'s ``models_per_client``), the vmap runs
 over (K, C) instead — `_round_compact`: a hard assignment trains C pairs,
-not M x C.
+not M x C. Where the caller knows from the algorithm that only W trailing
+time steps carry weight (``train_round``'s ``time_window``), the program
+slices x, y and time_w to them first and every body sees T1 = W.
 
 Batch sampling semantics match the reference: data is pre-shuffled once per
 time step (host side), a step picks time step t ~ Categorical(time_w) and a
@@ -61,6 +63,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from feddrift_tpu import obs
@@ -124,6 +127,16 @@ def inverse_cdf_draw(key, cdf: jnp.ndarray, batch: int) -> jnp.ndarray:
     u = jax.random.uniform(key, (batch,))
     return jnp.clip(jnp.searchsorted(cdf, u, side="right"),
                     0, cdf.shape[0] - 1)
+
+
+def time_window_of(x, y, time_w, lo, width: int):
+    """The ``width`` time steps from ``lo`` on of a round's data and of its
+    time weights: x, y [C, T1, ...] -> [C, width, ...], time_w [M, C, T1] ->
+    [M, C, width]. ``lo`` is an operand, so one program serves every time
+    step; ``width`` is static."""
+    return (jax.lax.dynamic_slice_in_dim(x, lo, width, axis=1),
+            jax.lax.dynamic_slice_in_dim(y, lo, width, axis=1),
+            jax.lax.dynamic_slice_in_dim(time_w, lo, width, axis=2))
 
 
 def make_optimizer(name: str, lr: float, wd: float) -> optax.GradientTransformation:
@@ -777,7 +790,8 @@ class TrainStep:
                     operands=StackOperands(), *,
                     keep_client_params: bool = True,
                     with_agg_stats: bool = False,
-                    models_per_client: int | None = None):
+                    models_per_client: int | None = None,
+                    time_window: tuple[int, int] | None = None):
         """One communication round. Returns (new_params [M, ...],
         new_opt_states, client_params [M, C, ...], n [M, C], mean_loss [M, C])
         plus, when ``with_agg_stats``, the robust-aggregation stats
@@ -802,6 +816,25 @@ class TrainStep:
         program runs local steps for (not under ``client_axis="scan"``,
         which counts the pairs it trained itself: ``pairs_trained``).
 
+        ``time_window`` ``(lo, W)``: the caller's word, from the algorithm
+        that made the weights (`DriftAlgorithm.time_window`), that ``time_w``
+        is 0 outside the W time steps from ``lo`` on. The round program then
+        takes ``x[:, lo:lo+W]``, ``y[:, lo:lo+W]`` and ``time_w[..., lo:lo+W]``
+        as its first act (`time_window_of`) and every body below sees
+        T1 = W: the batch is gathered from W time steps of every client and
+        not from all T1, which on the chip were copied into the gather's
+        layout every round. W is static and ``lo`` an operand, so the time
+        steps of a run meet one program. The positional ``x``, ``y`` and
+        ``time_w`` stay whole whatever the window: what stands in front of
+        this entry reads them (the benchmark's recorder replays ``time_w``
+        over its reference's own whole ``x``). With contiguous batches and
+        W = 1 the results are the whole axis's bit for bit (a pair without
+        weight, n = 0, reports the loss of batches drawn from the steps it
+        is handed, which nothing reads); None hands the whole axis to the
+        program it has always been. Counter ``train_round_time_steps`` and
+        the ``dispatch`` span's ``time_steps`` say how many time steps the
+        dispatched program was handed: W, or T1.
+
         Under ``client_axis="scan"`` the program is `_round_body_scan`:
         ``keep_client_params`` must be False, ``params`` is DONATED (the
         new pool is written over it) and, with ``with_agg_stats``, an
@@ -810,18 +843,25 @@ class TrainStep:
         """
         args = (params, opt_states, key, x, y, time_w, sample_w, feat_mask,
                 lr_scale, client_mask, operands)
-        M, C = time_w.shape[:2]
+        M, C, T1 = time_w.shape
         program, K = self._round_program(M, models_per_client,
                                          keep_client_params, operands)
         kwargs = {"keep_client_params": keep_client_params}
         if K is not None:
             kwargs["models_per_client"] = K
+        W = None
+        if time_window is not None:
+            lo, W = time_window
+            args += (np.int32(lo),)
+            kwargs["time_steps"] = W
         with self._tracked(
                 "train_round", program, args, kwargs,
                 sig=(params, opt_states, x, y, time_w, sample_w, feat_mask,
                      client_mask, operands),
-                static=(keep_client_params, K)) as sp:
+                static=(keep_client_params, K, W)) as sp:
             out = program(self, *args, **kwargs)
+            obs.registry().counter("train_round_time_steps").inc(W or T1)
+            sp.set(time_steps=W or T1)
             if not self.donates_pool:
                 pairs_run = (K or M) * C
                 obs.registry().counter("pairs_run").inc(pairs_run)
@@ -829,12 +869,16 @@ class TrainStep:
         return out if with_agg_stats else out[:5]
 
     @partial(jax.jit, static_argnums=0,
-             static_argnames=("keep_client_params", "models_per_client"))
+             static_argnames=("keep_client_params", "models_per_client",
+                              "time_steps"))
     def _train_round_jit(self, params, opt_states, key, x, y, time_w,
                          sample_w, feat_mask, lr_scale, client_mask=None,
-                         operands=StackOperands(), *,
+                         operands=StackOperands(), window_lo=None, *,
                          keep_client_params: bool = True,
-                         models_per_client: int | None = None):
+                         models_per_client: int | None = None,
+                         time_steps: int | None = None):
+        if time_steps is not None:
+            x, y, time_w = time_window_of(x, y, time_w, window_lo, time_steps)
         out = self._round_body(params, opt_states, key, x, y, time_w,
                                sample_w, feat_mask, lr_scale, client_mask,
                                operands, models_per_client)
@@ -843,12 +887,15 @@ class TrainStep:
     # the scanned round writes the new pool over the old one: the pool is
     # DONATED (argnum 1), and the caller's ``params`` do not outlive the call
     @partial(jax.jit, static_argnums=0, donate_argnums=(1,),
-             static_argnames=("keep_client_params",))
+             static_argnames=("keep_client_params", "time_steps"))
     def _train_round_scan_jit(self, params, opt_states, key, x, y, time_w,
                               sample_w, feat_mask, lr_scale, client_mask=None,
-                              operands=StackOperands(), *,
-                              keep_client_params: bool = True):
+                              operands=StackOperands(), window_lo=None, *,
+                              keep_client_params: bool = True,
+                              time_steps: int | None = None):
         self._refuse_stack_users(opt_states, keep_client_params, operands)
+        if time_steps is not None:
+            x, y, time_w = time_window_of(x, y, time_w, window_lo, time_steps)
         return self._round_body_scan(
             params, opt_states, key, x, y, time_w, sample_w, feat_mask,
             lr_scale, client_mask)

@@ -1303,7 +1303,8 @@ class Experiment:
                     prev_params, opt_states, rkey, self.x, self.y, tw, sw,
                     fm, lr_scale, cm, operands,
                     keep_client_params=keep_cp, with_agg_stats=True,
-                    models_per_client=self.algo.models_per_client)
+                    models_per_client=self.algo.models_per_client,
+                    time_window=self.algo.time_window(t))
                 if cfg.trace_sync:
                     # attribute the device time to this phase instead of
                     # letting async dispatch spill it into whichever call
