@@ -3,8 +3,8 @@ one drive of everything in a run but the look for a chip (the driver's
 set-up, warm-up, window, reference and comparison), held to the cell's own
 limits; the faults that are planted under the timed path; and a temporary
 copy of ``benchmark/`` to which a family of the tests' own, its
-configurations and its cells are added as files, none that is there being
-edited."""
+configurations, its cells and a per-layer metric are added as files and
+appended manifest entries, none that is there being edited."""
 
 import functools
 import json
@@ -113,12 +113,31 @@ def door_cell(kind: str) -> str:
     return f"dense2_{kind}.ifca_perround"
 
 
+# a per-layer metric of the door's own: its entry lists one door cell, and its
+# reader is a file that only the copy has
+DOOR_METRIC = {"name": "door_rounds_per_time_step", "unit": "count",
+               "better": "lower", "source": "program_span", "layer": "runner",
+               "moves": "train_examples_per_s",
+               "workloads": [door_cell("sgd")]}
+DOOR_READER = '''"""Rounds a time step of the window: a reader that only
+the tests' copy of ``benchmark/`` has."""
+
+
+def read(records, trace, cell):
+    steps = records.get("time_steps") or []
+    if not steps:
+        return None
+    return sum(s["rounds"] for s in steps) / len(steps)
+'''
+
+
 def open_door(tmp: str, limits=DOOR_LIMITS) -> dict:
     """Copies ``benchmark/`` to ``tmp`` and ADDS, for each optimizer, a
     configuration ``sea_dense2_<kind>`` of the family ``dense2_fixture``
     (the program's ``fnn`` on ``sea``) and a cell of it under the traffic
-    ``ifca_perround``, with their manifest entries in a ``BENCHMARK.json``
-    beside it. Returns that manifest."""
+    ``ifca_perround``, and the per-layer metric ``DOOR_METRIC`` with its
+    reader ``metrics/<name>.py``, with their manifest entries appended in a
+    ``BENCHMARK.json`` beside it. Returns that manifest."""
     shutil.copytree(os.path.join(ROOT, "benchmark"),
                     os.path.join(tmp, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -147,6 +166,10 @@ def open_door(tmp: str, limits=DOOR_LIMITS) -> dict:
             "name": cell["name"], "config": config["name"],
             "traffic": "ifca_perround", "chips": 1,
             "why": "the door is open: files alone"})
+    with open(os.path.join(tmp, "benchmark", "metrics",
+                           f"{DOOR_METRIC['name']}.py"), "w") as f:
+        f.write(DOOR_READER)
+    manifest["per_layer"].append(dict(DOOR_METRIC))
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(manifest, f, indent=1)
     return manifest

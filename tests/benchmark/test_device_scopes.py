@@ -123,8 +123,12 @@ def test_the_resnet_cell_reports_none_of_the_shares_and_the_decoder_all():
     assert set(SHARES) <= set(per_cell["kanana2.ifca_perround"])
     assert "pairs_run_per_round" in per_cell["resnet20.ifca_perround"]
     assert "pairs_run_per_round" not in per_cell["kanana2.ifca_perround"]
-    assert len(per_cell["resnet20.ifca_perround"]) == 12
-    assert len(per_cell["kanana2.ifca_perround"]) == 18
+    # the accepted metrics, in order, begin what each cell reports; what is
+    # appended after them belongs to the PR that appended it
+    accepted = [m["name"] for m in MANIFEST["per_layer"][:19]]
+    assert per_cell["resnet20.ifca_perround"][:12] == \
+        accepted[:11] + ["pairs_run_per_round"]
+    assert per_cell["kanana2.ifca_perround"][:18] == accepted[:18]
     layers = {m["name"]: m["layer"] for m in MANIFEST["per_layer"]}
     assert [layers[n] for n in SHARES] == [
         "lm head", "expert layer", "latent attention", "round programs"]

@@ -207,19 +207,25 @@ def test_the_cells_entries_come_after_the_ones_that_were_there():
     the four shares of device time by scope and the resnet cell's
     ``pairs_run_per_round`` (PR 33), each after the accepted ones; the cell
     reports eighteen of the nineteen metrics, the accepted cell the eleven
-    it reported and its one."""
-    assert [c["name"] for c in MANIFEST["configs"]] == [
+    it reported and its one. The accepted entries are the first of each
+    list, in their order: what is appended after them belongs to the PR that
+    appended it (``test_door_manifest.py``)."""
+    assert [c["name"] for c in MANIFEST["configs"][:2]] == [
         "cifar10_resnet20", "kanana2_30b_a3b"]
-    assert [c["name"] for c in MANIFEST["workloads"]] == [
+    assert [c["name"] for c in MANIFEST["workloads"][:2]] == [
         "resnet20.ifca_perround", CELL]
-    names = [m["name"] for m in MANIFEST["per_layer"]]
+    names = [m["name"] for m in MANIFEST["per_layer"][:19]]
+    first = ["time_step_max_s", "drift_decision_ms",
+             "train_program_device_ms", "steady_compiles", "train_step_mfu",
+             "device_idle_share", "peak_hbm_gb", "peak_hbm_reserved_gb",
+             "runner_host_share", "dispatches_per_round",
+             "host_syncs_per_round"]
     new = ["pairs_trained_per_round", "held_expert_assignments_per_token",
            "eval_program_device_ms", "lm_head_device_share",
            "expert_layer_device_share", "attention_device_share",
            "outside_scopes_device_share"]
-    assert names[11:] == new + ["pairs_run_per_round"] and len(names) == 19
-    assert names[4] == "train_step_mfu"
-    for m in MANIFEST["per_layer"][11:]:
+    assert names == first + new + ["pairs_run_per_round"]
+    for m in MANIFEST["per_layer"][11:19]:
         assert m["workloads"] == [
             "resnet20.ifca_perround" if m["name"] == "pairs_run_per_round"
             else CELL]
@@ -237,9 +243,10 @@ def test_the_cells_entries_come_after_the_ones_that_were_there():
     assert MANIFEST["configs"][1]["source"] == CONFIG["source"]
     reported = [m["name"] for m in bench.metrics_of(MANIFEST, "per_layer",
                                                     CELL)]
-    assert reported == names[:18]                 # all but the resnet's
+    assert reported[:18] == names[:18]            # all but the resnet's
     old = bench.metrics_of(MANIFEST, "per_layer", "resnet20.ifca_perround")
-    assert [m["name"] for m in old] == names[:11] + ["pairs_run_per_round"]
+    assert [m["name"] for m in old][:12] == \
+        names[:11] + ["pairs_run_per_round"]
 
 
 def test_the_programs_module_still_names_the_familys_device_scopes():

@@ -59,7 +59,8 @@ def test_a_family_and_an_optimizer_that_arrive_as_files_run_to_correct(
         kind, door, tmp_path):
     """``run.py --rehearse`` of the temporary copy, with nothing of
     ``benchmark/`` edited: every file of the repo's is in the copy byte for
-    byte, and the copy has four files more."""
+    byte, and the copy has five files more (four data files and a metric's
+    reader)."""
     copy = str(tmp_path)
     p = subprocess.run(
         [sys.executable, "-c",
@@ -84,8 +85,9 @@ def test_a_family_and_an_optimizer_that_arrive_as_files_run_to_correct(
     added = _compare(os.path.join(bd.ROOT, "benchmark"),
                      os.path.join(copy, "benchmark"))
     assert sorted(added) == sorted(
-        os.path.join(sub, f"{stem}.json") for k in KINDS for sub, stem in
-        (("configs", f"sea_dense2_{k}"), ("cells", bd.door_cell(k))))
+        [os.path.join(sub, f"{stem}.json") for k in KINDS for sub, stem in
+         (("configs", f"sea_dense2_{k}"), ("cells", bd.door_cell(k)))]
+        + [os.path.join("metrics", f"{bd.DOOR_METRIC['name']}.py")])
 
 
 def _compare(ours, theirs, rel=""):
