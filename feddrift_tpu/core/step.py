@@ -218,6 +218,13 @@ class TrainStep:
     # the assignments its held experts got). Read by the scanned body alone,
     # which returns their sum over the round's training steps.
     stats_fn: Callable | None = None
+    # Optional (logits, y) -> loss and (logits, y) -> (hits, summed loss,
+    # labels): a model's own training objective and evaluation counts
+    # (models/sdar_moe.py: the masked-diffusion loss, whose labels are the
+    # masked positions). None: mean cross-entropy, a hit and a label per
+    # label of ``y``.
+    objective: Callable | None = None
+    eval_counts: Callable | None = None
     # Optional device mesh (parallel/mesh.py). When it names a "models"
     # and/or "clients" axis, the megastep program annotates its carry
     # params / opt states / time-weight slices with with_sharding_constraint
@@ -378,14 +385,17 @@ class TrainStep:
             # token ids take no multiplicative feature mask
             return xb if jnp.issubdtype(xb.dtype, jnp.integer) else xb * fmask
 
+        objective = cross_entropy if self.objective is None \
+            else self.objective
+
         def loss_fn(p, xb, yb):
-            return cross_entropy(self.apply_fn(p, feature_masked(xb)), yb)
+            return objective(self.apply_fn(p, feature_masked(xb)), yb)
 
         grad_fn = jax.value_and_grad(loss_fn)
         if with_stats:
             def loss_stats(p, xb, yb):
                 logits, stats = self.stats_fn(p, feature_masked(xb))
-                return cross_entropy(logits, yb), stats
+                return objective(logits, yb), stats
             grad_fn = jax.value_and_grad(loss_stats, has_aux=True)
 
         def step(carry, k):
@@ -657,8 +667,9 @@ class TrainStep:
     @property
     def fuses_rounds(self) -> bool:
         """Whether the fused programs (``train_iteration_eval``,
-        ``train_megastep``) exist for this step: their body is vmap's."""
-        return self.client_axis != "scan"
+        ``train_megastep``) exist for this step: their body is vmap's, and
+        they count a label per label of ``y`` (no ``eval_counts``)."""
+        return self.client_axis != "scan" and self.eval_counts is None
 
     def _round_program(self, num_models: int, models_per_client,
                        keep_client_params: bool, operands):
@@ -1177,11 +1188,14 @@ class TrainStep:
 
     def _acc_matrix_body(self, params, x, y, feat_mask):
         """Hits and summed loss count labels: one per sample, or one per
-        token where ``y`` is [C, N, L]; ``total`` is a client's labels."""
+        token where ``y`` is [C, N, L], or those the model's
+        ``eval_counts`` counts; ``total`` is a client's labels."""
         def one(p_m, f_m):
             def per_client(xc, yc):
                 xin = xc * f_m if xc.dtype != jnp.int32 else xc
                 logits = self.apply_fn(p_m, xin)
+                if self.eval_counts is not None:
+                    return self.eval_counts(logits, yc)
                 logp = jax.nn.log_softmax(logits)
                 nll = -jnp.take_along_axis(logp, yc[..., None], axis=-1).sum()
                 return (logits.argmax(-1) == yc).sum(), nll
@@ -1196,16 +1210,20 @@ class TrainStep:
                 def chunked(xc_yc):
                     xc, yc = (a.reshape((N // B, B) + a.shape[1:])
                               for a in xc_yc)
-                    hits, nll = jax.lax.map(lambda ab: per_client(*ab),
-                                            (xc, yc))
-                    return hits.sum(), nll.sum()
+                    counts = jax.lax.map(lambda ab: per_client(*ab),
+                                         (xc, yc))
+                    return tuple(a.sum() for a in counts)
                 return jax.lax.map(chunked, (x, y))
             return jax.vmap(per_client)(x, y)
         if self.client_axis == "scan":
-            correct, loss_sum = jax.lax.map(lambda pf: one(*pf),
-                                            (params, feat_mask))
+            counts = jax.lax.map(lambda pf: one(*pf), (params, feat_mask))
         else:
-            correct, loss_sum = jax.vmap(one)(params, feat_mask)
+            counts = jax.vmap(one)(params, feat_mask)
+        if self.eval_counts is not None:
+            # a client's labels, the same under every model
+            correct, loss_sum, labels = counts
+            return correct, loss_sum, labels[0]
+        correct, loss_sum = counts
         labels = x.shape[1] * math.prod(y.shape[2:])
         total = jnp.full((x.shape[0],), labels, dtype=jnp.int32)
         return correct, loss_sum, total

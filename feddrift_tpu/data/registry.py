@@ -104,6 +104,18 @@ def _mk_tokens(cfg: ExperimentConfig, change_points: np.ndarray) -> DriftDataset
         seq_len=cfg.text_seq_len, vocab=cfg.token_vocab)
 
 
+@register_dataset("token_diffusion")
+def _mk_token_diffusion(cfg: ExperimentConfig,
+                        change_points: np.ndarray) -> DriftDataset:
+    # block-diffusion data: token_drift's law over the first token_vocab - 1
+    # ids, a noisy and a clean copy, blocks of text.py's DIFFUSION_BLOCK
+    from feddrift_tpu.data.text import generate_token_diffusion
+    return generate_token_diffusion(
+        change_points, cfg.train_iterations, cfg.client_num_in_total,
+        cfg.sample_num, cfg.time_stretch, cfg.seed,
+        seq_len=cfg.text_seq_len, vocab=cfg.token_vocab)
+
+
 @register_dataset("susy", "ro")
 def _mk_uci(cfg: ExperimentConfig, change_points: np.ndarray) -> DriftDataset:
     from feddrift_tpu.data.tabular import generate_uci_drift

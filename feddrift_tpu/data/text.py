@@ -413,3 +413,55 @@ def generate_token_drift(
         concepts=concepts, name="token_drift", is_sequence=True,
         meta={"vocab": vocab, "seq_len": seq_len,
               "zipf_exponent": zipf_exponent, "follow_prob": follow_prob})
+
+
+NOISE_LEVELS = 1000      # t = (level + 1) / NOISE_LEVELS: U[1e-3, 1] on a grid
+DIFFUSION_BLOCK = 4      # tokens a block: the block of SDAR's chat models
+
+
+def generate_token_diffusion(
+    change_points: np.ndarray,
+    train_iterations: int,
+    num_clients: int,
+    sample_num: int,
+    time_stretch: int = 1,
+    seed: int = 0,
+    seq_len: int = SEQ_LEN,
+    vocab: int = VOCAB_SIZE,
+    zipf_exponent: float = ZIPF_EXPONENT,
+    follow_prob: float = FOLLOW_PROB,
+) -> DriftDataset:
+    """Block-diffusion data for a decoder: ``x`` [C, T+1, N, 2L] token ids,
+    a noisy copy x_t followed by the clean copy x_0, and ``y`` [C, T+1, N,
+    L], a packed label a noisy position.
+
+    x_0 is ``generate_token_drift``'s law (the same concepts and drift) over
+    the first ``vocab - 1`` ids; id ``vocab - 1`` is the mask. Each block of
+    ``DIFFUSION_BLOCK`` tokens of a sequence draws a noise level t uniformly
+    from {1, ..., NOISE_LEVELS} / NOISE_LEVELS, and each of its tokens is
+    replaced by the mask with probability t. The label of a masked position is
+    ``clean id + vocab x (level)``, where t = (level + 1) / NOISE_LEVELS, and
+    -1 at the others (``models/sdar_moe.py::unpack_labels``). The noise is
+    drawn here once, from the seed, and not afresh at each step.
+    """
+    block = DIFFUSION_BLOCK
+    if seq_len % block:
+        raise ValueError(f"seq_len {seq_len} is not a whole number of "
+                         f"blocks of {block}")
+    clean = generate_token_drift(
+        change_points, train_iterations, num_clients, sample_num,
+        time_stretch, seed, seq_len=seq_len, vocab=vocab - 1,
+        zipf_exponent=zipf_exponent, follow_prob=follow_prob)
+    x0 = clean.x
+    rng = np.random.default_rng([seed, 0xB10C])
+    level = np.repeat(rng.integers(0, NOISE_LEVELS,
+                                   size=x0.shape[:-1] + (seq_len // block,)),
+                      block, axis=-1)
+    masked = rng.random(x0.shape) < (level + 1) / NOISE_LEVELS
+    x = np.concatenate([np.where(masked, vocab - 1, x0), x0], axis=-1)
+    y = np.where(masked, x0 + vocab * level, -1)
+    return DriftDataset(
+        x=x.astype(np.int32), y=y.astype(np.int32), num_classes=vocab,
+        concepts=clean.concepts, name="token_diffusion", is_sequence=True,
+        meta={**clean.meta, "vocab": vocab, "diffusion_block": block,
+              "mask_id": vocab - 1, "noise_levels": NOISE_LEVELS})

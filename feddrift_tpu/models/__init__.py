@@ -136,6 +136,30 @@ def _mla_moe(ds: DriftDataset, cfg) -> nn.Module:
     return MLAMoEDecoder(preset=cfg.model, remat=cfg.remat)
 
 
+@register_model("sdar_30b_a3b_chat", "sdar_moe_tiny")
+def _sdar_moe(ds: DriftDataset, cfg) -> nn.Module:
+    """A block-diffusion, sparse-expert decoder preset (the model's name is
+    the preset's); the data set gives the noisy and clean copies, a packed
+    label a noisy position, over the held rows of the vocabulary, the last
+    of them the mask id, and blocks of the preset's length."""
+    from feddrift_tpu.models.sdar_moe import PRESETS, SDARDecoder, check_heads
+    z = PRESETS[cfg.model]
+    check_heads(cfg.model)
+    # a data set of shapes alone (a sizing compile) states no block
+    block = ds.meta.get("diffusion_block", z["block_length"])
+    if ds.num_classes != z["vocab_rows_held"] \
+            or block != z["block_length"] \
+            or ds.x.shape[3:] != (2 * ds.y.shape[3],):
+        raise ValueError(
+            f"model {cfg.model!r} holds {z['vocab_rows_held']} rows of its "
+            f"vocabulary and denoises blocks of {z['block_length']} over a "
+            f"noisy and a clean copy; data set {ds.name!r} has "
+            f"{ds.num_classes} ids, blocks of {block} and samples of "
+            f"{ds.x.shape[3:]} for labels of {ds.y.shape[3:]} (use dataset "
+            f"'token_diffusion')")
+    return SDARDecoder(preset=cfg.model, remat=cfg.remat)
+
+
 @register_model("rnn")
 def _rnn(ds: DriftDataset, cfg) -> nn.Module:
     return CharLSTM(vocab_size=ds.num_classes)

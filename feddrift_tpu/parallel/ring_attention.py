@@ -57,12 +57,46 @@ def _block_attn(q, k, v, acc, m, l, q_off, k_off, causal: bool, scale: float,
     return acc_new, m_new, l_new
 
 
+def block_diffusion_mask(half: int, block: int):
+    """Whether query position i may see key position j, over a noisy copy
+    (positions 0..half-1) followed by a clean one (half..2 half-1), where
+    the block of a position is ``(i mod half) // block``: a noisy query sees
+    the noisy keys of its own block and the clean keys of earlier blocks; a
+    clean query sees the clean keys of its own and earlier blocks and never
+    a noisy one. Elementwise over broadcast position arrays."""
+    def live(qpos, kpos):
+        q_noisy, k_noisy = qpos < half, kpos < half
+        qb, kb = (qpos % half) // block, (kpos % half) // block
+        return jnp.where(q_noisy, jnp.where(k_noisy, kb == qb, kb < qb),
+                         ~k_noisy & (kb <= qb))
+    return live
+
+
+def live_pairs(half: int, block: int) -> int:
+    """(query, key) pairs that ``block_diffusion_mask`` lets through, a
+    head: the noisy copy's own blocks (half x block), noisy queries on the
+    clean blocks before theirs and clean queries on their own and earlier
+    clean blocks (half ** 2 together)."""
+    return half * block + half * half
+
+
 def blockwise_attention(q, k, v, *, causal: bool = True,
-                        block_size: int = 512) -> jnp.ndarray:
+                        block_size: int = 512,
+                        diffusion_block: int = 0) -> jnp.ndarray:
     """Single-device flash-style attention via lax.scan over key blocks.
 
     ``v`` may be narrower or wider than ``q`` and ``k`` ([B, H, L, Dv]): the
-    result has the value's width, the scores are scaled by the key's."""
+    result has the value's width, the scores are scaled by the key's.
+    ``diffusion_block`` > 0 takes the positions as a noisy and a clean copy
+    of L / 2 each and masks by ``block_diffusion_mask`` with blocks of that
+    length, in place of ``causal``; the mask is computed a key block at a
+    time from the positions. Then ``k`` and ``v`` may hold fewer heads than
+    ``q`` ([B, KV, L, D], KV dividing H): query head h reads key/value head
+    h // (H / KV), grouped-query attention with no copy of a key head."""
+    if diffusion_block:
+        return _masked_blockwise(
+            q, k, v, block_diffusion_mask(q.shape[2] // 2, diffusion_block),
+            block_size)
     B, H, L, D = q.shape
     Dv = v.shape[-1]
     scale = 1.0 / jnp.sqrt(D).astype(q.dtype)
@@ -91,6 +125,48 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
     (acc, m, l), _ = lax.scan(step, (acc, m, l),
                               (k_blocks, v_blocks, jnp.arange(nblocks)))
     return acc / jnp.maximum(l[..., None], 1e-30)
+
+
+def _masked_blockwise(q, k, v, live, block_size: int) -> jnp.ndarray:
+    """``blockwise_attention`` under the mask ``live(qpos, kpos)``, with a
+    query head's group folded into the query axis: [B, KV, G x L, D]
+    against each key/value head once. A score the mask drops adds nought to
+    the running sum, whatever block it falls in."""
+    B, H, L, D = q.shape
+    KV, Dv = k.shape[1], v.shape[-1]
+    G = H // KV
+    q = q.reshape(B, KV, G * L, D)
+    qpos = jnp.tile(jnp.arange(L), G)[:, None]
+    scale = 1.0 / jnp.sqrt(D).astype(q.dtype)
+    bs = min(block_size, L)
+    nblocks = -(-L // bs)
+    pad = nblocks * bs - L
+    if pad:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    k_blocks = k.reshape(B, KV, nblocks, bs, D).transpose(2, 0, 1, 3, 4)
+    v_blocks = v.reshape(B, KV, nblocks, bs, Dv).transpose(2, 0, 1, 3, 4)
+
+    def step(carry, inp):
+        acc, m, l = carry
+        kb, vb, b_idx = inp
+        kpos = (b_idx * bs + jnp.arange(bs))[None, :]
+        ok = (kpos < L) & live(qpos, kpos)
+        s = jnp.where(ok, jnp.einsum("bhqd,bhkd->bhqk", q, kb) * scale,
+                      NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        correction = jnp.exp(m - m_new)
+        return (acc * correction[..., None]
+                + jnp.einsum("bhqk,bhkd->bhqd", p, vb),
+                m_new, l * correction + p.sum(axis=-1)), None
+
+    acc = jnp.zeros((B, KV, G * L, Dv), q.dtype)
+    m = jnp.full((B, KV, G * L), NEG_INF, dtype=q.dtype)
+    l = jnp.zeros((B, KV, G * L), dtype=q.dtype)
+    (acc, m, l), _ = lax.scan(step, (acc, m, l),
+                              (k_blocks, v_blocks, jnp.arange(nblocks)))
+    return (acc / jnp.maximum(l[..., None], 1e-30)).reshape(B, H, L, Dv)
 
 
 def ring_attention(q, k, v, *, axis_name: str,

@@ -136,6 +136,10 @@ class Experiment:
             # also returns the model's own counts, where it gives any
             client_axis=cfg.client_axis,
             stats_fn=self._make_apply(stats=True),
+            # the model's own objective and evaluation counts, where it
+            # declares them (models/sdar_moe.py); else a cross-entropy a label
+            objective=getattr(self.module, "objective", None),
+            eval_counts=getattr(self.module, "eval_counts", None),
             # The megastep program annotates its [M, C, ...] stacks with
             # with_sharding_constraint over this mesh (no-op on 1-D/1-device
             # meshes — parallel/mesh.py::constrain_pool).
@@ -551,14 +555,15 @@ class Experiment:
             [t, t + 1] if spec is None else [t], fm)
         correct, loss_sum, total = fetched[0]
         if spec is None:
-            corr_te, loss_te, _ = fetched[1]
+            corr_te, loss_te, total_te = fetched[1]
         correct = correct[:, :C]
         loss_sum = loss_sum[:, :C]
         total = total[:C]
 
         if spec is None:
             return self._log_eval(t, correct, loss_sum,
-                                  corr_te[:, :C], loss_te[:, :C], total)
+                                  corr_te[:, :C], loss_te[:, :C], total,
+                                  total_te[:C])
 
         tidx = self.algo.train_model_idx(t)                    # [C]
         idx = self.algo.test_model_idx(t)                      # [C]
@@ -580,15 +585,17 @@ class Experiment:
                                  ec[:C], el[:C], et[:C])
 
     def _log_eval(self, t: int, correct, loss_sum, corr_te, loss_te,
-                  total) -> dict:
+                  total, total_te=None) -> dict:
         """Log one eval point from host-side [M, C]/[C] numpy matrices
-        (the non-ensemble test path shared by every execution mode)."""
+        (the non-ensemble test path shared by every execution mode).
+        ``total_te``: the test half's labels where they are not the train
+        half's (a model that counts its own labels); None: the same."""
         tidx = self.algo.train_model_idx(t)                    # [C]
         idx = self.algo.test_model_idx(t)                      # [C]
         cr = np.arange(self.C_)
         return self._log_metrics(t, idx, correct[tidx, cr], loss_sum[tidx, cr],
                                  total, corr_te[idx, cr], loss_te[idx, cr],
-                                 total)
+                                 total if total_te is None else total_te)
 
     def _log_metrics(self, t: int, idx, train_correct, train_loss, total,
                      tcorrect, tloss, ttotal) -> dict:
@@ -1145,6 +1152,11 @@ class Experiment:
                     float(loads.max() / loads.mean()), 4)
                 reg.gauge("expert_load_max_over_mean").set(
                     args["expert_load_max_over_mean"])
+        # a block-diffusion model's counts (models/sdar_moe.py): noisy and
+        # masked positions, live (query, key) pairs of its attention
+        for key in sorted(k for k in counts if k.startswith("bd_")):
+            args[key] = int(counts[key])
+            reg.counter(key).inc(args[key])
         span.set(**args)
 
     def _byz_modes(self, rounds, t: int) -> "np.ndarray | None":
