@@ -48,7 +48,7 @@ from jax import lax
 from feddrift_tpu.data.text import NOISE_LEVELS
 from feddrift_tpu.models.mla_moe import BLOCK_ROWS, rms_norm, routed_experts
 from feddrift_tpu.parallel.ring_attention import (blockwise_attention,
-                                                  live_pairs)
+                                                  live_pairs, pairs_computed)
 
 PRESETS: dict[str, dict] = {
     # https://huggingface.co/JetLM/SDAR-30B-A3B-Chat/blob/main/config.json
@@ -74,6 +74,9 @@ PRESETS: dict[str, dict] = {
 }
 
 _INIT = nn.initializers.normal(0.02)
+# positions a tile of the attention's queries and keys
+# (parallel/ring_attention.py::_block_diffusion_attention)
+ATTENTION_TILE = 512
 
 
 def unpack_labels(y, rows: int):
@@ -152,7 +155,8 @@ class BlockDiffusionAttention(nn.Module):
             # the softmax's running maximum and sum in float32
             o = blockwise_attention(
                 *(a.astype(jnp.float32).transpose(0, 2, 1, 3)
-                  for a in (q, k, v)), diffusion_block=self.block)
+                  for a in (q, k, v)), diffusion_block=self.block,
+                block_size=ATTENTION_TILE)
             o = o.transpose(0, 2, 1, 3).reshape(B, P, H * d).astype(u.dtype)
             return o @ wo
 
@@ -221,7 +225,9 @@ class SDARDecoder(nn.Module):
     gives them (over all 2L positions), and ``bd_noisy_positions``,
     ``bd_masked_positions`` (noisy positions that hold the mask id) and
     ``bd_live_attention_pairs`` ((query, key) pairs the mask lets through,
-    a head and a layer, over the sequences)."""
+    a head and a layer, over the sequences) and
+    ``bd_attention_pairs_computed`` (the pairs the attention forms, counted
+    alike)."""
     preset: str = "sdar_30b_a3b_chat"
     remat: bool = True
 
@@ -286,7 +292,10 @@ class SDARDecoder(nn.Module):
             "bd_masked_positions": (tokens[:, :L] == V - 1).sum(
                 dtype=jnp.int32),
             "bd_live_attention_pairs": jnp.asarray(
-                B * live_pairs(L, z["block_length"]), jnp.int32)}
+                B * live_pairs(L, z["block_length"]), jnp.int32),
+            "bd_attention_pairs_computed": jnp.asarray(
+                B * pairs_computed(L, z["block_length"], ATTENTION_TILE),
+                jnp.int32)}
 
 
 def parameter_count(preset: str) -> int:

@@ -22,10 +22,12 @@ from benchmark.run import load_json, overlay                     # noqa: E402
 from feddrift_tpu.data.text import (generate_token_diffusion,    # noqa: E402
                                     generate_token_drift)
 from feddrift_tpu.models import sdar_moe                          # noqa: E402
-from feddrift_tpu.models.sdar_moe import (BlockDiffusionAttention,  # noqa: E402
+from feddrift_tpu.models.sdar_moe import (ATTENTION_TILE,  # noqa: E402
+                                          BlockDiffusionAttention,
                                           SDARDecoder, SoftmaxExperts)
 from feddrift_tpu.parallel.ring_attention import (  # noqa: E402
-    block_diffusion_mask, blockwise_attention, live_pairs)
+    _tile_lanes, block_diffusion_mask, blockwise_attention, clean_key_tiles,
+    live_pairs, pairs_computed)
 
 CONFIG = load_json("configs", "sdar_30b_a3b_chat.json")
 TINY = overlay(CONFIG, CONFIG["rehearse"])["arch"]
@@ -54,6 +56,11 @@ def test_noisy_logits_and_counts_agree_with_the_plain_reference():
     assert int(stats["bd_masked_positions"]) == int((y >= 0).sum()) \
         == int((x[:, :L] == ROWS - 1).sum())
     assert int(stats["bd_live_attention_pairs"]) == 2 * (L * L + L * BLOCK)
+    # one tile of 16 positions: both copies' queries on the clean keys, and
+    # the noisy copy's own blocks
+    assert int(stats["bd_attention_pairs_computed"]) \
+        == 2 * pairs_computed(L, BLOCK, ATTENTION_TILE) \
+        == 2 * (2 * L * L + L * BLOCK)
     own = module.init(jax.random.PRNGKey(0), x)["params"]
     assert jax.tree_util.tree_structure(own) \
         == jax.tree_util.tree_structure(tree)
@@ -160,10 +167,14 @@ def test_live_pairs_are_counted_as_the_mask_has_them():
                                                                   abs=1e-5)
 
 
-@pytest.mark.parametrize("kv,block_size", [(1, 8), (2, 16), (4, 12)])
+@pytest.mark.parametrize("kv,block_size", [(1, 8), (2, 16), (4, 12), (1, 6),
+                                           (2, 10), (4, 3), (1, 2)])
 def test_blockwise_attention_under_the_mask_with_grouped_heads(kv, block_size):
     """Query head h reads key/value head h // (H / KV); the forward and the
-    gradients against whole rows under the dense mask."""
+    gradients against whole rows under the dense mask. Tiles of 6, 10 and
+    3 positions are no whole number of blocks of 4, so a block straddles
+    two tiles and a query tile reads the key tile after its own; 3 and 2
+    make an odd and an even number of tiles narrower than a block."""
     H, D = 4, 8
     ks = jax.random.split(jax.random.PRNGKey(kv), 3)
     q = jax.random.normal(ks[0], (2, H, 2 * L, D))
@@ -187,6 +198,40 @@ def test_blockwise_attention_under_the_mask_with_grouped_heads(kv, block_size):
             jax.grad(lambda *a: (mine(*a) * w).sum(), (0, 1, 2))(q, k, v),
             jax.grad(lambda *a: (dense(*a) * w).sum(), (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(g_mine, g_dense, rtol=1e-4, atol=1e-4)
+
+
+def test_the_tiles_formed_hold_every_live_pair_at_the_cells_size():
+    """The mask evaluated in numpy at the cell's size (L = 4,096, blocks of
+    4, tiles of 512): a noisy query's live noisy keys are its own block's,
+    which the block product forms; a clean query sees no noisy key; every
+    (query tile, clean key tile) that holds a live pair is a live step of
+    the scan's lanes, once, and no other is; the pairs formed are
+    ``pairs_computed``, which the model counts."""
+    half, block, tile = 4096, 4, ATTENTION_TILE
+    n = half // tile
+    t_q, t_k, start, live, ends = _tile_lanes(
+        clean_key_tiles(half, block, tile))
+    formed = sorted(zip(t_q[live].tolist(), t_k[live].tolist()))
+    assert len(formed) == len(set(formed)) == n * (n + 1) // 2 == 36
+    assert t_q.shape == (n + 1, n // 2) and live.all()
+    key = np.arange(2 * half)
+    needed = set()
+    for t in range(2 * n):                   # a tile of either copy's queries
+        i = np.arange(t * tile, (t + 1) * tile)[:, None]
+        bi, bj = (i % half) // block, (key % half) // block
+        noisy_i, noisy_j = i < half, key < half
+        seen = np.where(noisy_i, np.where(noisy_j, bj == bi, bj < bi),
+                        ~noisy_j & (bj <= bi))
+        np.testing.assert_array_equal(seen[:, :half],
+                                      noisy_i & (bj == bi)[:, :half])
+        live_tiles = seen[:, half:].reshape(tile, n, tile).any(axis=(0, 2))
+        needed |= {(t % n, u) for u in np.flatnonzero(live_tiles).tolist()}
+    assert sorted(needed) == formed
+    computed = half * block + 2 * tile * tile * len(formed)
+    assert computed == pairs_computed(half, block, tile) == 18_890_752
+    assert computed / (2 * half) ** 2 == pytest.approx(0.2815, abs=1e-4)
+    assert computed / live_pairs(half, block) == pytest.approx(1.125,
+                                                               abs=1e-3)
 
 
 # ----------------------------------------------------------------------
@@ -400,6 +445,8 @@ def test_the_experiment_trains_and_evaluates_on_masked_positions():
     assert 0.2 < share < 0.8
     # 2 time steps x 2 rounds x 2 clients x 2 local steps x 2 sequences
     assert snap["bd_live_attention_pairs"] == 32 * live_pairs(L, BLOCK)
+    assert snap["bd_attention_pairs_computed"] \
+        == 32 * pairs_computed(L, BLOCK, ATTENTION_TILE)
     assert not exp.step.fuses_rounds
     correct, _, total = exp.step.acc_matrix(
         exp.pool.params, exp.x[:, 2], exp.y[:, 2],
