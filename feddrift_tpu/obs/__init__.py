@@ -29,6 +29,9 @@ were all invisible. This package is the missing observability layer:
 - ``obs.spans``       — wall-clock span recording (``spans.jsonl``) and
   the Chrome-trace-event exporter behind ``report <run_dir> --trace``
   (Perfetto-loadable ``trace.json``, one lane per process/thread).
+- ``obs.compile_spans`` — JAX's trace, lowering and compile (or cache
+  load) of every program as ``cat="compile"`` spans on the span recorder,
+  from ``jax.monitoring``; imports JAX, so it is not bound here.
 - ``obs.regress``     — the perf-regression gate over bench artifacts
   (CLI: ``python -m feddrift_tpu regress <bench.json> --baseline ...``).
 - ``obs.lineage``     — cluster genealogy DAG reconstruction + oracle

@@ -125,7 +125,6 @@ def analyze(run_dir: str) -> dict[str, Any]:
             "dominant": dominant,
             "coverage": round(seg_sum / wall, 4) if wall else None,
             "host_overhead_frac": bd.get("host_overhead_frac") if bd else None,
-            "profiled_rounds": bd.get("profiled_rounds") if bd else None,
             "culprits": culprits.get(it, []),
         })
         if wall:

@@ -88,6 +88,8 @@ class _NullSpan:
 
     __slots__ = ()
     dur = 0.0
+    name = None
+    args: dict = {}
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -115,6 +117,15 @@ class _Span:
         self._rec, self._name, self._cat, self._args = rec, name, cat, args
         self._frame = self._ann = None
         self.dur = 0.0
+
+    @property
+    def name(self) -> str:
+        return self._name
+
+    @property
+    def args(self) -> dict:
+        """The ``args`` it will be recorded with, so far."""
+        return self._args
 
     def set(self, **args: Any) -> None:
         """Arguments known only at the end of the interval."""
@@ -190,6 +201,11 @@ class SpanRecorder:
     def wall(self, perf: float) -> float:
         """Unix seconds of a ``time.perf_counter()`` reading."""
         return self._epoch + perf
+
+    @property
+    def context(self) -> dict[str, Any]:
+        """The ambient ``args`` (``set_context``); not to be mutated."""
+        return self._context
 
     def set_context(self, **ctx: Any) -> None:
         """Ambient ``args`` of every later ``span()``; None removes a key.

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from feddrift_tpu import obs
+from feddrift_tpu.obs import compile_spans
 from feddrift_tpu.algorithms import algorithm_class, make_algorithm
 from feddrift_tpu.comm import multihost
 from feddrift_tpu.config import ExperimentConfig
@@ -73,145 +74,12 @@ class Experiment:
 
     def __init__(self, cfg: ExperimentConfig, mesh=None,
                  use_wandb: bool = False, out_dir: Optional[str] = None) -> None:
+        import os
         self.cfg = cfg
-        self.ds = make_dataset(cfg)
-        self.module = create_model(cfg.model, self.ds, cfg)
-        # cfg.mesh_shape (e.g. {"models": 2, "clients": 4}) selects the 2-D
-        # layout; empty dict = legacy 1-D clients mesh over all devices.
-        self.mesh = mesh if mesh is not None \
-            else make_mesh(shape=cfg.mesh_shape or None)
-        # End-to-end precision policy (core/precision.py): resolved ONCE
-        # here — "auto" reproduces the legacy dtype/compute_dtype behavior
-        # (bf16 apply boundary on TPU only), explicit presets apply on any
-        # backend. The pool is created AT param_dtype, so a bf16 policy is
-        # bf16 from the first stored leaf (optimizer moments follow).
-        self.precision = resolve_precision(cfg)
-        self.pool = ModelPool.create(self.module, _sample_input(self.ds),
-                                     cfg.num_models, seed=cfg.seed + 42,
-                                     param_dtype=self.precision.param_dtype)
-        # Commit the pool to the mesh (replicated) up front: every jitted
-        # step consumes COMMITTED x/y (shard_client_arrays), so its param
-        # outputs come back committed to a NamedSharding — if the t=0
-        # params were left uncommitted, t=1 would present a new sharding
-        # signature and silently recompile the whole iteration program.
-        self.pool.params = replicate(self.mesh, self.pool.params)
-        from feddrift_tpu.resilience.robust_agg import RobustAggConfig
-        self.step = TrainStep(
-            apply_fn=self._make_apply(),
-            optimizer=make_optimizer(cfg.client_optimizer, cfg.lr, cfg.wd),
-            batch_size=cfg.batch_size,
-            num_steps=cfg.epochs,
-            num_classes=self.ds.num_classes,
-            # Static: algorithms declare the Poisson-bootstrap trait
-            # (Kue.uses_sample_weights); everyone else skips the expensive
-            # flattened-categorical batch draw entirely.
-            weighted_sampling=algorithm_class(
-                cfg.concept_drift_algo).uses_sample_weights,
-            # Static: the per-cluster aggregation strategy closing every
-            # round (resilience/robust_agg.py; "mean" = historical FedAvg).
-            robust_agg=cfg.robust_agg,
-            robust_cfg=RobustAggConfig(
-                trim_frac=cfg.robust_trim_frac, krum_f=cfg.robust_krum_f,
-                clip_norm=cfg.robust_clip_norm,
-                dp_stddev=cfg.robust_dp_stddev),
-            byz_scale=cfg.byzantine_scale,
-            byz_std=cfg.byzantine_std,
-            # Static: two-tier hierarchical aggregation + in-program wire
-            # codec simulation (platform/hierarchical.py, comm/compress.py).
-            hier_edges=cfg.hierarchy_edges,
-            edge_agg=cfg.edge_robust_agg,
-            server_agg=cfg.server_robust_agg,
-            codec=cfg.compress_codec,
-            codec_topk_frac=cfg.compress_topk_frac,
-            # Static: the resolved precision policy — drives the in-program
-            # aggregation boundary (agg_dtype) and eval-buffer dtypes.
-            precision=self.precision,
-            # Static: XLA cost-capture level (obs/costmodel.py) — each
-            # tracked program's first compile also harvests cost_analysis
-            # (and memory_analysis under "compiled") into program_cost
-            # events + gauges.
-            cost_capture=cfg.cost_model,
-            # Static: vmap over all (model, client) pairs, or the pairs one
-            # at a time (core/step.py::_round_body_scan); the forward that
-            # also returns the model's own counts, where it gives any
-            client_axis=cfg.client_axis,
-            stats_fn=self._make_apply(stats=True),
-            # the model's own objective and evaluation counts, where it
-            # declares them (models/sdar_moe.py); else a cross-entropy a label
-            objective=getattr(self.module, "objective", None),
-            eval_counts=getattr(self.module, "eval_counts", None),
-            # The megastep program annotates its [M, C, ...] stacks with
-            # with_sharding_constraint over this mesh (no-op on 1-D/1-device
-            # meshes — parallel/mesh.py::constrain_pool).
-            mesh=self.mesh,
-        )
-        # Device-resident dataset, client axis sharded over the mesh. The
-        # client axis is padded to a multiple of the mesh size with phantom
-        # clients whose time weights stay zero — they train masked and
-        # contribute n=0 to aggregation, so results are identical.
-        # Population mode flips the residency story: the dataset covers the
-        # whole registered population HOST-side, and only the sampled
-        # cohort's shard is staged into the fixed-shape [C_pad, T1, N, ...]
-        # device stacks each iteration (_prepare_cohort) — XLA program
-        # shapes depend on the cohort, never on the population.
-        self.population_mode = cfg.population_size > 0
-        # Pad the client axis to the CLIENTS mesh-axis size: on a 2-D
-        # (models, clients) mesh only the clients dimension shards data.
-        n_dev = dict(self.mesh.shape).get("clients", self.mesh.devices.size)
-        C = cfg.device_clients
-        self.C_pad = ((C + n_dev - 1) // n_dev) * n_dev
-        pad = self.C_pad - C
-        x_np, y_np = self.ds.x, self.ds.y
-        if self.population_mode:
-            self._x_pop, self._y_pop = x_np, y_np
-            self.x = self.y = None
-        elif cfg.stream_data:
-            if pad:
-                x_np = np.concatenate([x_np, np.repeat(x_np[:1], pad, 0)],
-                                      axis=0)
-                y_np = np.concatenate([y_np, np.repeat(y_np[:1], pad, 0)],
-                                      axis=0)
-            # host-resident: only a [C, 2, N, ...] window (current + next
-            # step) is staged into HBM per iteration (data/prefetch.py)
-            self._x_host, self._y_host = x_np, y_np
-            self.x = self.y = None
-            self._view_iter = None
-            self._view_next_t = -1
-        else:
-            if pad:
-                x_np = np.concatenate([x_np, np.repeat(x_np[:1], pad, 0)],
-                                      axis=0)
-                y_np = np.concatenate([y_np, np.repeat(y_np[:1], pad, 0)],
-                                      axis=0)
-            self.x = shard_client_arrays(self.mesh, jnp.asarray(x_np))
-            self.y = shard_client_arrays(self.mesh, jnp.asarray(y_np))
-        self.algo = make_algorithm(cfg, self.ds, self.pool, self.step)
-        if self.population_mode and not getattr(self.algo, "supports_cohort",
-                                                False):
-            raise ValueError(
-                f"population_size > 0 needs a cohort-capable algorithm "
-                f"(per-client state expressible as registry columns); "
-                f"{cfg.concept_drift_algo!r}/{cfg.concept_drift_algo_arg!r} "
-                f"is not")
-        if cfg.stream_data and not self.algo.supports_streaming:
-            raise ValueError(
-                f"stream_data requires a current-step-window algorithm "
-                f"(supports_streaming); {cfg.concept_drift_algo!r} trains on "
-                f"past steps or reads the full dataset")
         # Multi-controller runs: every process computes metrics (host logic
         # must stay in lockstep) but only the coordinator touches disk/wandb.
         self.is_coordinator = multihost.is_coordinator()
-        self.logger = MetricsLogger(out_dir if self.is_coordinator else None,
-                                    use_wandb and self.is_coordinator)
-        # Structured event bus: events.jsonl next to metrics.jsonl. The bus
-        # is process-local so comm-broker threads and the fault injector
-        # reach it without a handle on this object.
-        import os
         obs_cap = int(cfg.obs_max_file_mb * (1 << 20))   # 0 = unbounded
-        self.events = obs.configure(
-            os.path.join(out_dir, "events.jsonl")
-            if (out_dir and self.is_coordinator) else None,
-            max_bytes=obs_cap)
         # Span recorder: wall-clock intervals (phases, iterations, comm
         # publishes) next to the event stream; `report <run_dir> --trace`
         # folds both into one Perfetto-loadable trace.json. Every process
@@ -220,260 +88,422 @@ class Experiment:
         # Every span also enters a TraceAnnotation of its name, so a
         # profiler capture (xla_trace, the benchmark's) shows the runner's
         # segments in its host plane; obs/spans.py itself stays JAX-free.
+        # Armed before anything is built, with JAX's trace, lowering and
+        # compile of every program recorded on it (obs/compile_spans.py):
+        # the build is the cat="setup" span experiment_build, whose four
+        # children and its own self time partition it.
         self.spans = obs.spans.configure(
             os.path.join(out_dir, "spans.jsonl")
             if (out_dir and self.is_coordinator) else None,
             pid=jax.process_index(), max_bytes=obs_cap,
             annotate=jax.profiler.TraceAnnotation)
-        # Host-plane observatory (obs/hostprof.py): the per-subsystem
-        # host-seconds/bytes ledger finalized at each iteration tail, and
-        # the optional sampling stack profiler (cfg.hostprof_hz > 0) whose
-        # slices land in hostprof.jsonl (merged into report --trace) and
-        # whose folded stacks are written at run() exit. configure_profiler
-        # stops any sampler left by a previous Experiment in this process.
-        self._ledger = obs.hostprof.ledger()
-        self._ledger.reset()
-        self.hostprof = obs.hostprof.configure_profiler(
-            cfg.hostprof_hz,
-            path=os.path.join(out_dir, "hostprof.jsonl")
-            if (out_dir and self.is_coordinator) else None,
-            pid=jax.process_index())
-        # Live health monitor (obs/alerts.py): a bus tap evaluating the
-        # declarative rule set over every emitted event; fired alerts are
-        # re-emitted as alert_raised AND appended to alerts.jsonl so a
-        # crashed run keeps its alert trail.
-        self.alerts = None
-        if cfg.alerts:
-            self.alerts = obs.alerts.AlertMonitor(
-                rules=obs.alerts.default_rules(
-                    churn_threshold=cfg.alert_churn_threshold,
-                    churn_window=cfg.alert_window),
-                path=os.path.join(out_dir, "alerts.jsonl")
+        compile_spans.register()
+        with self.spans.span("experiment_build", cat="setup"):
+            with self.spans.span("data", cat="setup"):
+                self.ds = make_dataset(cfg)
+            with self.spans.span("pool_init", cat="setup"):
+                self.module = create_model(cfg.model, self.ds, cfg)
+                # cfg.mesh_shape (e.g. {"models": 2, "clients": 4}) selects
+                # the 2-D layout; empty dict = legacy 1-D clients mesh over
+                # all devices.
+                self.mesh = mesh if mesh is not None \
+                    else make_mesh(shape=cfg.mesh_shape or None)
+                # End-to-end precision policy (core/precision.py): resolved
+                # ONCE here — "auto" reproduces the legacy
+                # dtype/compute_dtype behavior (bf16 apply boundary on TPU
+                # only), explicit presets apply on any backend. The pool is
+                # created AT param_dtype, so a bf16 policy is bf16 from the
+                # first stored leaf (optimizer moments follow).
+                self.precision = resolve_precision(cfg)
+                self.pool = ModelPool.create(
+                    self.module, _sample_input(self.ds), cfg.num_models,
+                    seed=cfg.seed + 42, param_dtype=self.precision.param_dtype)
+                # Commit the pool to the mesh (replicated) up front: every
+                # jitted step consumes COMMITTED x/y (shard_client_arrays),
+                # so its param outputs come back committed to a
+                # NamedSharding — if the t=0 params were left uncommitted,
+                # t=1 would present a new sharding signature and silently
+                # recompile the whole iteration program.
+                self.pool.params = replicate(self.mesh, self.pool.params)
+            from feddrift_tpu.resilience.robust_agg import RobustAggConfig
+            self.step = TrainStep(
+                apply_fn=self._make_apply(),
+                optimizer=make_optimizer(cfg.client_optimizer, cfg.lr, cfg.wd),
+                batch_size=cfg.batch_size,
+                num_steps=cfg.epochs,
+                num_classes=self.ds.num_classes,
+                # Static: algorithms declare the Poisson-bootstrap trait
+                # (Kue.uses_sample_weights); everyone else skips the expensive
+                # flattened-categorical batch draw entirely.
+                weighted_sampling=algorithm_class(
+                    cfg.concept_drift_algo).uses_sample_weights,
+                # Static: the per-cluster aggregation strategy closing every
+                # round (resilience/robust_agg.py; "mean" = historical FedAvg).
+                robust_agg=cfg.robust_agg,
+                robust_cfg=RobustAggConfig(
+                    trim_frac=cfg.robust_trim_frac, krum_f=cfg.robust_krum_f,
+                    clip_norm=cfg.robust_clip_norm,
+                    dp_stddev=cfg.robust_dp_stddev),
+                byz_scale=cfg.byzantine_scale,
+                byz_std=cfg.byzantine_std,
+                # Static: two-tier hierarchical aggregation + in-program
+                # wire codec simulation (platform/hierarchical.py,
+                # comm/compress.py).
+                hier_edges=cfg.hierarchy_edges,
+                edge_agg=cfg.edge_robust_agg,
+                server_agg=cfg.server_robust_agg,
+                codec=cfg.compress_codec,
+                codec_topk_frac=cfg.compress_topk_frac,
+                # Static: the resolved precision policy — drives the
+                # in-program aggregation boundary (agg_dtype) and eval-buffer
+                # dtypes.
+                precision=self.precision,
+                # Static: XLA cost-capture level (obs/costmodel.py) — each
+                # tracked program's first compile also harvests cost_analysis
+                # (and memory_analysis under "compiled") into program_cost
+                # events + gauges.
+                cost_capture=cfg.cost_model,
+                # Static: vmap over all (model, client) pairs, or the pairs one
+                # at a time (core/step.py::_round_body_scan); the forward that
+                # also returns the model's own counts, where it gives any
+                client_axis=cfg.client_axis,
+                stats_fn=self._make_apply(stats=True),
+                # the model's own objective and evaluation counts, where it
+                # declares them (models/sdar_moe.py); else a cross-entropy
+                # a label
+                objective=getattr(self.module, "objective", None),
+                eval_counts=getattr(self.module, "eval_counts", None),
+                # The megastep program annotates its [M, C, ...] stacks with
+                # with_sharding_constraint over this mesh (no-op on
+                # 1-D/1-device meshes — parallel/mesh.py::constrain_pool).
+                mesh=self.mesh,
+            )
+            # Device-resident dataset, client axis sharded over the mesh. The
+            # client axis is padded to a multiple of the mesh size with phantom
+            # clients whose time weights stay zero — they train masked and
+            # contribute n=0 to aggregation, so results are identical.
+            # Population mode flips the residency story: the dataset covers the
+            # whole registered population HOST-side, and only the sampled
+            # cohort's shard is staged into the fixed-shape [C_pad, T1, N, ...]
+            # device stacks each iteration (_prepare_cohort) — XLA program
+            # shapes depend on the cohort, never on the population.
+            self.population_mode = cfg.population_size > 0
+            # Pad the client axis to the CLIENTS mesh-axis size: on a 2-D
+            # (models, clients) mesh only the clients dimension shards data.
+            n_dev = dict(self.mesh.shape).get("clients",
+                                              self.mesh.devices.size)
+            C = cfg.device_clients
+            self.C_pad = ((C + n_dev - 1) // n_dev) * n_dev
+            pad = self.C_pad - C
+            x_np, y_np = self.ds.x, self.ds.y
+            if self.population_mode:
+                self._x_pop, self._y_pop = x_np, y_np
+                self.x = self.y = None
+            elif cfg.stream_data:
+                if pad:
+                    x_np = np.concatenate([x_np, np.repeat(x_np[:1], pad, 0)],
+                                          axis=0)
+                    y_np = np.concatenate([y_np, np.repeat(y_np[:1], pad, 0)],
+                                          axis=0)
+                # host-resident: only a [C, 2, N, ...] window (current + next
+                # step) is staged into HBM per iteration (data/prefetch.py)
+                self._x_host, self._y_host = x_np, y_np
+                self.x = self.y = None
+                self._view_iter = None
+                self._view_next_t = -1
+            else:
+                if pad:
+                    x_np = np.concatenate([x_np, np.repeat(x_np[:1], pad, 0)],
+                                          axis=0)
+                    y_np = np.concatenate([y_np, np.repeat(y_np[:1], pad, 0)],
+                                          axis=0)
+                with self.spans.span("data_to_device", cat="setup"):
+                    self.x = shard_client_arrays(self.mesh, jnp.asarray(x_np))
+                    self.y = shard_client_arrays(self.mesh, jnp.asarray(y_np))
+            with self.spans.span("algorithm", cat="setup"):
+                self.algo = make_algorithm(cfg, self.ds, self.pool, self.step)
+            if self.population_mode and not getattr(
+                    self.algo, "supports_cohort", False):
+                raise ValueError(
+                    f"population_size > 0 needs a cohort-capable algorithm "
+                    f"(per-client state expressible as registry columns); "
+                    f"{cfg.concept_drift_algo!r}/"
+                    f"{cfg.concept_drift_algo_arg!r} is not")
+            if cfg.stream_data and not self.algo.supports_streaming:
+                raise ValueError(
+                    f"stream_data requires a current-step-window algorithm "
+                    f"(supports_streaming); {cfg.concept_drift_algo!r} "
+                    f"trains on past steps or reads the full dataset")
+            self.logger = MetricsLogger(
+                out_dir if self.is_coordinator else None,
+                use_wandb and self.is_coordinator)
+            # Structured event bus: events.jsonl next to metrics.jsonl. The bus
+            # is process-local so comm-broker threads and the fault injector
+            # reach it without a handle on this object.
+            self.events = obs.configure(
+                os.path.join(out_dir, "events.jsonl")
                 if (out_dir and self.is_coordinator) else None,
-                max_bytes=obs_cap,
-            ).attach(self.events)
-        # Live ops plane (obs/live.py): SLO burn-rate engine on the event
-        # tap, plus the /metrics + /healthz + /status HTTP server when
-        # cfg.ops_port enables it (0 = fully off: no tap, no thread, no
-        # per-iteration work beyond the two sketch observes that also
-        # feed bench p99 fields).
-        self.slo = self.ops = None
-        self._ops_active = cfg.ops_port != 0
-        slo_thresholds = dict(
-            rounds_per_s=cfg.slo_rounds_per_s,
-            host_overhead=cfg.slo_host_overhead,
-            p99_round_wall_s=cfg.slo_p99_round_wall_s,
-            eval_gap=cfg.slo_eval_gap,
-            model_accuracy=cfg.slo_model_accuracy)
-        if self._ops_active or any(v > 0 for v in slo_thresholds.values()):
-            self.slo = obs.live.SLOEngine(
-                objectives=obs.live.default_slos(**slo_thresholds),
-                path=os.path.join(out_dir, "alerts.jsonl")
+                max_bytes=obs_cap)
+            # Host-plane observatory (obs/hostprof.py): the per-subsystem
+            # host-seconds/bytes ledger finalized at each iteration tail, and
+            # the optional sampling stack profiler (cfg.hostprof_hz > 0) whose
+            # slices land in hostprof.jsonl (merged into report --trace) and
+            # whose folded stacks are written at run() exit. configure_profiler
+            # stops any sampler left by a previous Experiment in this process.
+            self._ledger = obs.hostprof.ledger()
+            self._ledger.reset()
+            self.hostprof = obs.hostprof.configure_profiler(
+                cfg.hostprof_hz,
+                path=os.path.join(out_dir, "hostprof.jsonl")
                 if (out_dir and self.is_coordinator) else None,
-                max_bytes=obs_cap,
-            ).attach(self.events)
-        if self._ops_active:
-            obs.live.status_board().reset()
-            obs.live.StatusTap().attach(self.events)
-            self.ops = obs.live.OpsServer(
-                port=max(cfg.ops_port, 0),   # -1 -> ephemeral bind
-                slo=self.slo).start()
-        # Incident plane (obs/blackbox.py, obs/incident.py): always-on
-        # flight recorder over the event stream + debounced bundle
-        # capture on the trigger set (crit alerts, SLO burns, replica
-        # deaths, secure degradation, preemption, divergence aborts via
-        # run()'s exception guard). Every process records; only the
-        # coordinator writes bundles, like every other sink here.
-        self.flight = self.incidents = None
-        if cfg.incident_capture:
-            self.flight = obs.blackbox.configure(
-                capacity=cfg.incident_ring).attach(self.events)
-            self.incidents = obs.incident.IncidentManager(
-                run_dir=out_dir if (out_dir and self.is_coordinator)
-                else None,
-                recorder=self.flight,
-                debounce_s=cfg.incident_debounce_s,
-                max_bundles=cfg.incident_max_bundles,
-                config_json=cfg.to_json(),
-                ckpt_path=os.path.join(out_dir, "ckpt") if out_dir
-                else None,
-            ).attach(self.events)
-        self.algo.bind(self.x, self.y, self.logger, self.C_pad)
-        # Population-scale participation (platform/registry.py,
-        # resilience/participation.py): host-side registry of every
-        # registered client, a seeded per-iteration cohort sampler, and a
-        # deadline+quorum closing rule; straggler/churn injectors are the
-        # chaos for this layer. cfg forbids the dense-pool fault/byzantine
-        # injectors here — their client indices mean device slots.
-        self.registry = self.sampler = None
-        self.straggler = self.churn = self.participation = None
-        self._cohort_members = None
-        self._slot_valid = None
-        self._stager = None
-        if self.population_mode:
-            from feddrift_tpu.platform.faults import (ChurnSchedule,
-                                                      StragglerInjector)
-            from feddrift_tpu.platform.registry import (ClientRegistry,
-                                                        CohortSampler)
-            from feddrift_tpu.resilience.participation import \
-                ParticipationPolicy
-            P = cfg.population_size
-            self.registry = ClientRegistry(P, num_steps=self.ds.num_steps + 1)
-            self.sampler = CohortSampler(self.registry, cfg.cohort_slots,
-                                         seed=cfg.cohort_seed)
-            if cfg.straggler_prob > 0 or cfg.straggler_slow_frac > 0:
-                self.straggler = StragglerInjector(
-                    P, cfg.straggler_prob, cfg.straggler_slow_frac,
-                    deadline=cfg.round_deadline, seed=cfg.straggler_seed)
-            if cfg.churn_leave_prob > 0 or cfg.churn_join_prob > 0:
-                self.churn = ChurnSchedule(P, cfg.churn_leave_prob,
-                                           cfg.churn_join_prob,
-                                           seed=cfg.churn_seed)
-            self.participation = ParticipationPolicy(
-                cfg.round_deadline, cfg.quorum_frac,
-                cfg.cohort_size or cfg.client_num_in_total)
-            self._slot_valid = np.ones(self.C_pad, dtype=bool)
-            self._slot_valid[self.C_:] = False
-            # Pipelined cohort staging: iteration t's tail kicks off the
-            # t+1 gather + device_put on a background thread so the next
-            # _prepare_cohort finds its shard already staged
-            # (data/prefetch.py::AsyncStager; bitwise-identical — only the
-            # copy timing moves). Megastep blocks keep up to K gathers in
-            # flight (each plan step submits the next step's shard), hence
-            # the K-deep pipeline.
-            from feddrift_tpu.data.prefetch import AsyncStager
-            self._stager = AsyncStager(depth=max(1, cfg.megastep_k))
-        from feddrift_tpu.platform.faults import (ByzantineInjector,
-                                                  FailureDetector,
-                                                  FaultInjector)
-        self.fault_injector = (
-            FaultInjector(self.C_, cfg.fault_dropout_prob, cfg.fault_seed)
-            if (cfg.fault_dropout_prob > 0 or cfg.fault_enabled) else None)
-        self.failure_detector = (
-            FailureDetector(self.C_, cfg.failure_patience)
-            if self.fault_injector is not None else None)
-        byz_clients = cfg.byzantine_client_list
-        self.byzantine = (
-            ByzantineInjector(self.C_, byz_clients, mode=cfg.byzantine_mode,
-                              prob=cfg.byzantine_prob,
-                              seed=cfg.byzantine_seed)
-            if byz_clients else None)
-        # Two-tier hierarchy (platform/hierarchical.py): a host-side edge
-        # map over the padded client axis, an edge-level fault injector
-        # (crash/stall/corrupt + scheduled kill), and the same deadline +
-        # quorum closing rule as population rounds applied at edge
-        # granularity.
-        self.hierarchy = cfg.hierarchy_edges > 0
-        self.edge_map = self.edge_fault = self.edge_participation = None
-        if self.hierarchy:
-            from feddrift_tpu.platform.faults import EdgeFaultInjector
-            from feddrift_tpu.platform.hierarchical import EdgeMap
-            from feddrift_tpu.resilience.participation import \
-                ParticipationPolicy
-            E = cfg.hierarchy_edges
-            self.edge_map = EdgeMap(self.C_pad, E, assign=cfg.hierarchy_assign)
-            if (cfg.edge_crash_prob > 0 or cfg.edge_stall_prob > 0
-                    or cfg.edge_corrupt_prob > 0 or cfg.edge_kill_round >= 0):
-                self.edge_fault = EdgeFaultInjector(
-                    E, cfg.edge_crash_prob, cfg.edge_stall_prob,
-                    cfg.edge_corrupt_prob, deadline=cfg.round_deadline,
-                    seed=cfg.edge_fault_seed)
-                self.edge_participation = ParticipationPolicy(
-                    cfg.round_deadline, cfg.edge_quorum_frac, E)
-        # Secure aggregation (resilience/secure_round.py): the cohort's
-        # clients double as share-holders; the per-round path recomputes
-        # the flat weighted mean through the masked protocol and a
-        # degraded round keeps prev params (config validation pins the
-        # flat mean/megastep_k=1 path this substitution is exact for).
-        self.secure_driver = None
-        if cfg.secure_agg != "off":
-            from feddrift_tpu.resilience.secure_round import \
-                SecureRoundDriver
-            self.secure_driver = SecureRoundDriver(
-                cfg.secure_agg, num_clients=self.C_,
-                threshold=cfg.secure_threshold_t,
-                scale_bits=cfg.secure_scale_bits,
-                seed=cfg.secure_fault_seed, deadline=cfg.round_deadline,
-                drop_prob=cfg.secure_drop_prob,
-                delay_prob=cfg.secure_delay_prob,
-                corrupt_prob=cfg.secure_corrupt_prob,
-                holder_stall_prob=cfg.secure_holder_stall_prob,
-                group_size=cfg.secure_group_size or None,
-                strict=cfg.sanitize)
-        # robust_agg_applied events only when a defense is actually on —
-        # plain "mean" runs keep their historical event stream.
-        self._robust_active = (
-            cfg.robust_agg != "mean" or cfg.robust_dp_stddev > 0
-            or (self.hierarchy and (cfg.edge_robust_agg != "mean"
-                                    or cfg.server_robust_agg != "mean")))
-        self._byz_stale = None   # last round's client submissions (stale_replay)
-        self._codec_prev = None  # delta codec: last round's decoded diffs
-        self.key = experiment_key(cfg.seed)
-        self.global_round = 0
-        self.start_iteration = 0
-        self.out_dir = out_dir
-        self.preempted = False
-        from feddrift_tpu.resilience.divergence import DivergenceGuard
-        self.divergence_guard = (
-            DivergenceGuard(spike_factor=cfg.divergence_spike_factor,
-                            max_rollbacks=cfg.divergence_max_rollbacks,
-                            warmup=cfg.divergence_warmup_rounds)
-            if cfg.divergence_guard else None)
-        self.tracer = PhaseTracer(registry=obs.registry(), spans=self.spans)
-        # Round-breakdown accounting: a segment is the summed SELF time of
-        # the cat="round" spans of that name, recorded where the work
-        # happens (here, core/step.py's dispatch wrapper, multihost.fetch);
-        # whatever no span claims is the dispatch gap — unclaimed host
-        # time. Finalized into one round_breakdown event +
-        # host_overhead_frac gauge per iteration.
-        self._segs: dict[str, float] = {}
-        self._seg_owner: "str | None" = None
-        self.last_round_breakdown: "dict | None" = None
-        # The ground-truth concept matrix rides along in run_start for
-        # synthetic datasets: obs/lineage.py scores the recorded
-        # cluster_assign timeline against it (oracle ARI/purity) without
-        # re-materializing the dataset. Size-gated so a thousand-client
-        # scaling run does not bloat its first event line.
-        concepts = getattr(self.ds, "concepts", None)
-        # In population mode the first C_ concept columns are NOT the
-        # cohort slots' clients (slots are re-sampled per iteration), so
-        # no dense concept matrix is recorded; the per-iteration
-        # cluster_assign events carry the member ids + live oracle scores.
-        concept_matrix = (concepts[:, : self.C_].tolist()
-                          if concepts is not None and not self.population_mode
-                          and concepts[:, : self.C_].size <= 20000 else None)
-        device = obs.costmodel.device_info()
-        self.events.emit(
-            "run_start", dataset=cfg.dataset, model=cfg.model,
-            algo=cfg.concept_drift_algo, algo_arg=cfg.concept_drift_algo_arg,
-            clients=self.C_, num_models=self.pool.num_models,
-            comm_round=cfg.comm_round, train_iterations=cfg.train_iterations,
-            backend=device["platform"], device_kind=device["device_kind"],
-            device_count=device["device_count"], mesh=dict(self.mesh.shape),
-            # the policy as RESOLVED on this backend: "auto" computes at
-            # cfg.compute_dtype on a TPU and at cfg.dtype elsewhere, so the
-            # same command is a different program per platform
-            precision=self.precision.name,
-            compute_dtype=self.precision.compute_dtype,
-            param_dtype=self.precision.param_dtype,
-            # which attention path "auto" became here (None: the model has
-            # no attention); see models/transformer.py
-            attention_impl=self._attention_impl(),
-            # which round body runs the (model, client) pairs, and the
-            # routed experts this process holds of each expert layer
-            # ([first, end) of the router's outputs; None: no expert layer)
-            client_axis=cfg.client_axis,
-            experts_held=(list(self.module.experts_held)
-                          if hasattr(self.module, "experts_held") else None),
-            seed=cfg.seed, concept_matrix=concept_matrix,
-            population=cfg.population_size or None)
-        if cfg.debug_checks:
-            from feddrift_tpu.utils.invariants import enable_nan_debugging
-            enable_nan_debugging()
-        self.sanitizer = None
-        if cfg.sanitize:
-            from feddrift_tpu.analysis.sanitize import Sanitizer
-            self.sanitizer = Sanitizer(cfg, bus=self.events)
+                pid=jax.process_index())
+            # Live health monitor (obs/alerts.py): a bus tap evaluating the
+            # declarative rule set over every emitted event; fired alerts are
+            # re-emitted as alert_raised AND appended to alerts.jsonl so a
+            # crashed run keeps its alert trail.
+            self.alerts = None
+            if cfg.alerts:
+                self.alerts = obs.alerts.AlertMonitor(
+                    rules=obs.alerts.default_rules(
+                        churn_threshold=cfg.alert_churn_threshold,
+                        churn_window=cfg.alert_window),
+                    path=os.path.join(out_dir, "alerts.jsonl")
+                    if (out_dir and self.is_coordinator) else None,
+                    max_bytes=obs_cap,
+                ).attach(self.events)
+            # Live ops plane (obs/live.py): SLO burn-rate engine on the event
+            # tap, plus the /metrics + /healthz + /status HTTP server when
+            # cfg.ops_port enables it (0 = fully off: no tap, no thread, no
+            # per-iteration work beyond the two sketch observes that also
+            # feed bench p99 fields).
+            self.slo = self.ops = None
+            self._ops_active = cfg.ops_port != 0
+            slo_thresholds = dict(
+                rounds_per_s=cfg.slo_rounds_per_s,
+                host_overhead=cfg.slo_host_overhead,
+                p99_round_wall_s=cfg.slo_p99_round_wall_s,
+                eval_gap=cfg.slo_eval_gap,
+                model_accuracy=cfg.slo_model_accuracy)
+            if self._ops_active or any(v > 0 for v in slo_thresholds.values()):
+                self.slo = obs.live.SLOEngine(
+                    objectives=obs.live.default_slos(**slo_thresholds),
+                    path=os.path.join(out_dir, "alerts.jsonl")
+                    if (out_dir and self.is_coordinator) else None,
+                    max_bytes=obs_cap,
+                ).attach(self.events)
+            if self._ops_active:
+                obs.live.status_board().reset()
+                obs.live.StatusTap().attach(self.events)
+                self.ops = obs.live.OpsServer(
+                    port=max(cfg.ops_port, 0),   # -1 -> ephemeral bind
+                    slo=self.slo).start()
+            # Incident plane (obs/blackbox.py, obs/incident.py): always-on
+            # flight recorder over the event stream + debounced bundle
+            # capture on the trigger set (crit alerts, SLO burns, replica
+            # deaths, secure degradation, preemption, divergence aborts via
+            # run()'s exception guard). Every process records; only the
+            # coordinator writes bundles, like every other sink here.
+            self.flight = self.incidents = None
+            if cfg.incident_capture:
+                self.flight = obs.blackbox.configure(
+                    capacity=cfg.incident_ring).attach(self.events)
+                self.incidents = obs.incident.IncidentManager(
+                    run_dir=out_dir if (out_dir and self.is_coordinator)
+                    else None,
+                    recorder=self.flight,
+                    debounce_s=cfg.incident_debounce_s,
+                    max_bundles=cfg.incident_max_bundles,
+                    config_json=cfg.to_json(),
+                    ckpt_path=os.path.join(out_dir, "ckpt") if out_dir
+                    else None,
+                ).attach(self.events)
+            self.algo.bind(self.x, self.y, self.logger, self.C_pad)
+            # Population-scale participation (platform/registry.py,
+            # resilience/participation.py): host-side registry of every
+            # registered client, a seeded per-iteration cohort sampler, and a
+            # deadline+quorum closing rule; straggler/churn injectors are the
+            # chaos for this layer. cfg forbids the dense-pool fault/byzantine
+            # injectors here — their client indices mean device slots.
+            self.registry = self.sampler = None
+            self.straggler = self.churn = self.participation = None
+            self._cohort_members = None
+            self._slot_valid = None
+            self._stager = None
+            if self.population_mode:
+                from feddrift_tpu.platform.faults import (ChurnSchedule,
+                                                          StragglerInjector)
+                from feddrift_tpu.platform.registry import (ClientRegistry,
+                                                            CohortSampler)
+                from feddrift_tpu.resilience.participation import \
+                    ParticipationPolicy
+                P = cfg.population_size
+                self.registry = ClientRegistry(
+                    P, num_steps=self.ds.num_steps + 1)
+                self.sampler = CohortSampler(self.registry, cfg.cohort_slots,
+                                             seed=cfg.cohort_seed)
+                if cfg.straggler_prob > 0 or cfg.straggler_slow_frac > 0:
+                    self.straggler = StragglerInjector(
+                        P, cfg.straggler_prob, cfg.straggler_slow_frac,
+                        deadline=cfg.round_deadline, seed=cfg.straggler_seed)
+                if cfg.churn_leave_prob > 0 or cfg.churn_join_prob > 0:
+                    self.churn = ChurnSchedule(P, cfg.churn_leave_prob,
+                                               cfg.churn_join_prob,
+                                               seed=cfg.churn_seed)
+                self.participation = ParticipationPolicy(
+                    cfg.round_deadline, cfg.quorum_frac,
+                    cfg.cohort_size or cfg.client_num_in_total)
+                self._slot_valid = np.ones(self.C_pad, dtype=bool)
+                self._slot_valid[self.C_:] = False
+                # Pipelined cohort staging: iteration t's tail kicks off the
+                # t+1 gather + device_put on a background thread so the next
+                # _prepare_cohort finds its shard already staged
+                # (data/prefetch.py::AsyncStager; bitwise-identical — only
+                # the copy timing moves). Megastep blocks keep up to K
+                # gathers in flight (each plan step submits the next step's
+                # shard), hence the K-deep pipeline.
+                from feddrift_tpu.data.prefetch import AsyncStager
+                self._stager = AsyncStager(depth=max(1, cfg.megastep_k))
+            from feddrift_tpu.platform.faults import (ByzantineInjector,
+                                                      FailureDetector,
+                                                      FaultInjector)
+            self.fault_injector = (
+                FaultInjector(self.C_, cfg.fault_dropout_prob, cfg.fault_seed)
+                if (cfg.fault_dropout_prob > 0 or cfg.fault_enabled) else None)
+            self.failure_detector = (
+                FailureDetector(self.C_, cfg.failure_patience)
+                if self.fault_injector is not None else None)
+            byz_clients = cfg.byzantine_client_list
+            self.byzantine = (
+                ByzantineInjector(self.C_, byz_clients,
+                                  mode=cfg.byzantine_mode,
+                                  prob=cfg.byzantine_prob,
+                                  seed=cfg.byzantine_seed)
+                if byz_clients else None)
+            # Two-tier hierarchy (platform/hierarchical.py): a host-side edge
+            # map over the padded client axis, an edge-level fault injector
+            # (crash/stall/corrupt + scheduled kill), and the same deadline +
+            # quorum closing rule as population rounds applied at edge
+            # granularity.
+            self.hierarchy = cfg.hierarchy_edges > 0
+            self.edge_map = self.edge_fault = self.edge_participation = None
+            if self.hierarchy:
+                from feddrift_tpu.platform.faults import EdgeFaultInjector
+                from feddrift_tpu.platform.hierarchical import EdgeMap
+                from feddrift_tpu.resilience.participation import \
+                    ParticipationPolicy
+                E = cfg.hierarchy_edges
+                self.edge_map = EdgeMap(self.C_pad, E,
+                                        assign=cfg.hierarchy_assign)
+                if (cfg.edge_crash_prob > 0 or cfg.edge_stall_prob > 0
+                        or cfg.edge_corrupt_prob > 0
+                        or cfg.edge_kill_round >= 0):
+                    self.edge_fault = EdgeFaultInjector(
+                        E, cfg.edge_crash_prob, cfg.edge_stall_prob,
+                        cfg.edge_corrupt_prob, deadline=cfg.round_deadline,
+                        seed=cfg.edge_fault_seed)
+                    self.edge_participation = ParticipationPolicy(
+                        cfg.round_deadline, cfg.edge_quorum_frac, E)
+            # Secure aggregation (resilience/secure_round.py): the cohort's
+            # clients double as share-holders; the per-round path recomputes
+            # the flat weighted mean through the masked protocol and a
+            # degraded round keeps prev params (config validation pins the
+            # flat mean/megastep_k=1 path this substitution is exact for).
+            self.secure_driver = None
+            if cfg.secure_agg != "off":
+                from feddrift_tpu.resilience.secure_round import \
+                    SecureRoundDriver
+                self.secure_driver = SecureRoundDriver(
+                    cfg.secure_agg, num_clients=self.C_,
+                    threshold=cfg.secure_threshold_t,
+                    scale_bits=cfg.secure_scale_bits,
+                    seed=cfg.secure_fault_seed, deadline=cfg.round_deadline,
+                    drop_prob=cfg.secure_drop_prob,
+                    delay_prob=cfg.secure_delay_prob,
+                    corrupt_prob=cfg.secure_corrupt_prob,
+                    holder_stall_prob=cfg.secure_holder_stall_prob,
+                    group_size=cfg.secure_group_size or None,
+                    strict=cfg.sanitize)
+            # robust_agg_applied events only when a defense is actually on —
+            # plain "mean" runs keep their historical event stream.
+            self._robust_active = (
+                cfg.robust_agg != "mean" or cfg.robust_dp_stddev > 0
+                or (self.hierarchy and (cfg.edge_robust_agg != "mean"
+                                        or cfg.server_robust_agg != "mean")))
+            # last round's client submissions (stale_replay)
+            self._byz_stale = None
+            self._codec_prev = None  # delta codec: last round's decoded diffs
+            self.key = experiment_key(cfg.seed)
+            self.global_round = 0
+            self.start_iteration = 0
+            self.out_dir = out_dir
+            self.preempted = False
+            from feddrift_tpu.resilience.divergence import DivergenceGuard
+            self.divergence_guard = (
+                DivergenceGuard(spike_factor=cfg.divergence_spike_factor,
+                                max_rollbacks=cfg.divergence_max_rollbacks,
+                                warmup=cfg.divergence_warmup_rounds)
+                if cfg.divergence_guard else None)
+            self.tracer = PhaseTracer(registry=obs.registry(),
+                                      spans=self.spans)
+            # Round-breakdown accounting: a segment is the summed SELF time of
+            # the cat="round" spans of that name, recorded where the work
+            # happens (here, core/step.py's dispatch wrapper, multihost.fetch);
+            # whatever no span claims is the dispatch gap — unclaimed host
+            # time. Finalized into one round_breakdown event +
+            # host_overhead_frac gauge per iteration.
+            self._segs: dict[str, float] = {}
+            self._seg_owner: "str | None" = None
+            self.last_round_breakdown: "dict | None" = None
+            # The ground-truth concept matrix rides along in run_start for
+            # synthetic datasets: obs/lineage.py scores the recorded
+            # cluster_assign timeline against it (oracle ARI/purity) without
+            # re-materializing the dataset. Size-gated so a thousand-client
+            # scaling run does not bloat its first event line.
+            concepts = getattr(self.ds, "concepts", None)
+            # In population mode the first C_ concept columns are NOT the
+            # cohort slots' clients (slots are re-sampled per iteration), so
+            # no dense concept matrix is recorded; the per-iteration
+            # cluster_assign events carry the member ids + live oracle scores.
+            concept_matrix = (
+                concepts[:, : self.C_].tolist()
+                if concepts is not None and not self.population_mode
+                and concepts[:, : self.C_].size <= 20000 else None)
+            device = obs.costmodel.device_info()
+            self.events.emit(
+                "run_start", dataset=cfg.dataset, model=cfg.model,
+                algo=cfg.concept_drift_algo,
+                algo_arg=cfg.concept_drift_algo_arg,
+                clients=self.C_, num_models=self.pool.num_models,
+                comm_round=cfg.comm_round,
+                train_iterations=cfg.train_iterations,
+                backend=device["platform"], device_kind=device["device_kind"],
+                device_count=device["device_count"],
+                mesh=dict(self.mesh.shape),
+                # the policy as RESOLVED on this backend: "auto" computes at
+                # cfg.compute_dtype on a TPU and at cfg.dtype elsewhere, so the
+                # same command is a different program per platform
+                precision=self.precision.name,
+                compute_dtype=self.precision.compute_dtype,
+                param_dtype=self.precision.param_dtype,
+                # which attention path "auto" became here (None: the model has
+                # no attention); see models/transformer.py
+                attention_impl=self._attention_impl(),
+                # which round body runs the (model, client) pairs, and the
+                # routed experts this process holds of each expert layer
+                # ([first, end) of the router's outputs; None: no expert layer)
+                client_axis=cfg.client_axis,
+                experts_held=(list(self.module.experts_held)
+                              if hasattr(self.module, "experts_held")
+                              else None),
+                seed=cfg.seed, concept_matrix=concept_matrix,
+                population=cfg.population_size or None)
+            if cfg.debug_checks:
+                from feddrift_tpu.utils.invariants import enable_nan_debugging
+                enable_nan_debugging()
+            self.sanitizer = None
+            if cfg.sanitize:
+                from feddrift_tpu.analysis.sanitize import Sanitizer
+                self.sanitizer = Sanitizer(cfg, bus=self.events)
 
     def _attention_impl(self) -> Optional[str]:
         impl = getattr(self.module, "attention_impl", None)
@@ -988,8 +1018,6 @@ class Experiment:
         self.last_round_breakdown = {
             "iteration": t, "wall_s": round(wall, 6),
             "rounds": cfg.comm_round,
-            # every round's device wait is measured (kept for consumers)
-            "profiled_rounds": cfg.comm_round,
             "segments": segments, "dispatch_gap_s": round(gap, 6),
             "host_overhead_frac": round(host_frac, 6)}
         self.events.emit("round_breakdown", **self.last_round_breakdown)
@@ -1854,7 +1882,7 @@ class Experiment:
                               cat="runner", iteration=t)
             self.last_round_breakdown = {
                 "iteration": t, "wall_s": round(wall_j, 6), "rounds": R,
-                "profiled_rounds": R, "megastep_k": K,
+                "megastep_k": K,
                 "segments": segments,
                 "dispatch_gap_s": round(gap / committed, 6),
                 "host_overhead_frac": round(host_frac, 6)}

@@ -355,7 +355,7 @@ def _write_run(tmp_path, walls, breakdown_iters=None, stragglers=(),
             f.write(json.dumps({
                 "_ts": 1_700_000_000.0, "kind": "round_breakdown",
                 "iteration": it, "wall_s": wall, "rounds": 4,
-                "profiled_rounds": 4, "segments": segs,
+                "segments": segs,
                 "dispatch_gap_s": segs["dispatch_gap"],
                 "host_overhead_frac": 0.4}) + "\n")
         for it in stragglers:
@@ -508,7 +508,7 @@ class TestRoundBreakdownEndToEnd:
             assert seg_sum == pytest.approx(bd["wall_s"], rel=0.05)
             assert bd["segments"]["device_compute"] > 0
             assert 0.0 <= bd["host_overhead_frac"] <= 1.0
-            assert bd["profiled_rounds"] == bd["rounds"]   # trace_sync
+            assert bd["rounds"] == cfg.comm_round
         assert exp.last_round_breakdown["iteration"] == 1
 
         # the gauge + histogram landed in the registry

@@ -56,12 +56,14 @@ def test_the_program_gives_what_the_pure_body_gives(optimizer, precision):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     # benchmark/sizing.py's call: the pure body under eval_shape gives the
-    # same tree of shapes and dtypes, and is no dispatch: no span, and no
-    # entry in the signature table
+    # same tree of shapes and dtypes, and is no dispatch: no span but JAX's
+    # one trace of it (obs/compile_spans.py), nothing lowered or compiled,
+    # and no entry in the signature table
     seen = {fn: len(sigs) for fn, sigs in step._signatures.items()}
     n_spans = len(obs.spans.get_recorder().spans())
     shapes = jax.eval_shape(lambda p: step.init_opt_states(p, M, C), params)
-    assert len(obs.spans.get_recorder().spans()) == n_spans
+    added = obs.spans.get_recorder().spans()[n_spans:]
+    assert [(s["name"], s["cat"]) for s in added] == [("trace", "compile")]
     assert {fn: len(sigs) for fn, sigs in step._signatures.items()} == seen
     assert jax.tree_util.tree_structure(shapes) == \
         jax.tree_util.tree_structure(eager)
