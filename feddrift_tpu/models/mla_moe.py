@@ -103,19 +103,28 @@ def swiglu(x, w_gate, w_up, w_down):
 
 # ----------------------------------------------------------------------
 def _by_expert(on, block: int):
-    """Of one held expert: how many tokens chose it, its tokens first and in
-    their own order (padded by a block, so that the last block's slice of
-    ``block`` rows stays in range), and each token's place among them."""
-    count = on.sum()
+    """Of one held expert: how many tokens chose it, and its tokens first and
+    in their own order, padded by a block past the last row (so that the
+    last block's slice of ``block`` rows stays in range, and its padding
+    rows land nowhere)."""
+    T = on.shape[0]
     order = jnp.concatenate([jnp.argsort(~on, stable=True),
-                             jnp.zeros((block,), jnp.int32)])
-    return count, order, jnp.clip(jnp.cumsum(on) - 1, 0)
+                             T + jnp.arange(block, dtype=jnp.int32)])
+    return on.sum(), order
 
 
-def _to_tokens(packed, on, place):
-    """Rows packed by place, back at their tokens' rows; nought elsewhere."""
-    return jnp.where(on.reshape(on.shape + (1,) * (packed.ndim - 1)),
-                     packed[place], 0)
+def _block_rows(order, count, k, block: int):
+    """Block k's rows of the expert's order, and which of them are its
+    tokens: the rest are other tokens' rows or past the last row."""
+    rows = lax.dynamic_slice(order, (k * block,), (block,))
+    return rows, k * block + jnp.arange(block) < count
+
+
+def _add_rows(acc, rows, live, y):
+    """``acc`` with ``y`` added into ``rows`` where ``live``, and nought
+    elsewhere; rows past the last are dropped."""
+    live = live.reshape(live.shape + (1,) * (y.ndim - 1))
+    return acc.at[rows].add(jnp.where(live, y, 0), mode="drop")
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -131,33 +140,31 @@ def routed_experts(x, weight, chosen, w_gate, w_up, w_down, block: int):
     go through the expert ``block`` rows at a time, as many blocks as the
     expert got tokens for (a loop whose length the router sets: the cost
     follows the assignments held here, whatever their spread over the
-    experts), and the results go back to the tokens' rows by a gather. No
-    token is dropped and no capacity is set. A loop of that kind has no
-    automatic transpose, so the backward is written out below: the same
-    blocks, each recomputed. (A tile of fixed rows an expert with a masked
-    pass past it under a ``lax.cond`` is differentiable as it stands, and
-    cost 5 s of a 14 s time step on the chip: a ``cond`` writes what its
-    branch's backward needs whether taken or not. PERF.md section 6, PR 29.)
+    experts). Each block's result, weighted, is added straight into its
+    tokens' rows of the float32 accumulator, so an expert reads and writes
+    the rows it got and no others: a pass over all T rows for each held
+    expert reads and writes 16 x the rows it changes where an expert gets
+    512 of 8,192 rows (PERF.md section 5). Each row receives its experts'
+    terms in the held experts' order. No token is dropped and no capacity
+    is set. A loop of that kind has no automatic transpose, so the backward
+    is written out below: the same blocks, each recomputed. (A tile of fixed
+    rows an expert with a masked pass past it under a ``lax.cond`` is
+    differentiable as it stands, and cost 5 s of a 14 s time step on the
+    chip: a ``cond`` writes what its branch's backward needs whether taken
+    or not; PERF.md section 6.)
     """
-    T, D = x.shape
-
     def one(out, e):
         wg, wu, wd, w_e, on = e
-        count, order, place = _by_expert(on, block)
+        count, order = _by_expert(on, block)
 
-        def run(k, ys):
-            rows = lax.dynamic_slice(order, (k * block,), (block,))
-            return lax.dynamic_update_slice(
-                ys, swiglu(x[rows], wg, wu, wd), (k * block, 0))
+        def run(k, out):
+            rows, live = _block_rows(order, count, k, block)
+            y = swiglu(x[rows], wg, wu, wd).astype(jnp.float32)
+            return _add_rows(out, rows, live, y * w_e[rows][:, None])
 
-        # rows past the expert's last token hold other tokens' results or
-        # nought: no token's place points there
-        ys = lax.fori_loop(0, -(-count // block), run,
-                           jnp.zeros((T + block, D), x.dtype))
-        return out + _to_tokens(ys, on, place).astype(jnp.float32) \
-            * w_e[:, None], None
+        return lax.fori_loop(0, -(-count // block), run, out), None
 
-    return lax.scan(one, jnp.zeros((T, D), jnp.float32),
+    return lax.scan(one, jnp.zeros(x.shape, jnp.float32),
                     (w_gate, w_up, w_down, weight.T, chosen.T))[0]
 
 
@@ -169,9 +176,11 @@ def _routed_fwd(x, weight, chosen, w_gate, w_up, w_down, block):
 def _routed_bwd(block, res, g):
     """``g`` [T, D] float32. Per held expert and block of its rows: the
     block's SwiGLU again, then the products' transposes; the experts'
-    gradients add up over the blocks in float32."""
+    gradients add up over the blocks in float32, and the rows' gradients
+    (of ``x`` and of the routing weight) go into their own rows, as the
+    forward's results do."""
     x, weight, chosen, w_gate, w_up, w_down = res
-    T, D = x.shape
+    T = x.shape[0]
     f32 = jnp.float32
 
     def mm(a, b):
@@ -179,42 +188,37 @@ def _routed_bwd(block, res, g):
 
     def one(dx, e):
         wg, wu, wd, w_e, on = e
-        count, order, place = _by_expert(on, block)
+        count, order = _by_expert(on, block)
 
         def run(k, c):
-            dxs, dws, dwg, dwu, dwd = c
-            rows = lax.dynamic_slice(order, (k * block,), (block,))
-            live = (k * block + jnp.arange(block) < count)[:, None]
-            xs, gs = x[rows], jnp.where(live, g[rows], 0.0)
+            dx, dw, dwg, dwu, dwd = c
+            rows, live = _block_rows(order, count, k, block)
+            xs, gs = x[rows], jnp.where(live[:, None], g[rows], 0.0)
             hg, hu = xs @ wg, xs @ wu
             sig = jax.nn.sigmoid(hg.astype(f32))
             act = (hg.astype(f32) * sig).astype(x.dtype)       # silu
             a = act * hu
             # the routing weight's share: <g_t, SwiGLU_e(x_t)>
-            dws = lax.dynamic_update_slice(
-                dws, (gs * mm(a, wd)).sum(-1), (k * block,))
+            dw = _add_rows(dw, rows, live, (gs * mm(a, wd)).sum(-1))
             gy = (gs * w_e[rows][:, None]).astype(x.dtype)
             da = mm(gy, wd.T)
             dhu = (da * act.astype(f32)).astype(x.dtype)
             dhg = (da * hu.astype(f32) * sig
                    * (1.0 + hg.astype(f32) * (1.0 - sig))).astype(x.dtype)
-            dxs = lax.dynamic_update_slice(
-                dxs, (mm(dhg, wg.T) + mm(dhu, wu.T)).astype(x.dtype),
-                (k * block, 0))
-            return (dxs, dws, dwg + mm(xs.T, dhg), dwu + mm(xs.T, dhu),
+            dx = _add_rows(dx, rows, live, (mm(dhg, wg.T) + mm(dhu, wu.T))
+                           .astype(x.dtype).astype(f32))
+            return (dx, dw, dwg + mm(xs.T, dhg), dwu + mm(xs.T, dhu),
                     dwd + mm(a.T, gy))
 
-        dxs, dws, dwg, dwu, dwd = lax.fori_loop(
+        dx, dw, dwg, dwu, dwd = lax.fori_loop(
             0, -(-count // block), run,
-            (jnp.zeros((T + block, D), x.dtype), jnp.zeros((T + block,), f32),
-             jnp.zeros(wg.shape, f32), jnp.zeros(wu.shape, f32),
-             jnp.zeros(wd.shape, f32)))
-        return dx + _to_tokens(dxs, on, place).astype(f32), (
-            _to_tokens(dws, on, place), dwg.astype(wg.dtype),
-            dwu.astype(wu.dtype), dwd.astype(wd.dtype))
+            (dx, jnp.zeros((T,), f32), jnp.zeros(wg.shape, f32),
+             jnp.zeros(wu.shape, f32), jnp.zeros(wd.shape, f32)))
+        return dx, (dw, dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+                    dwd.astype(wd.dtype))
 
     dx, (dw, dwg, dwu, dwd) = lax.scan(
-        one, jnp.zeros((T, D), f32),
+        one, jnp.zeros(x.shape, f32),
         (w_gate, w_up, w_down, weight.T, chosen.T))
     return (dx.astype(x.dtype), dw.T.astype(weight.dtype), None, dwg, dwu,
             dwd)

@@ -122,13 +122,19 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 def _routed_case(skew, dtype=jnp.float32):
-    T, D, F, G = 40, 16, 8, 4
+    # 43 rows where every token chose every expert: the last block of 8 runs
+    # five rows past the last token
+    T, D, F, G = 43 if skew == "all" else 40, 16, 8, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     x = jax.random.normal(ks[0], (T, D))
     wg, wu = (0.3 * jax.random.normal(k, (G, D, F)) for k in ks[1:3])
     wd = 0.3 * jax.random.normal(ks[3], (G, F, D))
     if skew == "one_expert":
         chosen = jnp.zeros((T, G), bool).at[:, 2].set(True)
+    elif skew == "all":
+        chosen = jnp.ones((T, G), bool)
+    elif skew == "none":
+        chosen = jnp.zeros((T, G), bool)
     elif skew == "past_a_block":
         chosen = jnp.zeros((T, G), bool).at[3:36:3, 1].set(True) \
             .at[::4, 3].set(True)                   # 11 and 10 tokens
@@ -147,13 +153,16 @@ def _plain_routed(x, weight, wg, wu, wd):
     return y
 
 
-@pytest.mark.parametrize("skew", ["one_expert", "past_a_block", "even"])
+@pytest.mark.parametrize(
+    "skew", ["one_expert", "past_a_block", "even", "all", "none"])
 def test_no_token_is_dropped_whatever_the_router_does(skew):
     """A router skewed onto one held expert sends every token there (five
     blocks of 8 rows, three experts with none), one a little less skewed
-    sends two experts a few tokens more than a block holds; the result is
-    the plain masked sum over the held experts, for every token, and so are
-    the gradients of the backward that is written out."""
+    sends two experts a few tokens more than a block holds; every token may
+    choose every held expert (43 rows: the last block's rows run past the
+    last token), or none of them; the result is the plain masked sum over
+    the held experts, for every token, and so are the gradients of the
+    backward that is written out."""
     chosen, args = _routed_case(skew)
 
     def mine(x, weight, wg, wu, wd):
@@ -170,6 +179,47 @@ def test_no_token_is_dropped_whatever_the_router_does(skew):
         if i == 1:      # a weight exists only where the token chose
             got, want = (jnp.where(chosen, g, 0) for g in (got, want))
         np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
+        if skew == "none":      # nothing routed here, nothing flows back
+            assert not np.asarray(got).any()
+    if skew == "none":
+        assert not np.asarray(out).any()
+
+
+def _ops_inside_scans(jaxpr, inside=False):
+    """(primitive, output shapes) of every equation of ``jaxpr`` that runs
+    inside a ``scan``, through every nested jaxpr (loops, calls, custom
+    derivatives)."""
+    for eqn in jaxpr.eqns:
+        if inside:
+            yield eqn.primitive.name, [tuple(v.aval.shape)
+                                       for v in eqn.outvars]
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _ops_inside_scans(
+                        sub, inside or eqn.primitive.name == "scan")
+
+
+def test_the_experts_add_into_their_own_rows_and_no_other():
+    """Inside the scan over the held experts, forward and written-out
+    backward, no elementwise operation makes an array of the whole [T, D]
+    accumulator's shape: each block's result goes into its tokens' rows (a
+    scatter-add of the block's rows into the carried accumulator), and no
+    pass over all T rows is made for each expert."""
+    chosen, args = _routed_case("even")
+    T, D = args[0].shape
+
+    def loss(x, weight, wg, wu, wd):
+        return (routed_experts(x, weight, chosen, wg, wu, wd, 8) ** 2).sum()
+    elementwise = {"add", "add_any", "sub", "mul", "select_n",
+                   "convert_element_type", "max", "min"}
+    for fn in (loss, jax.grad(loss, (0, 1, 2, 3, 4))):
+        ops = list(_ops_inside_scans(jax.make_jaxpr(fn)(*args).jaxpr))
+        assert ("scatter-add", [(T, D)]) in ops
+        whole = [name for name, shapes in ops
+                 if name in elementwise and (T, D) in shapes]
+        assert not whole, whole
 
 
 def test_the_written_out_backward_keeps_its_types_under_bfloat16():
